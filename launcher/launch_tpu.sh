@@ -279,10 +279,14 @@ deliver_workload() {
   else
     # bare path: nothing on a fresh TPU-VM has the package — ship this repo
     # (incl. the hardware test lane) as an sdist-style tarball and
-    # pip-install it on every worker
+    # pip-install it on every worker. Only what git tracks is packed: the
+    # workers run what a checkout of the commit would hold, not this
+    # working tree's __pycache__ or untracked files
     local PKG_TGZ
     PKG_TGZ=$(mktemp /tmp/tpudist_pkg.XXXXXX.tgz)
-    tar -czf "$PKG_TGZ" -C "$SCRIPT_DIR/.." pyproject.toml tpudist tests_tpu
+    git -C "$SCRIPT_DIR/.." ls-files -z -- pyproject.toml README.md \
+        tpudist tests_tpu \
+      | tar -czf "$PKG_TGZ" -C "$SCRIPT_DIR/.." --null -T -
     gcloud compute tpus tpu-vm scp "$PKG_TGZ" "$TPU_NAME:tpudist_pkg.tgz" \
       --zone "$ZONE" --project "$PROJECT" --worker=all
     tpu_ssh all "rm -rf ~/tpudist_src && mkdir -p ~/tpudist_src && \

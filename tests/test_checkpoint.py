@@ -9,7 +9,6 @@ import pytest
 from tpudist import checkpoint, engine
 from tpudist.config import DataConfig, ParallelConfig, TrainConfig
 from tpudist.parallel import build_mesh
-from tpudist.utils import compat
 
 
 @pytest.fixture()
@@ -77,14 +76,9 @@ def test_fsdp_sharded_roundtrip(tmp_path, devices8):
 
 
 @pytest.mark.parametrize("model_kw,par", [
-    pytest.param(
-        dict(name="transformer", vocab_size=128, n_layers=4, d_model=32,
-             n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=16),
-        dict(data=2, pipe=2, fsdp=2),
-        marks=pytest.mark.skipif(
-            not compat.PARTIAL_AUTO_COLLECTIVES,
-            reason="jax version cannot lower collectives under "
-                   "partial-auto shard_map (pipe + data/fsdp)")),
+    (dict(name="transformer", vocab_size=128, n_layers=4, d_model=32,
+          n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=16),
+     dict(data=2, pipe=2, fsdp=2)),
     (dict(name="moe", vocab_size=128, n_layers=2, d_model=32, n_heads=2,
           n_kv_heads=2, d_ff=48, max_seq_len=16, n_experts=4),
      dict(data=2, fsdp=2, expert=2)),

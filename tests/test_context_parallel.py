@@ -8,19 +8,6 @@ import pytest
 from tpudist import data, engine
 from tpudist.config import DataConfig, ModelConfig, ParallelConfig, TrainConfig
 from tpudist.parallel import build_mesh
-from tpudist.utils import compat
-
-# old jax's SPMD partitioner hard-aborts on ulysses' all_to_all inside a
-# partially-manual shard_map (see utils.compat); the impl raises a clean
-# NotImplementedError there, and these tests skip rather than fail
-needs_partial_auto_a2a = pytest.mark.skipif(
-    not compat.PARTIAL_AUTO_ALL_TO_ALL,
-    reason="jax version cannot lower all_to_all under partial-auto "
-           "shard_map (ulysses)")
-needs_partial_auto = pytest.mark.skipif(
-    not compat.PARTIAL_AUTO_COLLECTIVES,
-    reason="jax version cannot lower collectives under partial-auto "
-           "shard_map (cp composed with data/fsdp)")
 
 TINY = dict(vocab_size=97, n_layers=2, d_model=32, n_heads=4, n_kv_heads=4,
             d_ff=64, max_seq_len=32)
@@ -62,7 +49,6 @@ def test_cp_matches_dense(devices8):
     assert l_cp[-1] < l_cp[0]  # learning
 
 
-@needs_partial_auto
 def test_cp_combined_with_dp(devices8):
     """data=2 × context=4: both batch and sequence sharded."""
     cfg = _cfg(ParallelConfig(data=2, context=4))
@@ -76,7 +62,6 @@ def _cfg_ulysses(parallel):
     return dataclasses.replace(_cfg(parallel), cp_impl="ulysses")
 
 
-@needs_partial_auto_a2a
 def test_ulysses_matches_dense(devices8):
     cfg_cp = _cfg_ulysses(ParallelConfig(data=2, context=4))
     mesh_cp = build_mesh(cfg_cp.parallel, devices=devices8)
@@ -88,7 +73,6 @@ def test_ulysses_matches_dense(devices8):
     assert l_cp[-1] < l_cp[0]
 
 
-@needs_partial_auto_a2a
 def test_ulysses_composes_with_fsdp(devices8):
     cfg = _cfg_ulysses(ParallelConfig(data=2, fsdp=2, context=2))
     mesh = build_mesh(cfg.parallel, devices=devices8)
@@ -108,10 +92,7 @@ def test_ulysses_rejects_indivisible_heads(devices8):
         step_fn(state, (toks,))
 
 
-@needs_partial_auto
-@pytest.mark.parametrize("impl", [
-    "ring",
-    pytest.param("ulysses", marks=needs_partial_auto_a2a)])
+@pytest.mark.parametrize("impl", ["ring", "ulysses"])
 def test_cp_gqa_compact_kv_matches_dense(devices8, impl):
     """Context parallelism over a GROUPED-QUERY model (2 kv heads, 4 q
     heads): the op-level GQA coverage (tests/test_ring_attention.py)

@@ -71,7 +71,6 @@ def _losses(cfg, mesh, steps=3):
 def _lowered_text(cfg, mesh, toks=None):
     from jax.sharding import PartitionSpec as P
 
-    from tpudist.utils import compat
     toks = _tokens() if toks is None else toks
     state = engine.init_state(jax.random.PRNGKey(0), cfg, mesh)
     body, dp, _ = engine._build_step_body(cfg, mesh)
@@ -79,10 +78,10 @@ def _lowered_text(cfg, mesh, toks=None):
 
     def jitted(state, batch):
         bspecs = jax.tree.map(lambda x: shd.batch_spec(x.ndim), batch)
-        return compat.shard_map(body, mesh=mesh,
-                                in_specs=(P(), bspecs),
-                                out_specs=(P(), P()),
-                                check_vma=False)(state, batch)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(), bspecs),
+                             out_specs=(P(), P()),
+                             check_vma=False)(state, batch)
     staged = shd.put_batch(mesh, (toks,))
     return jax.jit(jitted).lower(state, staged).as_text()
 

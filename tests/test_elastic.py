@@ -758,9 +758,8 @@ def _free_port():
 def _launch(rank, port, nprocs, save_dir, extra, devices_per_proc=2,
             env_extra=None):
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
     env.update(
-        TPUDIST_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=(f"--xla_force_host_platform_device_count="
                    f"{devices_per_proc}"),
     )

@@ -220,3 +220,45 @@ def test_partial_merge_matches_full_attention():
     for g, w, name in zip(g_got, g_want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4,
                                    rtol=1e-3, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("parallel", [dict(data=4),
+                                      dict(data=1, fsdp=2, tensor=2)])
+def test_flash_runs_per_shard_where_the_program_is_partitioned(
+        devices8, monkeypatch, parallel):
+    """GSPMD cannot partition a Mosaic call, which only a multi-chip TPU
+    run shows (PR 21: eval, jit + shardings training and serve prefill
+    died on it). With the kernel forced on (interpreted) the engine's
+    programs — the DP shard_map step, the jit + shardings step, and eval,
+    which is auto-partitioned on every mesh — must run it per shard and
+    reproduce the one-device XLA-attention trajectory."""
+    from tpudist import data, engine
+    from tpudist.config import ModelConfig, ParallelConfig, TrainConfig
+    from tpudist.models import transformer as T
+    from tpudist.parallel import build_mesh
+
+    model = ModelConfig(name="transformer", vocab_size=256, n_layers=1,
+                        d_model=256, n_heads=2, n_kv_heads=2, d_ff=256,
+                        max_seq_len=128)
+    toks = np.asarray(data.make_synthetic_tokens(4, 129, 256, seed=0))
+    seen = []
+
+    def run(par, devs):
+        cfg = TrainConfig(batch_size=4, model=model, parallel=par,
+                          dtype="float32")
+        mesh = build_mesh(par, devices=devs)
+        state = engine.init_state(jax.random.PRNGKey(0), cfg, mesh)
+        step = engine.make_train_step(cfg, mesh)
+        state, loss = step(state, (toks,))
+        return float(loss), float(engine.make_eval_fn(cfg, mesh)(
+            state, (toks,)))
+
+    want = run(ParallelConfig(), devices8[:1])      # XLA attention
+
+    def use_flash(q_shape, k_shape, causal=True):
+        seen.append(q_shape)
+        return fa.supports(q_shape, k_shape, causal=causal)
+    monkeypatch.setattr(T, "_use_flash", use_flash)
+    got = run(ParallelConfig(**parallel), devices8[:4])
+    assert seen, "the flash path was never taken"
+    np.testing.assert_allclose(got, want, rtol=1e-5)

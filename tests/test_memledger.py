@@ -643,24 +643,52 @@ def test_train_state_split_feeds_separate_buckets(devices8):
 # ------------------------------------------- hbm sampler satellites
 
 
-def test_hbm_split_reports_reservation_and_fragmentation():
+def test_hbm_watermark_is_live_buffers_plus_executable_scratch(monkeypatch):
+    """The TPU runtime holds a loaded executable's scratch under
+    ``bytes_reserved``, outside ``bytes_in_use`` (recorded on a v5e: the
+    stats below are that run's, scaled down): the watermark and the
+    per-device peaks are the sum, and the reservation is reported as
+    itself."""
+    import jax
     from tpudist.obs import hbm
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
     s = hbm.HbmSampler(period_s=0)
     fields = s.split()
-    assert "hbm_bytes_reserved" in fields
-    assert "hbm_fragmentation_bytes" in fields
-    # the CPU mesh has no device stats: RSS fallback says nothing
-    # about the allocator, so both stay None
+    # the CPU mesh has no device stats: RSS says nothing about the
+    # allocator
     if fields["hbm_source"] != "memory_stats":
         assert fields["hbm_bytes_reserved"] is None
-        assert fields["hbm_fragmentation_bytes"] is None
-    # scripted memory_stats: fragmentation = reserved - in_use, >= 0
-    s.source = "memory_stats"
-    s.last_in_use = 60
-    s.last_reserved = 100
-    assert s.split()["hbm_fragmentation_bytes"] == 40
-    s.last_reserved = 10
-    assert s.split()["hbm_fragmentation_bytes"] == 0
+        assert fields["hbm_peak_bytes_per_device"] is None
+    s = hbm.HbmSampler(period_s=0)
+    s.peak_in_use = 0       # forget the construction sample's RSS
+    running = {"bytes_in_use": 107, "peak_bytes_in_use": 107,
+               "bytes_reserved": 429, "peak_bytes_reserved": 429,
+               "bytes_limit": 1690}
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [Dev(running), Dev(dict(running))])
+    s.sample()
+    f = s.split()
+    assert f["hbm_source"] == "memory_stats"
+    assert f["hbm_peak_bytes"] == 107 + 429
+    assert f["hbm_peak_bytes_per_device"] == [536, 536]
+    assert f["hbm_bytes_in_use"] == 107 and f["hbm_bytes_reserved"] == 429
+    # buffers freed, executable dropped: the watermark does not recede
+    idle = {"bytes_in_use": 2, "peak_bytes_in_use": 107,
+            "bytes_reserved": 0, "peak_bytes_reserved": 429,
+            "bytes_limit": 1690}
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(idle)] * 2)
+    s.sample()
+    f = s.split()
+    assert f["hbm_peak_bytes"] == 536 and f["hbm_bytes_reserved"] == 0
+    assert f["hbm_peak_bytes_per_device"] == [536, 536]
+    assert f["hbm_peak_fraction"] == round(536 / 1690, 4)
     s.close()
 
 

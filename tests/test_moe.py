@@ -16,12 +16,6 @@ from tpudist.config import (DataConfig, ModelConfig, ParallelConfig,
                             TrainConfig)
 from tpudist.models import moe
 from tpudist.parallel import build_mesh
-from tpudist.utils import compat
-
-needs_partial_auto = pytest.mark.skipif(
-    not compat.PARTIAL_AUTO_COLLECTIVES,
-    reason="jax version cannot lower collectives under partial-auto "
-           "shard_map (cp/pp composed with data/fsdp/expert)")
 
 MODEL = ModelConfig(name="moe", vocab_size=128, n_layers=2, d_model=32,
                     n_heads=2, n_kv_heads=2, d_ff=48, max_seq_len=16,
@@ -131,7 +125,6 @@ def test_expert_parallel_matches_single_device():
     np.testing.assert_allclose(got["ep4_fsdp"], got["ep1"], rtol=2e-5)
 
 
-@needs_partial_auto
 def test_moe_context_parallel_matches_global():
     """MoE + CP (both impls): with ample capacity no routed pair drops,
     so shard-local routing matches the global-batch jit path exactly."""
@@ -156,7 +149,6 @@ def test_moe_context_parallel_matches_global():
                                rtol=2e-4)
 
 
-@needs_partial_auto
 def test_moe_context_composes_with_expert_axis():
     """The full zoo in one program: dp x expert x context — pinned
     against the same CP layout without expert sharding (identical math;
@@ -179,7 +171,6 @@ def test_moe_context_composes_with_expert_axis():
     assert got["ep2"][-1] < got["ep2"][0]
 
 
-@needs_partial_auto
 def test_moe_pipeline_matches_global():
     """MoE + pipeline: per-microbatch group-local routing; with ample
     capacity the dispatch/xent match the global jit path (the aux term is

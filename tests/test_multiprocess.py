@@ -27,9 +27,8 @@ def _free_port():
 def _launch(rank, port, nprocs, tmp, extra, devices_per_proc=2,
             env_by_rank=None):
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
     env.update(
-        TPUDIST_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=(f"--xla_force_host_platform_device_count="
                    f"{devices_per_proc}"),
         TPUDIST_VERDICT_PATH=os.path.join(tmp, "job_status.txt"),

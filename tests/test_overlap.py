@@ -265,7 +265,6 @@ class TestProgramStructure:
     def _lowered_text(self, cfg, mesh, toks):
         from jax.sharding import PartitionSpec as P
 
-        from tpudist.utils import compat
         state = engine.init_state(jax.random.PRNGKey(0), cfg, mesh)
         body, dp, _ = engine._build_step_body(cfg, mesh)
         assert dp
@@ -273,10 +272,10 @@ class TestProgramStructure:
         def jitted(state, batch):
             bspecs = jax.tree.map(lambda x: shd.batch_spec(x.ndim),
                                   batch)
-            return compat.shard_map(body, mesh=mesh,
-                                    in_specs=(P(), bspecs),
-                                    out_specs=(P(), P()),
-                                    check_vma=False)(state, batch)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(), bspecs),
+                                 out_specs=(P(), P()),
+                                 check_vma=False)(state, batch)
         staged = shd.put_batch(mesh, (toks,))
         return jax.jit(jitted).lower(state, staged).as_text()
 
@@ -436,7 +435,6 @@ class TestInterleavedPipeline:
         cut. Measured as compiled FLOPs with the slot scan unrolled on
         a layer-dominated model (tiny vocab — the head contributes
         equally to both programs)."""
-        from tpudist.utils import compat
         model = dataclasses.replace(PP_MODEL, vocab_size=32, d_ff=256)
         S, M, batch = 2, 4, 8
         mesh = _pipe_mesh(S)
@@ -451,7 +449,7 @@ class TestInterleavedPipeline:
                                  dtype=jnp.float32, interleave=v,
                                  unroll_slots=True)
             cost = jax.jit(pp).lower(params, toks).compile()
-            fl[v] = compat.cost_analysis(cost).get("flops")
+            fl[v] = cost.cost_analysis().get("flops")
         if not fl[1] or not fl[2]:
             pytest.skip("backend reports no flops in cost_analysis")
         assert fl[2] < fl[1], fl

@@ -17,17 +17,7 @@ from tpudist import data, engine
 from tpudist.config import (DataConfig, ModelConfig, ParallelConfig,
                             TrainConfig)
 from tpudist.parallel import build_mesh
-from tpudist.utils import compat
 from tpudist.parallel.pipeline import make_pp_loss_fn
-
-# every pp test composes pipe with data/fsdp sharding; old jax's SPMD
-# partitioner hard-aborts on collectives under partial-auto shard_map
-# (utils.compat), so the builders raise NotImplementedError there and
-# this module skips
-pytestmark = pytest.mark.skipif(
-    not compat.PARTIAL_AUTO_COLLECTIVES,
-    reason="jax version cannot lower collectives under partial-auto "
-           "shard_map (pipeline + data/fsdp)")
 
 MODEL = ModelConfig(name="transformer", vocab_size=128, n_layers=4,
                     d_model=32, n_heads=2, n_kv_heads=2, d_ff=64,
@@ -156,7 +146,7 @@ def test_pp_head_flops_do_not_scale_with_slots():
         pp_loss = make_pp_loss_fn(model, mesh, n_microbatches=8,
                                   dtype=jnp.float32, unroll_slots=True)
         cost = jax.jit(pp_loss).lower(params, toks).compile()
-        fl[pipe] = compat.cost_analysis(cost).get("flops")
+        fl[pipe] = cost.cost_analysis().get("flops")
     if not fl[2] or not fl[4]:
         pytest.skip("backend reports no flops in cost_analysis")
     # S=4 also runs FEWER layer-flops per device (11 slots × 1 layer vs
@@ -186,7 +176,7 @@ def test_pp_bubble_cost_decreases_with_microbatches():
         pp_loss = make_pp_loss_fn(model, mesh, n_microbatches=micro,
                                   dtype=jnp.float32, unroll_slots=True)
         cost = jax.jit(pp_loss).lower(params, toks).compile()
-        return compat.cost_analysis(cost).get("flops")
+        return cost.cost_analysis().get("flops")
 
     fl = {m: flops(m) for m in (S, 2 * S, 4 * S, 0)}
     if not all(fl.values()):

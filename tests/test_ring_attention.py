@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from tpudist.config import ParallelConfig
-from tpudist.utils import compat
 from tpudist.models.transformer import _attention
 from tpudist.ops.ring_attention import make_ring_attention
 from tpudist.parallel import build_mesh
@@ -110,7 +109,7 @@ def test_zigzag_halves_causal_attention_flops(ctx_mesh):
         from tpudist.ops.ring_attention import ring_attention_local
         spec = P(None, "context", None, None)
 
-        @functools.partial(compat.shard_map, mesh=ctx_mesh,
+        @functools.partial(jax.shard_map, mesh=ctx_mesh,
                            in_specs=(spec, spec, spec), out_specs=spec,
                            check_vma=False)
         def f(q, k, v):
@@ -120,7 +119,7 @@ def test_zigzag_halves_causal_attention_flops(ctx_mesh):
                                         layout=layout, unroll=True)
         sh = NamedSharding(ctx_mesh, spec)
         args = [jax.device_put(x, sh) for x in (q, k, v)]
-        cost = compat.cost_analysis(jax.jit(f).lower(*args).compile())
+        cost = jax.jit(f).lower(*args).compile().cost_analysis()
         return cost.get("flops")
 
     dense_fl = flops_of("contig")

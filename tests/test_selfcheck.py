@@ -20,7 +20,7 @@ def test_refuses_off_tpu():
     """Backend != tpu exits 2 — distinct from a check failure (1) — and
     does not run any check."""
     env = dict(os.environ)
-    env["TPUDIST_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "tpudist.selfcheck"],
         cwd=REPO, env=env,

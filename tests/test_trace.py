@@ -414,7 +414,7 @@ def test_traced_run_exports_pod_trace(traced_run):
     assert json.load(open(traced_run / "trace.worker0.json"))
     spans = report_mod.complete_events(doc)
     names = {e["name"] for e in spans}
-    # the phase taxonomy the tentpole promises: staging, dispatch and
+    # the phase categories the tentpole promises: staging, dispatch and
     # checkpoint phases are all present as spans, one track per host
     assert {"stage_slab", "dispatch", "fence", "epoch",
             "ckpt_enqueue", "ckpt_drain"} <= names

@@ -107,8 +107,7 @@ def profile_step(cfg, trace_dir: str, n_steps: int = 5):
 
 def main(argv: Optional[list] = None) -> int:
     from tpudist.config import parse_args
-    from tpudist.utils import maybe_force_platform, tune_tpu
-    maybe_force_platform()
+    from tpudist.utils import tune_tpu
     tune_tpu()
 
     p = argparse.ArgumentParser(add_help=False)

@@ -174,8 +174,7 @@ def gate(records: List[dict], min_pct_peak: float) -> dict:
 
 
 def main(argv=None) -> int:
-    from tpudist.utils import maybe_force_platform, tune_tpu
-    maybe_force_platform()
+    from tpudist.utils import tune_tpu
     tune_tpu()
     # multi-host slices need distributed init (all workers run the sweep;
     # the collectives span the full pod); single-host this is a no-op

@@ -115,12 +115,12 @@ def _attempt(python: str, save_dir: str, *, extra: Sequence[str] = (),
     TPUDIST_* environment (outer chaos/live/kill knobs must not leak
     into a drill) and its output kept next to the artifacts."""
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    keep = {"TPUDIST_PLATFORM", "TPUDIST_COMPILATION_CACHE_DIR"}
     for k in list(env):
-        if k.startswith("TPUDIST_") and k not in keep:
+        if k.startswith("TPUDIST_"):
             env.pop(k)
-    env.setdefault("TPUDIST_PLATFORM", "cpu")
+    # the drill's mesh is scripted CPU devices; a parent that holds a
+    # chip (selfcheck) must not have its children reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     # drills are import/compile-dominated by construction; the goodput
     # gate must grade the WIRING here, not this host's startup latency

@@ -154,9 +154,6 @@ class TrainConfig:
     steps_per_dispatch: int = 0   # superstep length k: one compiled
     # lax.scan dispatch covers k train steps (engine.make_superstep).
     # 0 = auto (resolve_steps_per_dispatch); 1 = per-step dispatch.
-    compilation_cache_dir: Optional[str] = None  # persistent XLA
-    # compilation cache (also via TPUDIST_COMPILATION_CACHE_DIR); repeat
-    # runs skip recompiles entirely
     staging_budget_mb: Optional[float] = None  # per-device MB of batch
     # staging memory (sharding.plan_slabs). None = $TPUDIST_STAGING_BUDGET_MB,
     # else auto from device memory stats minus the train-state estimate
@@ -811,11 +808,6 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> TrainConfig:
                         "compute (default: $TPUDIST_STAGING_BUDGET_MB, "
                         "else auto from device memory stats minus the "
                         "params/opt-state estimate)")
-    p.add_argument("--compilation-cache-dir", type=str,
-                   default=None,
-                   help="persistent XLA compilation cache directory "
-                        "(default: $TPUDIST_COMPILATION_CACHE_DIR); repeat "
-                        "runs reuse compiled programs instead of retracing")
     p.add_argument("--stall-timeout-s", type=float, default=None,
                    help="flight-recorder watchdog: no step progress for "
                         "this long dumps thread stacks + memory stats + "
@@ -925,7 +917,6 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> TrainConfig:
         profile_dir=args.profile_dir,
         profile_window=args.profile_window,
         steps_per_dispatch=args.steps_per_dispatch,
-        compilation_cache_dir=args.compilation_cache_dir,
         staging_budget_mb=args.staging_budget_mb,
         stall_timeout_s=args.stall_timeout_s,
         heartbeat_dir=args.heartbeat_dir,

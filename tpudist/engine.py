@@ -522,12 +522,32 @@ def _build_step_body(cfg: TrainConfig, mesh: Mesh):
     return body, dp, st_sh
 
 
+class OnMesh:
+    """A jitted program that is always traced, lowered and dispatched
+    inside its mesh's context (``jax.set_mesh``). The context is what a
+    kernel that GSPMD cannot partition reads to run per shard
+    (models.transformer._flash_per_shard), and it is part of jit's trace
+    key — so every entry to the program goes through here, the run-end
+    ``.lower()`` hooks included, and one program stays one trace."""
+
+    def __init__(self, jitted, mesh: Mesh):
+        self.jitted, self.mesh = jitted, mesh
+
+    def __call__(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jitted(*args)
+
+    def lower(self, *args):
+        with jax.set_mesh(self.mesh):
+            return self.jitted.lower(*args)
+
+
 def _arg_specs(args):
     """Shape/dtype/sharding skeletons of a call's arguments — what
     ``jit.lower`` needs, WITHOUT keeping any buffer alive (holding the
     last staged slab would break the streaming pipeline's ≤2-resident
     guarantee; donated states are deleted but their avals survive).
-    Only NamedShardings are kept: host-created scalars (total, lo, hi)
+    Only NamedShardings are kept: host-created scalars (lo, hi)
     carry a SingleDeviceSharding that would contradict the mesh-wide
     state at lowering — the real call passes them uncommitted and the
     specs must reproduce that."""
@@ -552,7 +572,7 @@ def _cost_analysis_hook(jitted, cell) -> Callable:
         if cell[0] is None:
             return None
         try:
-            return compat.cost_analysis(jitted.lower(*cell[0]).compile())
+            return jitted.lower(*cell[0]).compile().cost_analysis()
         except Exception:
             return None
     return cost_analysis
@@ -613,9 +633,8 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh) -> Callable:
             # batch specs are built per-leaf (x is 2-D, labels are 1-D);
             # re-wrapping per trace is free — jit caches by structure.
             bspecs = jax.tree.map(lambda x: shd.batch_spec(x.ndim), batch)
-            spmd = compat.shard_map(body, mesh=mesh,
-                                    in_specs=(P(), bspecs),
-                                    out_specs=(P(), P()), check_vma=False)
+            spmd = jax.shard_map(body, mesh=mesh, in_specs=(P(), bspecs),
+                                 out_specs=(P(), P()), check_vma=False)
             return spmd(state, batch)
         # donate the incoming state like the general path does: the update
         # writes in place instead of carrying two copies of params+opt
@@ -626,6 +645,7 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh) -> Callable:
         jitted = jax.jit(body, in_shardings=(st_sh, None),
                          out_shardings=(st_sh, NamedSharding(mesh, P())),
                          donate_argnums=(0,))
+    jitted = OnMesh(jitted, mesh)
 
     _specs: list = [None]
 
@@ -742,26 +762,32 @@ def make_superstep(cfg: TrainConfig, mesh: Mesh, k: int) -> Callable:
             scan_body, (state, total), (slab, jnp.arange(n)))
         return state, total, losses
 
+    rep = NamedSharding(mesh, P())
     if dp:
         def jitted(state, total, slab, lo, hi):
             sspecs = jax.tree.map(lambda x: shd.epoch_spec(x.ndim), slab)
-            spmd = compat.shard_map(super_body, mesh=mesh,
-                                    in_specs=(P(), P(), sspecs, P(), P()),
-                                    out_specs=(P(), P(), P()),
-                                    check_vma=False)
+            spmd = jax.shard_map(super_body, mesh=mesh,
+                                 in_specs=(P(), P(), sspecs, P(), P()),
+                                 out_specs=(P(), P(), P()),
+                                 check_vma=False)
             return spmd(state, total, slab, lo, hi)
         jitted = jax.jit(jitted, donate_argnums=(0, 1))
     else:
-        rep = NamedSharding(mesh, P())
         jitted = jax.jit(super_body,
                          in_shardings=(st_sh, rep, None, None, None),
                          out_shardings=(st_sh, rep, rep),
                          donate_argnums=(0, 1))
+    jitted = OnMesh(jitted, mesh)
 
     _specs: list = [None]
 
     def superstep(state, total, slab, lo, hi):
-        args = (state, total, slab, jnp.int32(lo), jnp.int32(hi))
+        # the carry comes back typed with the mesh, and jit keys its trace
+        # on that: a caller's fresh off-mesh zero is placed here so the
+        # first dispatch and every later one share one program (a no-op
+        # for a carry that is already there)
+        args = (state, jax.device_put(total, rep), slab, jnp.int32(lo),
+                jnp.int32(hi))
         if _specs[0] is None:
             _specs[0] = _arg_specs(args)
         return jitted(*args)
@@ -775,7 +801,8 @@ def make_superstep(cfg: TrainConfig, mesh: Mesh, k: int) -> Callable:
 def make_eval_fn(cfg: TrainConfig, mesh: Mesh) -> Callable:
     """(state, batch) -> global mean loss, no update."""
     loss_fn = make_loss_fn(cfg, mesh)
-    jitted = jax.jit(lambda state, batch: loss_fn(state.params, batch))
+    jitted = OnMesh(
+        jax.jit(lambda state, batch: loss_fn(state.params, batch)), mesh)
 
     def ev(state, batch):
         return jitted(state, shd.put_batch(mesh, batch))

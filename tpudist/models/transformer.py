@@ -26,6 +26,7 @@ Stacked-layer params use a leading ``n_layers`` dim and the forward uses
 
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import jax
@@ -135,6 +136,48 @@ def _use_flash(q_shape, k_shape, causal: bool = True) -> bool:
     return fa.supports(q_shape, k_shape, causal=causal)
 
 
+def _flash_per_shard(q, k, v, cos, sin, causal: bool):
+    """The flash kernel under whatever mesh the program is traced in.
+
+    GSPMD cannot partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" — the
+    first multi-chip run, PR 21: eval, jit + shardings training and serve
+    prefill all died on it). So wherever the ambient mesh (``jax.set_mesh``,
+    which the train and serve engines enter around their compiled
+    programs) has several devices and axes still Auto, the kernel runs
+    per shard under a shard_map over EVERY such axis (the Mosaic lowering
+    accepts nothing less than a fully manual mesh): batch over data x
+    fsdp and heads over tensor where they divide, replicated otherwise —
+    attention is independent per sequence and per kv group. Inside a
+    region already manual over the mesh (the DP shard_map step, whose
+    ambient axes read Manual), on one device and with no ambient mesh it
+    is the plain call."""
+    from tpudist.ops.pallas.flash_attention import flash_attention
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                     if t == jax.sharding.AxisType.Auto)
+    if not auto or mesh.size == 1:
+        return flash_attention(q, k, v, cos=cos, sin=sin, causal=causal)
+    batch = tuple(a for a in ("data", "fsdp") if a in auto)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch):
+        batch = ()
+    heads = None
+    if "tensor" in auto and not (q.shape[2] % mesh.shape["tensor"]
+                                 or k.shape[2] % mesh.shape["tensor"]):
+        heads = "tensor"
+    spec = P(batch or None, None, heads, None)
+    rope = () if cos is None else (cos, sin)
+
+    def per_shard(q, k, v, *rope):
+        cos, sin = rope or (None, None)
+        return flash_attention(q, k, v, cos=cos, sin=sin, causal=causal)
+
+    return jax.shard_map(
+        per_shard, in_specs=(spec, spec, spec) + (P(),) * len(rope),
+        out_specs=spec, axis_names=auto,
+        check_vma=False)(q, k, v, *rope)
+
+
 def _attention(q, k, v, *, causal: bool = True, cos=None, sin=None):
     """Local attention. q: (batch, seq, heads, head_dim); k/v may carry
     fewer (grouped-query) kv heads and are expanded here. On TPU, aligned
@@ -150,8 +193,7 @@ def _attention(q, k, v, *, causal: bool = True, cos=None, sin=None):
     flash kernel on TPU (saves the rotated tensors' HBM round-trip),
     applied up front otherwise."""
     if _use_flash(q.shape, k.shape, causal):
-        from tpudist.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, cos=cos, sin=sin, causal=causal)
+        return _flash_per_shard(q, k, v, cos, sin, causal)
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -757,10 +799,8 @@ def cp_attention(impl: str, axis: str, n_ctx: int, s_local: int,
     non-adjacent chunks; ulysses shards are contiguous). Shared by the
     transformer and MoE cp loss builders.
 
-    ``rank`` is this shard's index on ``axis``, passed in by the cp
-    scaffolding as a sharded-iota input: deriving it via
-    ``lax.axis_index`` inside the partially-manual cp shard_map lowers to
-    a PartitionId instruction old jax's SPMD partitioner rejects."""
+    ``rank`` is this shard's index on ``axis``; the cp scaffolding
+    passes it in as a sharded-iota input (None = ``lax.axis_index``)."""
     me = lax.axis_index(axis) if rank is None else rank
     if impl == "ring":
         from tpudist.ops.ring_attention import (ring_attention_local,
@@ -795,8 +835,6 @@ def make_cp_loss(mesh, shard_loss_fn, *, axis: str = "context",
     """
     if impl not in CP_IMPLS:
         raise ValueError(f"unknown cp impl {impl!r}: {' | '.join(CP_IMPLS)}")
-    from tpudist.utils import compat
-    compat.check_partial_auto(mesh, axis, "context parallelism")
     n_ctx = mesh.shape[axis]
 
     def loss(params, tokens: jax.Array) -> jax.Array:
@@ -814,7 +852,7 @@ def make_cp_loss(mesh, shard_loss_fn, *, axis: str = "context",
             local = shard_loss_fn(params, inputs, targets, attn, pos, off)
             return lax.pmean(local, axis)
 
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(None, axis), P(None, axis), P(axis)),
             out_specs=P(), axis_names=frozenset({axis}),

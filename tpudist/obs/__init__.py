@@ -144,8 +144,8 @@ class PodObserver:
             # in every timing record, None = not derived (parsers must
             # not key-error on degraded runs)
             return {"hbm_peak_bytes": None, "hbm_bytes_in_use": None,
+                    "hbm_peak_bytes_per_device": None,
                     "hbm_bytes_reserved": None,
-                    "hbm_fragmentation_bytes": None,
                     "hbm_limit_bytes": None, "hbm_peak_fraction": None,
                     "hbm_source": "off"}
         self.hbm.sample()   # final watermark before the record is cut

@@ -669,7 +669,7 @@ def run_drill(run_dir: str, *, python: Optional[str] = None,
 
     def run_attempt(extra_flags, env_extra):
         env = dict(os.environ)
-        env.setdefault("TPUDIST_PLATFORM", "cpu")
+        env.setdefault("JAX_PLATFORMS", "cpu")
         env["TPUDIST_RUN_ID"] = DRILL_RUN_ID
         env.update(env_extra)
         start = time.time()

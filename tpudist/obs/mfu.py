@@ -3,7 +3,7 @@
 ``bench.py`` has always reported an *analytic* MFU (FLOPs counted from
 the model formula). The training run can do better: the superstep is
 already compiled, and XLA's cost analysis on that exact executable
-(``utils.compat.cost_analysis``) reports the FLOPs and bytes the program
+(``Compiled.cost_analysis()``) reports the FLOPs and bytes the program
 actually executes — remat recompute, masked padding steps, fused
 epilogues and all. Divided by the ``StepTimer``'s steady-state wall
 time, that yields model-FLOP utilization and achieved HBM bytes/s per

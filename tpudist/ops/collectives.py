@@ -34,8 +34,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from tpudist.utils import compat
-
 KINDS = ("all_reduce", "all_gather", "reduce_scatter", "all_to_all",
          "ppermute")
 
@@ -88,7 +86,7 @@ def build_op(kind: str, mesh: Mesh, axis: str, *, message_bytes: int,
             def body(v):
                 return lax.psum_scatter(v[0], axis, tiled=True)
             out_spec = P(axis)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=P(axis, None),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis, None),
                            out_specs=out_spec, check_vma=False)
     elif kind == "all_gather":
         # shards of E/n gather into the full E buffer on every device
@@ -96,7 +94,7 @@ def build_op(kind: str, mesh: Mesh, axis: str, *, message_bytes: int,
 
         def body(v):
             return lax.all_gather(v, axis, tiled=True)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=P(axis),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                            out_specs=P(None), check_vma=False)
     elif kind == "all_to_all":
         # each device's send buffer is E (global n·E), exchanged n-ways
@@ -105,14 +103,14 @@ def build_op(kind: str, mesh: Mesh, axis: str, *, message_bytes: int,
         def body(v):
             return lax.all_to_all(v, axis, split_axis=0, concat_axis=0,
                                   tiled=True)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=P(axis),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                            out_specs=P(axis), check_vma=False)
     else:  # ppermute: each device passes its E-buffer one hop around the ring
         x = _sharded_iota(n * elems, P(axis))
 
         def body(v):
             return lax.ppermute(v, axis, perm=_ring_perm(n))
-        fn = compat.shard_map(body, mesh=mesh, in_specs=P(axis),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                            out_specs=P(axis), check_vma=False)
 
     return jax.jit(fn), x, elems * item

@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpudist.utils import compat
-
 NEG = -1e30
 
 # The kernels' working set (double-buffered q/k/v/out blocks + f32
@@ -54,7 +52,7 @@ NEG = -1e30
 # they compile whether or not the process set
 # --xla_tpu_scoped_vmem_limit_kib (tpudist.utils.tune_tpu); v5e VMEM is
 # 128 MiB total.
-_COMPILER_PARAMS = compat.tpu_compiler_params(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary"),
     vmem_limit_bytes=100 * 1024 * 1024,
 )
@@ -253,6 +251,7 @@ def _fwd(q, k, v, cos, sin, *, scale, block_b, block_q, block_k, causal,
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal, rope=rope,
                           single=single, rep=rep),
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -492,6 +491,7 @@ def _bwd(scale, block_b, block_q, block_k, causal, interpret, res, ct):
             functools.partial(_dqkv_kernel, scale=scale, block_q=block_q,
                               block_k=block_k, causal=causal, rope=rope,
                               rep=rep),
+            name="flash_dqkv",
             grid=(_cdiv(bh, block_b),),
             in_specs=in_specs1,
             out_specs=[qspec1, kspec1, kspec1],
@@ -501,7 +501,7 @@ def _bwd(scale, block_b, block_q, block_k, causal, interpret, res, ct):
                 jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
             ],
             interpret=interpret,
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
                 vmem_limit_bytes=100 * 1024 * 1024),
         )(*args1)
@@ -528,6 +528,7 @@ def _bwd(scale, block_b, block_q, block_k, causal, interpret, res, ct):
         functools.partial(_dq_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal, rope=rope,
                           single=single_q, rep=rep),
+        name="flash_dq",
         grid=(_cdiv(bh, block_b), _cdiv(s, block_q), _cdiv(sk, block_k)),
         in_specs=in_specs,
         out_specs=qspec,
@@ -559,6 +560,7 @@ def _bwd(scale, block_b, block_q, block_k, causal, interpret, res, ct):
         functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal, rope=rope,
                           single=single_kv, rep=rep),
+        name="flash_dkv",
         grid=(_cdiv(bh, block_b), _cdiv(sk, block_k), _cdiv(s, block_q)),
         in_specs=in_specs_t,
         out_specs=[kvout, kvout],

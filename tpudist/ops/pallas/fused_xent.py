@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpudist.utils import compat
-
 NEG = -1e30
 
 # Self-contained VMEM budget (see flash_attention._COMPILER_PARAMS): the
@@ -53,7 +51,7 @@ NEG = -1e30
 # re-streams the full (tokens, d) h (dE pass) or (vocab, d) embedding
 # (fwd/dh passes) through HBM: at the pre-tune block_t=256 that re-read
 # traffic alone was ~15 GB (≈18 ms) per kernel at bench shapes.
-_COMPILER_PARAMS = compat.tpu_compiler_params(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=100 * 1024 * 1024,
 )
@@ -121,6 +119,7 @@ def _fwd(h: jax.Array, emb: jax.Array, targets: jax.Array, *,
 
     loss, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, vocab=v, block_v=block_v),
+        name="fused_xent_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, d), lambda i, j: (i, 0),
@@ -243,6 +242,7 @@ def _bwd_call(h, emb, tgt2, lse, ct2, *, block_v_bwd, block_t_bwd,
     return pl.pallas_call(
         functools.partial(_bwd_kernel, vocab=v, block_v=block_v_bwd,
                           tokens=t, block_t=bt),
+        name="fused_xent_bwd",
         grid=(nig, _cdiv(v, block_v_bwd)),
         in_specs=[
             pl.BlockSpec((bt, d), col_i, memory_space=pltpu.VMEM),

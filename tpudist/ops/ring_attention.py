@@ -62,8 +62,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from tpudist.utils import compat
-
 NEG = -1e30
 
 
@@ -185,15 +183,12 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
     the shapes don't qualify); False forces the einsum reference path.
 
     ``rank``: this shard's index on ``axis``. None = derive via
-    ``lax.axis_index``, which is correct whenever it lowers — but under a
-    PARTIALLY-manual shard_map on old jax the SPMD partitioner rejects
-    the resulting PartitionId instruction, so partial-auto callers (the
-    context-parallel loss builders) pass the rank in as a sharded-iota
-    input instead (see models.transformer.make_cp_loss).
+    ``lax.axis_index``; the context-parallel loss builders pass it in as
+    a sharded-iota input (see models.transformer.make_cp_loss).
     """
     if layout not in ("zigzag", "contig"):
         raise ValueError(f"unknown ring layout {layout!r}")
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     if use_flash is None:
         use_flash = _auto_use_flash(q.shape, k.shape, layout, causal, n)
     elif use_flash and not flash_hops_supported(q.shape, k.shape,
@@ -243,7 +238,7 @@ def _ring_sweep(k, v, axis: str, state, consume, *, start: int,
     local block inside the sweep (contig); ``start=1`` expects the
     caller to have consumed it already (zigzag local specialisation)
     and begins with one rotation."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(j, (j + 1) % n) for j in range(n)]
     if start:
         k = lax.ppermute(k, axis, perm=perm)
@@ -264,7 +259,7 @@ def _ring_contig(q, k, v, axis: str, *, causal: bool,
                  unroll: int | bool = False, rank=None) -> jax.Array:
     """Contiguous-shard ring: every rank consumes every kv block (the only
     option without causality; under causality prefer zigzag)."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis) if rank is None else rank
     b, s, h, d = q.shape
     rep = h // k.shape[2]
@@ -295,7 +290,7 @@ def _ring_contig(q, k, v, axis: str, *, causal: bool,
 def _ring_zigzag(q, k, v, axis: str, *,
                  unroll: int | bool = False, rank=None) -> jax.Array:
     """Zigzag-layout causal ring (see module docstring for the schedule)."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis) if rank is None else rank
     b, s, h, d = q.shape
     if s % 2:
@@ -405,7 +400,7 @@ def _ring_zigzag_flash(q, k, v, axis: str, *,
     causal mask is exactly the zigzag local mask (lo×lo triangle, hi×lo
     full, lo×hi masked, hi×hi triangle). Remote hops are the two fully
     unmasked chunk calls of the zigzag schedule."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis) if rank is None else rank
     b, s, h, d = q.shape
     if s % 2:
@@ -477,7 +472,7 @@ def make_ring_attention(mesh: Mesh, axis: str = "context", *,
     spec = P(None, axis, None, None)
     zig = layout == "zigzag" and causal and n > 1
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def f(q, k, v):

@@ -29,8 +29,6 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-from tpudist.utils import compat
-
 
 def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       axis: str, *, causal: bool = True,
@@ -46,21 +44,13 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if attn_impl is None:
         from tpudist.models.transformer import _attention
         attn_impl = _attention
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     for name, x in (("q heads", q.shape[2]), ("kv heads", k.shape[2])):
         if x % n:
             raise ValueError(
                 f"ulysses needs {name} ({x}) divisible by the context "
                 f"axis size ({n}); use --cp-impl ring when the head "
                 f"count doesn't factor over the axis")
-    if not compat.PARTIAL_AUTO_ALL_TO_ALL:
-        # raise BEFORE building the all_to_all program: the old SPMD
-        # partitioner hard-aborts the process on it (uncatchable), which
-        # would take the whole test run down with it
-        raise NotImplementedError(
-            "ulysses context parallelism needs lax.all_to_all inside a "
-            "partially-manual shard_map, which this jax version's SPMD "
-            "partitioner cannot lower; use --cp-impl ring")
 
     def seq_to_heads(x):
         # (b, s/n, h, hd) -> (b, s, h/n, hd)

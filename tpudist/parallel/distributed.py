@@ -24,19 +24,6 @@ from typing import Optional
 import jax
 
 
-def _is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with old-jax fallback (0.4.x
-    predates the predicate; the global state's client being set is what
-    the new predicate checks)."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        return jax.distributed.global_state.client is not None
-    except Exception:
-        return False
-
-
 @dataclass(frozen=True)
 class DistContext:
     process_index: int
@@ -79,7 +66,7 @@ def initialize(coordinator_address: Optional[str] = None,
     want_multiprocess = (coordinator_address is not None
                          or (num_processes or 1) > 1 or on_tpu_pod)
 
-    if want_multiprocess and not _is_initialized():
+    if want_multiprocess and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
@@ -150,7 +137,7 @@ def shutdown() -> None:
     """Clean teardown (parity: reference ``train.py:131-140``
     destroy_process_group, equally best-effort)."""
     try:
-        if _is_initialized():
+        if jax.distributed.is_initialized():
             jax.distributed.shutdown()
     except Exception:
         pass

@@ -25,7 +25,6 @@ import jax
 from jax.sharding import Mesh
 
 from tpudist.config import ParallelConfig
-from tpudist.utils import compat
 
 # canonical axis order, most-global first
 AXIS_NAMES: Tuple[str, ...] = ("data", "pipe", "fsdp", "expert", "tensor",
@@ -76,8 +75,8 @@ def build_mesh(cfg: Optional[ParallelConfig] = None,
         # give no such guarantee and could put tensor-parallel collectives
         # on DCN). Axis types stay Auto: FSDP/TP rely on GSPMD propagation
         # (make_mesh defaults to Explicit, which type-rejects those layouts).
-        auto = (compat.AxisType.Auto,) * len(AXIS_NAMES)
-        return compat.make_mesh(sizes, AXIS_NAMES, axis_types=auto)
+        auto = (jax.sharding.AxisType.Auto,) * len(AXIS_NAMES)
+        return jax.make_mesh(sizes, AXIS_NAMES, axis_types=auto)
     import numpy as np
     return Mesh(np.asarray(devices).reshape(sizes), AXIS_NAMES)
 

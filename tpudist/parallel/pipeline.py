@@ -176,8 +176,6 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *,
     from tpudist.models import transformer as T
 
     is_moe = cfg.name == "moe"
-    from tpudist.utils import compat
-    compat.check_partial_auto(mesh, axis, "pipeline parallelism")
     n_stages = mesh.shape[axis]
     v = int(interleave)
     if v < 1:
@@ -262,9 +260,9 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *,
         x_emb = params["embed"].astype(dtype)[inputs]     # (b, s, d)
 
         def body(params, x_emb, targets, ranks):
-            # sharded-iota stage index: lax.axis_index inside this
-            # partially-manual shard_map lowers to a PartitionId the old
-            # SPMD partitioner rejects (see utils.compat)
+            # sharded-iota stage index: the rank rides in as data
+            # instead of lax.axis_index (a PartitionId under this
+            # partially-manual shard_map)
             stage = ranks[0]
             b, s, _ = x_emb.shape
             mb_x = x_emb.reshape(n_micro, b // n_micro, s, cfg.d_model)
@@ -380,11 +378,11 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *,
         # leading dim; embed/final_norm are replicated over pipe (the tied
         # table is consumed at both ring ends)
         pspecs = {"embed": P(), "layers": P(axis), "final_norm": P()}
-        return compat.shard_map(body, mesh=mesh,
-                                in_specs=(pspecs, P(), P(), P(axis)),
-                                out_specs=P(),
-                                axis_names=frozenset({axis}),
-                                check_vma=False)(
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(pspecs, P(), P(), P(axis)),
+                             out_specs=P(),
+                             axis_names=frozenset({axis}),
+                             check_vma=False)(
             params, x_emb, targets,
             jnp.arange(n_stages, dtype=jnp.int32))
 

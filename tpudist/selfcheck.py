@@ -749,9 +749,9 @@ CHECKS = [
 
 
 def main(argv=None) -> int:
-    from tpudist.utils import maybe_force_platform, tune_tpu
-    maybe_force_platform()
+    from tpudist.utils import enable_compilation_cache, tune_tpu
     tune_tpu()
+    enable_compilation_cache()
     # Multi-host slices: every worker runs this (libtpu on a pod worker
     # cannot initialize standalone — a lone process hangs waiting for the
     # rest of the slice). The checks themselves are host-local jits; with

@@ -595,11 +595,9 @@ def _write_bench(path: str, args: argparse.Namespace,
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
-    from tpudist.utils import (maybe_enable_compilation_cache,
-                               maybe_force_platform, tune_tpu)
-    maybe_force_platform()
+    from tpudist.utils import enable_compilation_cache, tune_tpu
     tune_tpu()
-    maybe_enable_compilation_cache()
+    enable_compilation_cache()
     args = parse_args(argv)
     verdict_path = os.environ.get("TPUDIST_VERDICT_PATH")
     status = slo_lib.FAIL

@@ -136,12 +136,12 @@ def _attempt(python: str, save_dir: str, flags: Sequence[str], *,
     makes TTFT/ITL deterministic, so the ceilings can be TIGHT in
     virtual seconds without grading this host's load."""
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    keep = {"TPUDIST_PLATFORM", "TPUDIST_COMPILATION_CACHE_DIR"}
     for k in list(env):
-        if k.startswith("TPUDIST_") and k not in keep:
+        if k.startswith("TPUDIST_"):
             env.pop(k)
-    env.setdefault("TPUDIST_PLATFORM", "cpu")
+    # the drill's mesh is scripted CPU devices; a parent that holds a
+    # chip (selfcheck) must not have its children reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     env["TPUDIST_LIVE"] = "on"
     env["TPUDIST_TTFT_P99_MAX"] = "0.5"

@@ -42,7 +42,7 @@ import numpy as np
 from jax import lax
 
 from tpudist.config import ModelConfig
-from tpudist.engine import _arg_specs
+from tpudist.engine import OnMesh, _arg_specs
 from tpudist.models import get_model
 from tpudist.parallel import sharding as shd
 from tpudist.serve import kvcache
@@ -131,11 +131,13 @@ class ServeEngine:
         # first call (program_memory / the memledger's per-program
         # memory_analysis reads these off the request clock)
         self._programs: dict = {}
-        self._prefill = jax.jit(self._prefill_body, donate_argnums=(1,))
+        self._prefill = OnMesh(
+            jax.jit(self._prefill_body, donate_argnums=(1,)), mesh)
         # k is STATIC (it is the lax.scan length): one compiled decode
         # program per ladder rung, all traced at warmup
-        self._decode = jax.jit(self._decode_body, static_argnums=(2,),
-                               donate_argnums=(1,))
+        self._decode = OnMesh(
+            jax.jit(self._decode_body, static_argnums=(2,),
+                    donate_argnums=(1,)), mesh)
 
     # ----------------------------------------------------------- state
 
@@ -407,12 +409,13 @@ class PagedServeEngine(ServeEngine):
         self.page_tokens = self.spec.page_tokens
         self.alloc = kvcache.PageAllocator(self.spec)
         self.verify_traces: list = []
-        self._prefill = jax.jit(self._paged_prefill_body,
-                                donate_argnums=(1,))
-        self._decode = jax.jit(self._paged_decode_body,
-                               static_argnums=(2,), donate_argnums=(1,))
-        self._verify = jax.jit(self._paged_verify_body,
-                               donate_argnums=(1,))
+        self._prefill = OnMesh(
+            jax.jit(self._paged_prefill_body, donate_argnums=(1,)), mesh)
+        self._decode = OnMesh(
+            jax.jit(self._paged_decode_body, static_argnums=(2,),
+                    donate_argnums=(1,)), mesh)
+        self._verify = OnMesh(
+            jax.jit(self._paged_verify_body, donate_argnums=(1,)), mesh)
 
     def new_allocator(self) -> kvcache.PageAllocator:
         """Fresh page bookkeeping (drops any shared-prefix registry) —

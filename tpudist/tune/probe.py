@@ -150,8 +150,7 @@ def time_runner(runner: EpochRunner, *, repeats: int = DEFAULT_PROBE_REPEATS,
                 state: Any = None) -> Tuple[Any, List[float], float]:
     """Warm (trace+compile+stage) one epoch, then time ``repeats`` epochs.
     Returns ``(state, ms_per_step_per_epoch, compile_s)``; fencing is a
-    host transfer of the last loss (block_until_ready can return early on
-    tunneled PJRT backends)."""
+    host transfer of the last loss, as in ``StepTimer.stop_many``."""
     state = runner.init_state() if state is None else state
     t0 = time.perf_counter()
     state, loss = runner.run_epoch(state)

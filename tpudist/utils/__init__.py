@@ -1,5 +1,3 @@
-from tpudist.utils.platform import (maybe_enable_compilation_cache,
-                                    maybe_force_platform, tune_tpu)
+from tpudist.utils.platform import enable_compilation_cache, tune_tpu
 
-__all__ = ["maybe_enable_compilation_cache", "maybe_force_platform",
-           "tune_tpu"]
+__all__ = ["enable_compilation_cache", "tune_tpu"]

@@ -1,131 +1,18 @@
-"""JAX cross-version compatibility shims.
-
-The repo targets the current ``jax.shard_map`` / ``jax.sharding.AxisType``
-API surface, but CI pins jax 0.4.37 where ``shard_map`` still lives in
-``jax.experimental`` with the older keyword spelling (``check_rep`` /
-``auto``) and mesh axis types do not exist yet. Every call site imports
-these names from here instead of from ``jax`` directly, so the version
-skew is handled in exactly one place:
-
-  * :func:`shard_map` — new-style signature (``check_vma``,
-    ``axis_names`` naming the MANUAL axes). On old jax it forwards to
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and the
-    complement-set ``auto`` frozenset.
-  * :data:`AxisType` — ``jax.sharding.AxisType`` when it exists, else a
-    no-op sentinel with the same member names (old jax behaves as
-    all-Auto, so the sentinel carries no semantics).
-  * :func:`make_mesh` — forwards ``axis_types`` only when the installed
-    ``jax.make_mesh`` accepts it (on old jax Auto is the only behavior,
-    so dropping the kwarg is exact).
-"""
+"""Normaliser for ``Compiled.memory_analysis()``, whose return shape
+differs by backend (object, per-device list, ``None``, or a raise)."""
 
 from __future__ import annotations
-
-import inspect
-from typing import Any
-
-import jax
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-# Old jax's SPMD partitioner cannot lower collectives (ppermute,
-# all_to_all) inside a PARTIALLY-manual shard_map when any auto axis has
-# size > 1 — it hard-aborts the XLA compiler (a CHECK failure, not a
-# catchable trace error), which would take the whole process down.
-# Features that need the combination (context/pipeline parallelism
-# composed with data/fsdp sharding, ulysses all-to-alls) gate on these
-# and raise a clean NotImplementedError at trace time instead. The
-# companion PartitionId limitation (lax.axis_index under partial-auto)
-# IS worked around — the rank rides in as a sharded-iota input (see
-# models.transformer.make_cp_loss) — but the collectives have no such
-# alternate spelling.
-PARTIAL_AUTO_ALL_TO_ALL = _NEW_SHARD_MAP
-PARTIAL_AUTO_COLLECTIVES = _NEW_SHARD_MAP
-
-
-def check_partial_auto(mesh, axis: str, feature: str) -> None:
-    """Raise a clean NotImplementedError when a partially-manual
-    shard_map over ``axis`` would need collectives alongside auto axes of
-    size > 1 on a jax version whose partitioner hard-aborts on that
-    (see :data:`PARTIAL_AUTO_COLLECTIVES`)."""
-    if PARTIAL_AUTO_COLLECTIVES:
-        return
-    big = [a for a in mesh.axis_names
-           if a != axis and mesh.shape[a] > 1]
-    if big:
-        raise NotImplementedError(
-            f"{feature} composed with sharded axes {big} needs "
-            f"collectives inside a partially-manual shard_map, which "
-            f"this jax version's SPMD partitioner cannot lower; use a "
-            f"mesh with only the '{axis}' axis > 1, or a newer jax")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names=None):
-    """New-API ``jax.shard_map`` with old-jax fallback.
-
-    ``axis_names`` names the axes manualized in the body (new-API
-    meaning); ``None`` means all mesh axes. On old jax this becomes
-    ``auto = mesh.axis_names - axis_names`` and ``check_vma`` maps to
-    ``check_rep`` (same semantics: static replication/varying-axes
-    checking of the body's outputs).
-    """
-    if _NEW_SHARD_MAP:
-        kw: dict[str, Any] = dict(mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _old_shard_map(f, mesh, in_specs, out_specs,
-                          check_rep=check_vma, auto=auto)
-
-
-try:
-    AxisType = jax.sharding.AxisType
-except AttributeError:
-    class AxisType:  # type: ignore[no-redef]
-        """Sentinel standing in for ``jax.sharding.AxisType`` on jax
-        versions that predate typed mesh axes (everything is Auto there,
-        so the values are never consumed)."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def axis_size(name):
-    """``jax.lax.axis_size`` with old-jax fallback: ``psum(1, name)`` is
-    the classic spelling — jax special-cases a psum of a literal into the
-    static axis size at trace time, so this stays a Python int for
-    control flow either way."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as one flat dict: old jax returns a
-    list with one properties-dict per device program, new jax returns the
-    dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost or {}
 
 
 def memory_analysis(compiled) -> dict:
     """``Compiled.memory_analysis()`` as one flat byte-count dict, or
     ``{}`` when the backend can't say.
 
-    New jax returns an object with ``*_size_in_bytes`` attributes; some
-    versions return a per-device list of them; CPU builds may return
-    ``None`` or raise (memory planning is an XLA:TPU/GPU feature). The
-    ledger treats a missing analysis as zero known temp with the gap
-    flagged, so this normalizer degrades to ``{}`` rather than raising.
+    A backend returns an object with ``*_size_in_bytes`` attributes, a
+    per-device list of them, ``None``, or raises (memory planning is an
+    XLA:TPU/GPU feature). The ledger treats a missing analysis as zero
+    known temp with the gap flagged, so this normalizer degrades to
+    ``{}`` rather than raising.
     """
     try:
         mem = compiled.memory_analysis()
@@ -149,29 +36,3 @@ def memory_analysis(compiled) -> dict:
             except (TypeError, ValueError):
                 pass
     return out
-
-
-def tpu_compiler_params(**kw):
-    """``pltpu.CompilerParams`` (new name) / ``pltpu.TPUCompilerParams``
-    (old name) — same constructor kwargs either way. Lazy import: pallas
-    must not load for callers that never touch the kernels."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
-_MAKE_MESH_PARAMS = inspect.signature(jax.make_mesh).parameters
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-    """``jax.make_mesh`` that tolerates the missing ``axis_types`` kwarg
-    on old jax (where Auto — the only type we ever request — is the
-    implicit behavior)."""
-    kw: dict[str, Any] = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
-        kw["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
