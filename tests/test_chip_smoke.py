@@ -27,8 +27,13 @@ def test_compilation_cache_dir_rule(monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
     platform.enable_compilation_cache()
     assert "jax_compilation_cache_dir" not in calls
+    # the key covers the ops' name stacks (tpudist.scopes is read back from
+    # captures) and no Python call stacks (tests/test_scopes.py has the
+    # two-process proof)
     assert calls == {"jax_persistent_cache_min_compile_time_secs": 0,
-                     "jax_persistent_cache_min_entry_size_bytes": 0}
+                     "jax_persistent_cache_min_entry_size_bytes": 0,
+                     "jax_compilation_cache_include_metadata_in_key": True,
+                     "jax_traceback_in_locations_limit": 0}
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     monkeypatch.chdir("/")      # the path must not depend on the cwd

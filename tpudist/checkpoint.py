@@ -120,13 +120,16 @@ class Checkpointer:
         """
         t0 = time.perf_counter()
         with trace_lib.span("ckpt_enqueue", cat="ckpt",
-                            step=int(state.step)):
+                            step=int(state.step)) as sp:
+            cost = trace_lib.HostCost(sp)
             self._mgr.save(int(state.step), args=ocp.args.Composite(
                 state=ocp.args.StandardSave(state),
                 meta=ocp.args.JsonSave({
                     "epoch": int(epoch),
                     "step_in_epoch": int(step_in_epoch),
                     **self.run_meta})))
+            cost.note(bytes=sum(int(getattr(x, "nbytes", 0))
+                                for x in jax.tree.leaves(state)))
         self.last_enqueue_ms = (time.perf_counter() - t0) * 1000
         self.saves += 1
 

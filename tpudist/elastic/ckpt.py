@@ -307,7 +307,8 @@ class ShardedCheckpointer:
         step = int(state.step)
         from tpudist.parallel import sharding as shd
         with trace_lib.span("ckpt_enqueue", cat="ckpt", step=step,
-                            mode="sharded"):
+                            mode="sharded") as sp:
+            cost = trace_lib.HostCost(sp)
             index: Dict[str, Any] = {}
             arrays: Dict[str, np.ndarray] = {}
             for li, (name, leaf) in enumerate(state_leaves(state)):
@@ -333,6 +334,8 @@ class ShardedCheckpointer:
                                                   np.float32))),
                     "shards": shards}
             job = (step, int(epoch), int(step_in_epoch), index, arrays)
+            # this process's shards are on the host now: the copy is done
+            cost.note(bytes=sum(int(a.nbytes) for a in arrays.values()))
             if self.use_async:
                 self._q.put(("write", job))
                 if self.process_index == 0:

@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 from tpudist.config import TrainConfig
 from tpudist.models import get_model
 from tpudist.parallel import sharding as shd
+from tpudist.scopes import scope
 from tpudist.utils import compat
 
 
@@ -432,7 +433,14 @@ def _build_step_body(cfg: TrainConfig, mesh: Mesh):
     # the logits constraint belongs to the jit+shardings path only — inside
     # the shard_map DP body every mesh axis is manual and a NamedSharding
     # constraint is rejected at trace time
-    loss_fn = make_loss_fn(cfg, mesh, constrain_logits=not dp)
+    model_loss = make_loss_fn(cfg, mesh, constrain_logits=not dp)
+
+    def loss_fn(params, batch):
+        # forward ops trace under jvp(loss), backward under
+        # transpose(jvp(loss)): how a capture tells the two apart
+        with scope("loss"):
+            return model_loss(params, batch)
+
     st_sh = None if dp else state_shardings(cfg, mesh)
     from tpudist.config import resolve_cross_slice, resolve_grad_overlap
     overlap_mode, bucket_bytes = resolve_grad_overlap(cfg)
@@ -479,8 +487,10 @@ def _build_step_body(cfg: TrainConfig, mesh: Mesh):
             cross_mode = "flat"
 
     def sgd_update(state: TrainState, loss, grads):
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         return TrainState(step=state.step + 1, params=new_params,
                           opt_state=new_opt), loss
 
@@ -607,11 +617,13 @@ def _lowered_text_hook(jitted, cell) -> Callable:
     DCN-bytes gauge carry). Lowering hits jit's trace cache after the
     first call; None before the first call or on any failure —
     observability must never fail a run."""
-    def lowered_text():
+    def lowered_text(debug_info: bool = False):
+        # debug_info: with every op's location, i.e. its name stack
+        # (tpudist.scopes) — what tests read the scopes from
         if cell[0] is None:
             return None
         try:
-            return jitted.lower(*cell[0]).as_text()
+            return jitted.lower(*cell[0]).as_text(debug_info=debug_info)
         except Exception:
             return None
     return lowered_text

@@ -35,6 +35,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpudist.config import CP_IMPLS, ModelConfig
+from tpudist.scopes import cast, scope
 
 Params = Dict
 
@@ -67,9 +68,33 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def rmsnorm(x: jax.Array, g: jax.Array, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale).astype(x.dtype) * g.astype(x.dtype)
+    with scope("norm"):
+        xf = x.astype(jnp.float32)
+        scale = jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        return (xf * scale).astype(x.dtype) * cast(g, x.dtype)
+
+
+def embed_tokens(params: Params, tokens: jax.Array, dtype) -> jax.Array:
+    """The token-embedding gather, table cast to the compute dtype."""
+    with scope("embed"):
+        return cast(params["embed"], dtype)[tokens]
+
+
+def _qkv(y, lp, b, s, h, kv, hd):
+    """The three input projections of an attention sublayer."""
+    dt = y.dtype
+    with scope("attn/qkv"):
+        q = (y @ cast(lp["wq"], dt)).reshape(b, s, h, hd)
+        k = (y @ cast(lp["wk"], dt)).reshape(b, s, kv, hd)
+        v = (y @ cast(lp["wv"], dt)).reshape(b, s, kv, hd)
+    return q, k, v
+
+
+def _attn_out(x, o, lp):
+    """Output projection + residual. o: (batch, seq, heads * head_dim)."""
+    with scope("attn/out"):
+        return x + o @ cast(lp["wo"], x.dtype)
 
 
 def _w(key, *shape, fan_in):
@@ -234,24 +259,23 @@ def _attn_sublayer(x, lp, cfg: ModelConfig, cos, sin, attn_impl,
     b, s, d = x.shape
     h, kv = cfg.n_heads, cfg.n_kv_heads
     hd = d // h
-    dt = x.dtype
 
     y = rmsnorm(x, lp["attn_norm"])
-    q = (y @ lp["wq"].astype(dt)).reshape(b, s, h, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, s, kv, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, s, kv, hd)
+    q, k, v = _qkv(y, lp, b, s, h, kv, hd)
     # GQA: compact kv heads go to the attention impl as-is — ring attention
     # must transfer the small blocks; expansion happens inside the kernel.
     if getattr(attn_impl, "accepts_rope", False) and not return_kv:
         # rope-aware impls take the tables and rotate internally (the flash
         # kernel rotates blocks in VMEM — no rotated-tensor HBM round-trip)
-        o = attn_impl(q, k, v, cos=cos, sin=sin)
+        with scope("attn/core"):
+            o = attn_impl(q, k, v, cos=cos, sin=sin)
     else:
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        o = attn_impl(q, k, v)
-    o = o.reshape(b, s, h * hd)
-    out = x + o @ lp["wo"].astype(dt)
+        with scope("attn/rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with scope("attn/core"):
+            o = attn_impl(q, k, v)
+    out = _attn_out(x, o.reshape(b, s, h * hd), lp)
     return (out, k, v) if return_kv else out
 
 
@@ -260,9 +284,10 @@ def _ffn_sublayer(x, lp, cfg: ModelConfig):
     the serving (prefill/decode) layers so the FFN math cannot fork."""
     dt = x.dtype
     y = rmsnorm(x, lp["ffn_norm"])
-    gate = jax.nn.silu(y @ lp["w_gate"].astype(dt))
-    up = y @ lp["w_up"].astype(dt)
-    return x + (gate * up) @ lp["w_down"].astype(dt)
+    with scope("ffn"):
+        gate = jax.nn.silu(y @ cast(lp["w_gate"], dt))
+        up = y @ cast(lp["w_up"], dt)
+        return x + (gate * up) @ cast(lp["w_down"], dt)
 
 
 def _layer(x, lp, cfg: ModelConfig, cos, sin, attn_impl):
@@ -320,18 +345,24 @@ def _cached_attention(q, k_new, v_new, cache_k, cache_v, pos):
     from tpudist.ops.gqa import expand_gqa
     b, t = cache_k.shape[0], cache_k.shape[1]
     slot = jnp.arange(b)
-    cache_k = cache_k.at[slot, pos].set(k_new[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[slot, pos].set(v_new[:, 0].astype(cache_v.dtype))
-    k, v = expand_gqa(q, cache_k, cache_v)
-    hd = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
-        jnp.asarray(hd, q.dtype))
-    mask = jnp.arange(t)[None, :] <= pos[:, None]            # (b, t)
-    scores = jnp.where(mask[:, None, None, :], scores,
-                       jnp.asarray(-1e30, scores.dtype))
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
-        q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v), cache_k, cache_v
+    with scope("attn/kv_write"):
+        cache_k = cache_k.at[slot, pos].set(
+            k_new[:, 0].astype(cache_k.dtype))
+        cache_v = cache_v.at[slot, pos].set(
+            v_new[:, 0].astype(cache_v.dtype))
+    with scope("attn/kv_gather"):
+        k, v = expand_gqa(q, cache_k, cache_v)
+    with scope("attn/core"):
+        hd = q.shape[-1]
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+            jnp.asarray(hd, q.dtype))
+        mask = jnp.arange(t)[None, :] <= pos[:, None]            # (b, t)
+        scores = jnp.where(mask[:, None, None, :], scores,
+                           jnp.asarray(-1e30, scores.dtype))
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+        o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return o, cache_k, cache_v
 
 
 def _attn_sublayer_cached(x, lp, cfg: ModelConfig, pos, cache_k, cache_v):
@@ -343,17 +374,14 @@ def _attn_sublayer_cached(x, lp, cfg: ModelConfig, pos, cache_k, cache_v):
     b, s, d = x.shape           # s == 1 (one appended token per slot)
     h, kv = cfg.n_heads, cfg.n_kv_heads
     hd = d // h
-    dt = x.dtype
     y = rmsnorm(x, lp["attn_norm"])
-    q = (y @ lp["wq"].astype(dt)).reshape(b, s, h, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, s, kv, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, s, kv, hd)
-    q = decode_rope(q, pos, cfg.rope_theta)
-    k = decode_rope(k, pos, cfg.rope_theta)
+    q, k, v = _qkv(y, lp, b, s, h, kv, hd)
+    with scope("attn/rope"):
+        q = decode_rope(q, pos, cfg.rope_theta)
+        k = decode_rope(k, pos, cfg.rope_theta)
     o, cache_k, cache_v = _cached_attention(q, k, v, cache_k, cache_v,
                                             pos)
-    o = o.reshape(b, s, h * hd)
-    return x + o @ lp["wo"].astype(dt), cache_k, cache_v
+    return _attn_out(x, o.reshape(b, s, h * hd), lp), cache_k, cache_v
 
 
 def _cached_hidden_states(params: Params, tokens: jax.Array,
@@ -377,7 +405,7 @@ def _cached_hidden_states(params: Params, tokens: jax.Array,
     the ONE thing the MoE model swaps; the whole cache contract lives
     here once. Returns ``(h, kv_cache')`` with ``h`` final-normed."""
     ck, cv = kv_cache["k"], kv_cache["v"]
-    x = params["embed"].astype(dtype)[tokens]
+    x = embed_tokens(params, tokens, dtype)
     unroll = cfg.n_layers <= 8
     if cur_index is None:
         s = tokens.shape[1]
@@ -391,8 +419,9 @@ def _cached_hidden_states(params: Params, tokens: jax.Array,
 
         x, (ks, vs) = lax.scan(body, x, params["layers"], unroll=unroll)
         # ks: (L, b, s, kv, hd) — seed cache columns [0, s)
-        ck = ck.at[:, :, :s].set(ks.astype(ck.dtype))
-        cv = cv.at[:, :, :s].set(vs.astype(cv.dtype))
+        with scope("attn/kv_write"):
+            ck = ck.at[:, :, :s].set(ks.astype(ck.dtype))
+            cv = cv.at[:, :, :s].set(vs.astype(cv.dtype))
     else:
         def body(x, xs):
             lp, ck_l, cv_l = xs
@@ -441,33 +470,38 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, page_table,
     trash = n_pool - 1
 
     # ---- write: new k/v land at their pages (or the trash page) ----
-    j = positions // pt                                   # (s, w)
-    off = positions % pt
-    pg = jnp.take_along_axis(page_table, j, axis=1)       # (s, w)
-    pg = jnp.where(write_ok & (pg >= 0), pg, trash)
-    pool_k = pool_k.at[pg, off].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[pg, off].set(v_new.astype(pool_v.dtype))
+    with scope("attn/kv_write"):
+        j = positions // pt                                   # (s, w)
+        off = positions % pt
+        pg = jnp.take_along_axis(page_table, j, axis=1)       # (s, w)
+        pg = jnp.where(write_ok & (pg >= 0), pg, trash)
+        pool_k = pool_k.at[pg, off].set(k_new.astype(pool_k.dtype))
+        pool_v = pool_v.at[pg, off].set(v_new.astype(pool_v.dtype))
 
-    # ---- read: ownership + position masks from one one-hot ----
-    onehot = page_table[:, :, None] == jnp.arange(n_pool)[None, None, :]
-    owned = onehot.any(axis=1)                            # (s, pool)
-    logical = jnp.einsum("sjp,j->sp", onehot.astype(jnp.int32),
-                         jnp.arange(maxp, dtype=jnp.int32))
-    kpos = logical[:, :, None] * pt + jnp.arange(pt)[None, None, :]
-    mask = owned[:, None, :, None] \
-        & (kpos[:, None, :, :] <= positions[:, :, None, None])
-    mask = mask.reshape(s, w, n_pool * pt)                # (s, w, keys)
+    # ---- read: ownership + position masks from one one-hot, and the
+    # whole pool flattened in the compute dtype ----
+    with scope("attn/kv_gather"):
+        onehot = page_table[:, :, None] \
+            == jnp.arange(n_pool)[None, None, :]
+        owned = onehot.any(axis=1)                            # (s, pool)
+        logical = jnp.einsum("sjp,j->sp", onehot.astype(jnp.int32),
+                             jnp.arange(maxp, dtype=jnp.int32))
+        kpos = logical[:, :, None] * pt + jnp.arange(pt)[None, None, :]
+        mask = owned[:, None, :, None] \
+            & (kpos[:, None, :, :] <= positions[:, :, None, None])
+        mask = mask.reshape(s, w, n_pool * pt)                # (s, w, keys)
+        kf = pool_k.reshape(n_pool * pt, kv, hd).astype(q.dtype)
+        vf = pool_v.reshape(n_pool * pt, kv, hd).astype(q.dtype)
 
-    kf = pool_k.reshape(n_pool * pt, kv, hd).astype(q.dtype)
-    vf = pool_v.reshape(n_pool * pt, kv, hd).astype(q.dtype)
-    qg = q.reshape(s, w, kv, h // kv, hd)   # GQA: group per kv head
-    scores = jnp.einsum("swkgd,nkd->swkgn", qg, kf) / jnp.sqrt(
-        jnp.asarray(hd, q.dtype))
-    scores = jnp.where(mask[:, :, None, None, :], scores,
-                       jnp.asarray(-1e30, scores.dtype))
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
-        q.dtype)
-    o = jnp.einsum("swkgn,nkd->swkgd", probs, vf).reshape(s, w, h, hd)
+    with scope("attn/core"):
+        qg = q.reshape(s, w, kv, h // kv, hd)   # GQA: group per kv head
+        scores = jnp.einsum("swkgd,nkd->swkgn", qg, kf) / jnp.sqrt(
+            jnp.asarray(hd, q.dtype))
+        scores = jnp.where(mask[:, :, None, None, :], scores,
+                           jnp.asarray(-1e30, scores.dtype))
+        probs = jax.nn.softmax(scores.astype(jnp.float32),
+                               axis=-1).astype(q.dtype)
+        o = jnp.einsum("swkgn,nkd->swkgd", probs, vf).reshape(s, w, h, hd)
     return o, pool_k, pool_v
 
 
@@ -481,18 +515,15 @@ def _attn_sublayer_paged(x, lp, cfg: ModelConfig, positions, write_ok,
     b, w, d = x.shape
     h, kv = cfg.n_heads, cfg.n_kv_heads
     hd = d // h
-    dt = x.dtype
     y = rmsnorm(x, lp["attn_norm"])
-    q = (y @ lp["wq"].astype(dt)).reshape(b, w, h, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, w, kv, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, w, kv, hd)
-    q = window_rope(q, positions, cfg.rope_theta)
-    k = window_rope(k, positions, cfg.rope_theta)
+    q, k, v = _qkv(y, lp, b, w, h, kv, hd)
+    with scope("attn/rope"):
+        q = window_rope(q, positions, cfg.rope_theta)
+        k = window_rope(k, positions, cfg.rope_theta)
     o, pool_k, pool_v = _paged_attention(q, k, v, pool_k, pool_v,
                                          page_table, positions,
                                          write_ok, page_tokens)
-    o = o.reshape(b, w, h * hd)
-    return x + o @ lp["wo"].astype(dt), pool_k, pool_v
+    return _attn_out(x, o.reshape(b, w, h * hd), lp), pool_k, pool_v
 
 
 def paged_hidden_states(params: Params, tokens: jax.Array,
@@ -510,7 +541,7 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
     target forward scoring a whole draft window). ``ffn(x, lp, cfg)``
     is the per-layer FFN half — the ONE thing the MoE model swaps.
     Returns ``(h, pool_k', pool_v')`` with ``h`` final-normed."""
-    x = params["embed"].astype(dtype)[tokens]
+    x = embed_tokens(params, tokens, dtype)
     unroll = cfg.n_layers <= 8
 
     def body(x, xs):
@@ -547,7 +578,7 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
     hd = cfg.d_model // cfg.n_heads
     cos, sin = precompute_rope(s, hd, cfg.rope_theta, offset=rope_offset,
                                positions=rope_positions)
-    x = params["embed"].astype(dtype)[tokens]
+    x = embed_tokens(params, tokens, dtype)
 
     def body(x, lp):
         return _layer(x, lp, cfg, cos, sin, attn_impl), None
@@ -559,6 +590,12 @@ def hidden_states(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
     # the single compiled body for fast compiles
     x, _ = lax.scan(body, x, params["layers"], unroll=cfg.n_layers <= 8)
     return rmsnorm(x, params["final_norm"])
+
+
+def tied_logits(params: Params, h: jax.Array, dtype) -> jax.Array:
+    """The tied output head: hidden states -> f32 logits."""
+    with scope("lm_head"):
+        return (h @ cast(params["embed"], dtype).T).astype(jnp.float32)
 
 
 def apply(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
@@ -577,13 +614,11 @@ def apply(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
         x, kv_cache = hidden_states(params, tokens, cfg, dtype=dtype,
                                     kv_cache=kv_cache,
                                     cur_index=cur_index)
-        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
-        return logits, kv_cache
+        return tied_logits(params, x, dtype), kv_cache
     x = hidden_states(params, tokens, cfg, dtype=dtype, attn_impl=attn_impl,
                       rope_offset=rope_offset, rope_positions=rope_positions,
                       remat=remat)
-    # tied output head
-    return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+    return tied_logits(params, x, dtype)
 
 
 def param_specs(cfg: ModelConfig, *, fsdp_axis: str = "fsdp",
@@ -753,29 +788,30 @@ def head_loss(emb: jax.Array, h: jax.Array, targets: jax.Array, *,
     dlogits for the tied-embed grad matmul while the xent backward produces
     it batch-sharded, and falls back to full rematerialisation of the
     tensor (dp+fsdp+tensor layouts)."""
-    if fused_xent and xent_chunks:
-        raise ValueError("--fused-xent and --xent-chunks are mutually "
-                         "exclusive LM-head strategies")
-    if fused_xent:
-        return _fused_head_xent(emb, h, targets)
-    if xent_chunks:
-        if targets.shape[1] % xent_chunks:
-            # erroring beats silently materialising the full logits tensor
-            # the flag was passed to avoid
-            raise ValueError(
-                f"sequence length {targets.shape[1]} not divisible by "
-                f"xent_chunks={xent_chunks}")
-        return _chunked_head_xent(emb, h, targets, xent_chunks)
-    # logits keep the model dtype (bf16 under mixed precision): the f32
-    # upcast stored 2× the bytes for a tensor whose only consumers — the
-    # f32 logsumexp inside _xent and the bf16 MXU matmuls of its cotangent
-    # — round exactly the same either way. Measured on v5e batch 56: the
-    # f32 logits+dlogits pair (7.3 GB) forced ~31 ms/step of XLA
-    # auto-rematerialisation.
-    logits = h @ emb.T
-    if logits_sharding is not None:
-        logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
-    return _xent(logits, targets)
+    with scope("lm_head"):
+        if fused_xent and xent_chunks:
+            raise ValueError("--fused-xent and --xent-chunks are mutually "
+                             "exclusive LM-head strategies")
+        if fused_xent:
+            return _fused_head_xent(emb, h, targets)
+        if xent_chunks:
+            if targets.shape[1] % xent_chunks:
+                # erroring beats silently materialising the full logits tensor
+                # the flag was passed to avoid
+                raise ValueError(
+                    f"sequence length {targets.shape[1]} not divisible by "
+                    f"xent_chunks={xent_chunks}")
+            return _chunked_head_xent(emb, h, targets, xent_chunks)
+        # logits keep the model dtype (bf16 under mixed precision): the f32
+        # upcast stored 2× the bytes for a tensor whose only consumers — the
+        # f32 logsumexp inside _xent and the bf16 MXU matmuls of its cotangent
+        # — round exactly the same either way. Measured on v5e batch 56: the
+        # f32 logits+dlogits pair (7.3 GB) forced ~31 ms/step of XLA
+        # auto-rematerialisation.
+        logits = h @ emb.T
+        if logits_sharding is not None:
+            logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
+        return _xent(logits, targets)
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
@@ -786,7 +822,7 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
     Head strategy selection: see :func:`head_loss`."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     h = hidden_states(params, inputs, cfg, dtype=dtype, remat=remat)
-    return head_loss(params["embed"].astype(dtype), h, targets,
+    return head_loss(cast(params["embed"], dtype), h, targets,
                      xent_chunks=xent_chunks, fused_xent=fused_xent,
                      logits_sharding=logits_sharding)
 
@@ -885,7 +921,7 @@ def make_cp_loss_fn(cfg: ModelConfig, mesh, *, axis: str = "context",
         h = hidden_states(params, inputs, cfg, dtype=dtype,
                           attn_impl=attn, rope_positions=pos,
                           rope_offset=off, remat=remat)
-        return head_loss(params["embed"].astype(dtype), h, targets,
+        return head_loss(cast(params["embed"], dtype), h, targets,
                          xent_chunks=xent_chunks, fused_xent=fused_xent)
 
     return make_cp_loss(mesh, shard_loss, axis=axis, impl=impl)

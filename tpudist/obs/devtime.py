@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 # tpudist.verdict is import-safe on the jax-free offline path (its jax
 # uses are lazy), so the status vocabulary has one home
 from tpudist import rules as rules_lib
+from tpudist.scopes import layer_of, scope_path
 from tpudist.verdict import FAIL, SUCCESS, UNGATEABLE
 
 Interval = Tuple[float, float]
@@ -397,6 +398,67 @@ def device_op_tracks(doc: Dict[str, Any]
     return tracks
 
 
+def scope_seconds(doc: Dict[str, Any]) -> Dict[str, float]:
+    """Device SECONDS per layer (``tpudist.scopes.layer_of`` of each
+    op's scope path), summed over the device tracks of one capture
+    document; time whose op carries none of the program's scopes goes
+    under ``""``.
+
+    The scope path travels in each ``XLA Ops`` event's ``tf_op``
+    argument (the op's name stack: where a v5e capture of jaxlib 0.9.0
+    carries it). Only LEAF events count: a ``while`` or ``cond`` on the
+    op line spans the ops of its body, and is told from them by
+    containing another event's start, not by its name. An op inside a
+    scoped ``while`` body whose own metadata the compiler dropped takes
+    the scope of the nearest container that has one."""
+    thread_names: Dict[Tuple[Any, Any], str] = {}
+    for e in doc.get("traceEvents", []):
+        if e.get("ph") == "M" and e.get("name") == "thread_name":
+            thread_names[(e.get("pid"), e.get("tid"))] = \
+                e.get("args", {}).get("name", "")
+    per_thread: Dict[Tuple[Any, Any], list] = {}
+    for e in doc.get("traceEvents", []):
+        if e.get("ph") != "X" or "ts" not in e or "dur" not in e:
+            continue
+        key = (e.get("pid"), e.get("tid"))
+        if thread_names.get(key) != "XLA Ops":
+            continue
+        t0 = float(e["ts"])
+        per_thread.setdefault(key, []).append(
+            (t0, t0 + float(e["dur"]),
+             scope_path((e.get("args") or {}).get("tf_op"))))
+    out: Dict[str, float] = {}
+    for evs in per_thread.values():
+        for t0, t1, path in leaf_events(evs):
+            layer = layer_of(path)
+            out[layer] = out.get(layer, 0.0) + (t1 - t0) / 1e6
+    return out
+
+
+# two ops that only touch (the next starts within 2 ns of this one's end,
+# as stamps rounded to the nanosecond can) do not nest
+_TOUCH_US = 2e-3
+
+
+def leaf_events(evs: List[Tuple[float, float, str]]
+                ) -> List[Tuple[float, float, str]]:
+    """One thread's ``(t0, t1, scope_path)`` events -> the leaves only,
+    each with its own path or, lacking one, its nearest container's."""
+    evs = sorted(evs, key=lambda ev: (ev[0], -ev[1]))
+    out = []
+    stack: List[Tuple[float, str]] = []       # (t1, inherited path)
+    for i, (t0, t1, path) in enumerate(evs):
+        while stack and stack[-1][0] <= t0:
+            stack.pop()
+        if not path and stack:
+            path = stack[-1][1]
+        if i + 1 < len(evs) and evs[i + 1][0] < t1 - _TOUCH_US:
+            stack.append((t1, path))          # a container
+        else:
+            out.append((t0, t1, path))
+    return out
+
+
 # ---------------------------------------------------------- attribution
 
 
@@ -490,11 +552,21 @@ def analyze_capture(capture_dir: str) -> Dict[str, Any]:
         raise FileNotFoundError(
             f"no *.trace.json(.gz) under {capture_dir}")
     tracks: Dict[str, List[Tuple[float, float, str]]] = {}
+    by_scope: Dict[str, float] = {}
     for p in paths:
-        for name, ops in device_op_tracks(load_capture_doc(p)).items():
+        doc = load_capture_doc(p)
+        for name, ops in device_op_tracks(doc).items():
             tracks.setdefault(name, []).extend(ops)
+        for layer, sec in scope_seconds(doc).items():
+            by_scope[layer] = by_scope.get(layer, 0.0) + sec
     out = attribute_tracks(tracks)
     out["capture_files"] = paths
+    # device seconds per layer (scopes.layer_of), largest first; ""
+    # is the time no scope names. Empty where the capture carries no
+    # name stacks (the CPU backend's)
+    out["by_scope"] = {k: round(v, 6) for k, v in sorted(
+        by_scope.items(), key=lambda kv: -kv[1])} \
+        if any(by_scope) else {}
     return out
 
 
@@ -641,7 +713,11 @@ class WindowProfiler:
         # the anchor must be read BEFORE start_trace: the profiler
         # stamps its session epoch (the ts origin) during the call
         self.anchor_ns = time.perf_counter_ns()
-        jax.profiler.start_trace(self.capture_dir)
+        # device and runtime events only: with the Python tracer on every
+        # call is an event, and the tracer's mirrored spans drown in them
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level, opts.host_tracer_level = 0, 1
+        jax.profiler.start_trace(self.capture_dir, profiler_options=opts)
         self.state = "open"
 
     def note_dispatch(self, result: Any = None) -> None:

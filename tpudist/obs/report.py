@@ -282,9 +282,16 @@ def ckpt_section(events, metrics) -> Dict[str, Any]:
     drain_recs = [r for r in metrics if r.get("kind") == "ckpt_drain"]
     saves = [r for r in metrics if r.get("kind") == "ckpt"]
     worst = max((float(e["dur"]) / 1e6 for e in drains), default=0.0)
+    # what the enqueue spans counted where the snapshot happens: bytes
+    # copied to the host, process CPU seconds spent meanwhile
+    enq_args = [e.get("args") or {} for e in events
+                if e.get("name") == "ckpt_enqueue"]
     return {
         "saves": len(saves),
         "enqueue_s": round(enq, 6),
+        "enqueue_bytes": sum(int(a.get("bytes") or 0) for a in enq_args),
+        "enqueue_cpu_s": round(sum(float(a.get("cpu_s") or 0.0)
+                                   for a in enq_args), 6),
         "drain_s": round(sum(float(e["dur"]) / 1e6 for e in drains), 6),
         "drain_spans": len(drains),
         "worst_drain_s": round(worst, 6),
@@ -445,6 +452,10 @@ def devtime_section(events, metrics, baseline: Optional[Dict]
         out["dcn_bytes_total"] = rec["dcn_bytes_total"]
         out["ici_bytes_total"] = rec.get("ici_bytes_total")
         out["collectives"] = rec.get("collectives")
+    # device seconds per program scope (tpudist.scopes), from the
+    # capture's own name stacks: which LAYER the device time went to
+    if recs and recs[-1].get("by_scope"):
+        out["by_scope"] = recs[-1]["by_scope"]
     return out
 
 
@@ -1101,7 +1112,16 @@ def to_markdown(report: Dict[str, Any]) -> str:
     lines += ["## Checkpointing",
               f"- {ck['saves']} saves, enqueue {ck['enqueue_s']:.3f}s, "
               f"drain {ck['drain_s']:.3f}s over {ck['drain_spans']} "
-              f"drain windows (worst {ck['worst_drain_s']:.3f}s)", ""]
+              f"drain windows (worst {ck['worst_drain_s']:.3f}s)"]
+    if ck.get("enqueue_bytes") and ck["enqueue_s"] > 0:
+        # cores busy near the snapshot's thread count: the copy is slow;
+        # far under it: the process was starved of its cores
+        lines += [f"- enqueue snapshots: "
+                  f"{ck['enqueue_bytes'] / 1e9:.3f} GB to the host, "
+                  f"{ck['enqueue_cpu_s']:.2f} CPU-s "
+                  f"({ck['enqueue_cpu_s'] / ck['enqueue_s']:.1f} cores "
+                  f"busy)"]
+    lines += [""]
     dt = r.get("devtime") or {}
     if dt.get("devices"):
         pod = dt["pod"]
@@ -1134,6 +1154,13 @@ def to_markdown(report: Dict[str, Any]) -> str:
             lines.append("- exposed comm by host phase: " + ", ".join(
                 f"{cat} {s:.3f}s"
                 for cat, s in dt["exposed_by_phase"].items()))
+            lines.append("")
+        if dt.get("by_scope"):
+            tot = sum(dt["by_scope"].values()) or 1.0
+            lines.append("- device time by program scope: " + ", ".join(
+                f"{name or '(no scope)'} {sec:.3f}s "
+                f"({100 * sec / tot:.1f}%)"
+                for name, sec in dt["by_scope"].items()))
             lines.append("")
         if dt.get("dcn_bytes_total") is not None:
             lines.append(

@@ -101,31 +101,60 @@ class _ThreadBuf:
         return [self.ring[i % self.capacity] for i in range(lo, self.count)]
 
 
+# Every span of an enabled tracer is mirrored into the profiler under this
+# prefix (``tpudist:decode_step``): inside a ``jax.profiler`` session the
+# host spans then sit in the capture itself, beside the device ops. (The
+# session's converter files the event under the part after the colon and
+# keeps the whole in ``args.long_name``.)
+MIRROR_PREFIX = "tpudist:"
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, or None where jax cannot be
+    imported (the offline report's hosts)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:
+        return None
+    return TraceAnnotation
+
+
 class _Span:
     """A single timed window; context-manager AND begin/end handle."""
 
-    __slots__ = ("_buf", "name", "cat", "args", "t0")
+    __slots__ = ("_buf", "name", "cat", "args", "t0", "_ann")
 
     def __init__(self, buf: _ThreadBuf, name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], ann_cls=None):
         self._buf = buf
         self.name = name
         self.cat = cat
         self.args = args
         self.t0 = 0
+        self._ann = None if ann_cls is None else ann_cls(
+            MIRROR_PREFIX + name)
 
     def __enter__(self) -> "_Span":
         self._buf.open.append(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()       # outside the ring's own times
         self.t0 = _now_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = _now_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         buf = self._buf
         if buf.open and buf.open[-1] == self.name:
             buf.open.pop()
         buf.record(self.name, self.cat, self.t0, t1, self.args)
         return False
+
+    def note(self, **args: Any) -> None:
+        """Arguments known only once the window is under way (a count of
+        what it did): merged into the span's args before it is recorded."""
+        self.args = {**(self.args or {}), **args}
 
 
 class Tracer:
@@ -144,6 +173,10 @@ class Tracer:
             raise ValueError(f"trace capacity must be >= 1, got {capacity}")
         self.enabled = enabled
         self.capacity = capacity
+        # the profiler mirror: resolved at the first span (jax imported
+        # lazily, once, and never by a disabled tracer)
+        self._ann_cls = None
+        self._ann_resolved = False
         self._tls = threading.local()
         self._bufs: List[_ThreadBuf] = []
         self._lock = threading.Lock()
@@ -170,20 +203,26 @@ class Tracer:
             self._tls.buf = buf
         return buf
 
+    def _new_span(self, name: str, cat: str, args: Dict[str, Any]) -> _Span:
+        if not self._ann_resolved:
+            self._ann_cls, self._ann_resolved = _annotation_cls(), True
+        return _Span(self._thread_buf(), name, cat, args or None,
+                     self._ann_cls)
+
     def span(self, name: str, cat: str = "misc", **args: Any):
-        """Context manager timing one window. ~1 µs/span enabled;
-        a shared no-op (zero clock reads) when disabled."""
+        """Context manager timing one window. ~1 µs/span enabled (plus
+        ~0.4 µs for the profiler mirror); a shared no-op (zero clock
+        reads) when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self._thread_buf(), name, cat, args or None)
+        return self._new_span(name, cat, args)
 
     def begin(self, name: str, cat: str = "misc", **args: Any):
         """Open a span; pair with :meth:`end`. For windows that cannot
         be a lexical ``with`` block (e.g. spanning loop iterations)."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self._thread_buf(), name, cat,
-                     args or None).__enter__()
+        return self._new_span(name, cat, args).__enter__()
 
     def end(self, span) -> None:
         if span is not _NULL_SPAN:
@@ -342,6 +381,31 @@ def instant(name: str, cat: str = "misc", **args: Any) -> None:
 
 def enabled() -> bool:
     return get().enabled
+
+
+class HostCost:
+    """What the HOST spent inside a window, for spans whose length is
+    host-bound (a device-to-host snapshot): process CPU seconds, all
+    threads. ``cpu_s`` at (busy threads x the span's length) says the
+    copy itself was slow; ``cpu_s`` far under it says the process was
+    starved of its cores. Read where the work happens: ``note`` puts it,
+    and whatever else it is given, into the span's args. Nothing is read
+    when the tracer is off. (Involuntary context switches would name the
+    starving directly, but ``ru_nivcsw`` reads 0 whatever happens on a
+    sandboxed kernel such as the v5e hosts': a counter that cannot
+    leave 0 there was left out.)"""
+
+    __slots__ = ("_span", "_cpu0")
+
+    def __init__(self, span):
+        self._span = span
+        if span is not _NULL_SPAN:
+            self._cpu0 = time.process_time()
+
+    def note(self, **args: Any) -> None:
+        if self._span is not _NULL_SPAN:
+            self._span.note(
+                cpu_s=round(time.process_time() - self._cpu0, 6), **args)
 
 
 # --------------------------------------------------- pod merge + export

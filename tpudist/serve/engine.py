@@ -45,6 +45,7 @@ from tpudist.config import ModelConfig
 from tpudist.engine import OnMesh, _arg_specs
 from tpudist.models import get_model
 from tpudist.parallel import sharding as shd
+from tpudist.scopes import cast, scope, scoped
 from tpudist.serve import kvcache
 from tpudist.utils import compat
 
@@ -156,9 +157,17 @@ class ServeEngine:
     # --------------------------------------------------------- prefill
 
     def _tied_logits(self, params, h):
-        emb = params["embed"].astype(self.dtype)
-        return (h @ emb.T).astype(jnp.float32)
+        with scope("lm_head"):
+            emb = cast(params["embed"], self.dtype)
+            return (h @ emb.T).astype(jnp.float32)
 
+    def _greedy(self, params, h):
+        """Greedy next token from final-normed hidden states."""
+        logits = self._tied_logits(params, h)
+        with scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    @scoped("prefill")
     def _prefill_body(self, params, state: ServeState, tokens,
                       prompt_len, slot, max_new
                       ) -> Tuple[ServeState, jax.Array]:
@@ -175,8 +184,7 @@ class ServeEngine:
         # padded tail's hidden states exist but are never consulted
         h_last = lax.dynamic_index_in_dim(h, prompt_len - 1, axis=1,
                                           keepdims=False)
-        first = jnp.argmax(self._tied_logits(params, h_last),
-                           axis=-1).astype(jnp.int32)[0]
+        first = self._greedy(params, h_last)[0]
         zeros = (0,) * (state.cache_k.ndim - 2)
         ck = lax.dynamic_update_slice(
             state.cache_k, kvcache.from_canonical(cache["k"], self.layout),
@@ -242,6 +250,7 @@ class ServeEngine:
 
     # ---------------------------------------------------------- decode
 
+    @scoped("decode")
     def _decode_body(self, params, state: ServeState, k: int
                      ) -> Tuple[ServeState, jax.Array, jax.Array]:
         self.decode_traces.append(k)    # trace-time compile marker
@@ -260,8 +269,7 @@ class ServeEngine:
                 h, cache = self.model.hidden_states(
                     params, st.last_token[:, None], self.model_cfg,
                     dtype=self.dtype, kv_cache=cache, cur_index=pos)
-                nxt = jnp.argmax(self._tied_logits(params, h[:, 0]),
-                                 axis=-1).astype(jnp.int32)
+                nxt = self._greedy(params, h[:, 0])
                 act = st.active
                 new_len = jnp.where(act, st.lengths + 1, st.lengths)
                 new_rem = jnp.where(act, st.remaining - 1, st.remaining)
@@ -439,6 +447,7 @@ class PagedServeEngine(ServeEngine):
 
     # --------------------------------------------------------- prefill
 
+    @scoped("prefill")
     def _paged_prefill_body(self, params, state: PagedServeState,
                             tokens, prompt_len, slot, max_new, page_row,
                             shared_len
@@ -462,15 +471,15 @@ class PagedServeEngine(ServeEngine):
             kv_cache=scratch, cur_index=None)
         h_last = lax.dynamic_index_in_dim(h, prompt_len - 1, axis=1,
                                           keepdims=False)
-        first = jnp.argmax(self._tied_logits(params, h_last),
-                           axis=-1).astype(jnp.int32)[0]
-        t = jnp.arange(self.prompt_pad)
-        write = (t >= shared_len) & (t < prompt_len)
-        pg = page_row[t // pt]
-        pg = jnp.where(write & (pg >= 0), pg, spec.pages)  # else: trash
-        off = t % pt
-        pk = state.pool_k.at[:, pg, off].set(scratch["k"][:, 0])
-        pv = state.pool_v.at[:, pg, off].set(scratch["v"][:, 0])
+        first = self._greedy(params, h_last)[0]
+        with scope("kv_scatter"):
+            t = jnp.arange(self.prompt_pad)
+            write = (t >= shared_len) & (t < prompt_len)
+            pg = page_row[t // pt]
+            pg = jnp.where(write & (pg >= 0), pg, spec.pages)  # else: trash
+            off = t % pt
+            pk = state.pool_k.at[:, pg, off].set(scratch["k"][:, 0])
+            pv = state.pool_v.at[:, pg, off].set(scratch["v"][:, 0])
         rem = max_new - 1            # the prefill itself produced token 1
         active = (rem > 0) & (prompt_len < self.max_seq)
         return PagedServeState(
@@ -529,6 +538,7 @@ class PagedServeEngine(ServeEngine):
 
     # ---------------------------------------------------------- decode
 
+    @scoped("decode")
     def _paged_decode_body(self, params, state: PagedServeState, k: int,
                            page_table, dispatch_active
                            ) -> Tuple[PagedServeState, jax.Array,
@@ -546,8 +556,7 @@ class PagedServeEngine(ServeEngine):
                     page_table=page_table, positions=pos[:, None],
                     write_ok=(act & (st.lengths < self.max_seq))[:, None],
                     page_tokens=self.spec.page_tokens)
-                nxt = jnp.argmax(self._tied_logits(params, h[:, 0]),
-                                 axis=-1).astype(jnp.int32)
+                nxt = self._greedy(params, h[:, 0])
                 new_len = jnp.where(act, st.lengths + 1, st.lengths)
                 new_rem = jnp.where(act, st.remaining - 1, st.remaining)
                 # slots OUTSIDE this dispatch (their page rows may be
@@ -596,6 +605,7 @@ class PagedServeEngine(ServeEngine):
 
     # ---------------------------------------------------------- verify
 
+    @scoped("decode")
     def _paged_verify_body(self, params, state: PagedServeState, draft,
                            page_table, dispatch_active):
         self.verify_traces.append(1)    # trace-time compile marker
@@ -614,8 +624,7 @@ class PagedServeEngine(ServeEngine):
             pool_k=state.pool_k, pool_v=state.pool_v,
             page_table=page_table, positions=pos, write_ok=write_ok,
             page_tokens=self.spec.page_tokens)
-        g = jnp.argmax(self._tied_logits(params, h),
-                       axis=-1).astype(jnp.int32)              # (S, W)
+        g = self._greedy(params, h)                             # (S, W)
         # draft token w-1 is correct iff all earlier drafts matched the
         # target's greedy choice — cumprod counts the accepted run
         match = (draft == g[:, :-1]).astype(jnp.int32)

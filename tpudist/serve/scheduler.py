@@ -463,16 +463,18 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                 pass   # the admission decision itself is host-trivial
             with tracer.span("prefill", cat="serve", rid=req.rid,
                              slot=i, prompt_len=req.prompt_len):
-                if paged:
-                    state, first = engine.prefill(
-                        params, state, req.tokens[None, :],
-                        req.prompt_len, i, budget,
-                        shared_len=alloc.admit_shared_len(shared))
-                else:
-                    state, first = engine.prefill(
-                        params, state, req.tokens[None, :],
-                        req.prompt_len, i, budget)
-                first = int(first)           # fence: the token exists NOW
+                with tracer.span("prefill_enqueue", cat="serve"):
+                    if paged:
+                        state, first = engine.prefill(
+                            params, state, req.tokens[None, :],
+                            req.prompt_len, i, budget,
+                            shared_len=alloc.admit_shared_len(shared))
+                    else:
+                        state, first = engine.prefill(
+                            params, state, req.tokens[None, :],
+                            req.prompt_len, i, budget)
+                with tracer.span("prefill_fence", cat="serve"):
+                    first = int(first)       # fence: the token exists NOW
             if virtual is not None:
                 virtual.clock.advance(virtual.prefill_s)
             t_first = now()
@@ -504,8 +506,14 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
             alerts.observe("tokens_per_chip",
                            generated / wall / n_chips)
 
+    def fenced(toks, valid):
+        """The dispatch's tokens on the host: the wait for the device."""
+        with tracer.span("decode_fence", cat="serve"):
+            return np.asarray(toks), np.asarray(valid)
+
     while len(results) + led.shed_total() < len(requests):
-        admit()
+        with tracer.span("admit_pass", cat="serve"):
+            admit()
         occupied = [i for i in range(engine.slots) if slots[i] is not None]
         if not occupied:
             if waiting:
@@ -523,8 +531,9 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                 if virtual is not None:
                     virtual.clock.wait_until(t0 + pending[0].arrival_s)
                 else:
-                    time.sleep(min(0.002, max(
-                        0.0, pending[0].arrival_s - now())))
+                    with tracer.span("idle_wait", cat="serve"):
+                        time.sleep(min(0.002, max(
+                            0.0, pending[0].arrival_s - now())))
                 continue
             break
         # depth sampled once per DISPATCH (not per idle busy-wait pass:
@@ -572,29 +581,31 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                     spec_k - 1)
             with tracer.span("verify_step", cat="serve",
                              active=len(occupied), window=spec_k):
-                state, toks, valid, _emitted = engine.verify(
-                    params, state, draft, dispatch_active=occ_mask)
-                toks = np.asarray(toks)      # fence: tokens on host
-                valid = np.asarray(valid)
+                with tracer.span("decode_enqueue", cat="serve"):
+                    state, toks, valid, _emitted = engine.verify(
+                        params, state, draft, dispatch_active=occ_mask)
+                toks, valid = fenced(toks, valid)
         elif paged:
             occ_mask = np.array([s is not None for s in slots])
             with tracer.span("decode_step", cat="serve",
                              active=len(occupied), decode_k=cur_k):
-                state, toks, valid = engine.decode(
-                    params, state, cur_k, dispatch_active=occ_mask)
-                toks = np.asarray(toks)      # fence: tokens on host
-                valid = np.asarray(valid)
+                with tracer.span("decode_enqueue", cat="serve"):
+                    state, toks, valid = engine.decode(
+                        params, state, cur_k, dispatch_active=occ_mask)
+                toks, valid = fenced(toks, valid)
         else:
             with tracer.span("decode_step", cat="serve",
                              active=len(occupied), decode_k=cur_k):
-                state, toks, valid = engine.decode(params, state, cur_k)
-                toks = np.asarray(toks)      # fence: tokens on host
-                valid = np.asarray(valid)
+                with tracer.span("decode_enqueue", cat="serve"):
+                    state, toks, valid = engine.decode(params, state,
+                                                       cur_k)
+                toks, valid = fenced(toks, valid)
         if virtual is not None:
             dt = virtual.decode_s + stall_s
             virtual.clock.advance(dt)
         else:
             dt = (clock() - t_dispatch) + stall_s
+        emit = tracer.begin("emit", cat="serve")
         dispatches += 1
         active_peak = max(active_peak, len(occupied))
         if paged:
@@ -657,11 +668,13 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                 # output length does not depend on decode_k and a freed
                 # slot is never still device-active
                 finish(i, "evicted")
+        tracer.end(emit)
         # SLO grading on the tick cadence, not per dispatch: summary()
         # sorts every accumulated sample, and that host work would land
         # in the inter-dispatch gap — inflating the very ITL it grades
         if dispatches % max(tick_every, 1) != 0:
             continue
+        tick = tracer.begin("tick", cat="serve")
         if flush_events and metrics is not None:
             # amortised durability for the supervised-but-unchaosed
             # path (a REAL preemption can land anywhere): at most one
@@ -720,6 +733,7 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                         ttft_hist=stats.ttft_hist(),
                         itl_hist=stats.itl_hist(),
                         **extra)
+        tracer.end(tick)
 
     wall_s = now()
     # an empty run measured NOTHING: throughput is None (→ the gate

@@ -657,6 +657,7 @@ def run(cfg: TrainConfig) -> float:
                 fabric=fabric, axis_fabric=fabrics,
                 capture=win.capture_dir, dispatches=win.seen,
                 process_index=ctx.process_index, **pod, **byte_fields,
+                by_scope=analysis["by_scope"],
                 per_device=[{"device": name, **d}
                             for name, d in analysis["devices"].items()])
             log0(f"tpudist: devtime {devtime_status}: "

@@ -37,12 +37,24 @@ def enable_compilation_cache() -> None:
     program already compiled. The two floors drop to 0: the acceptance
     workload's programs are deliberately tiny, and the default floors
     would skip caching exactly the programs this workload compiles.
+    The key covers the ops' name stacks (below): the first run after a
+    change of scopes compiles cold.
     """
     import jax
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # The program reads its own op metadata back (``tpudist.scopes``: the
+    # name stack is how a capture says which layer an op belongs to), so
+    # the metadata belongs to the program's identity. By default the key
+    # is computed with debug info stripped, and a build that adds or
+    # renames a scope would be handed the executable of one that has
+    # none. The name stack goes into the key; Python call stacks stay
+    # out of it (no frames in the locations), so that an edit which moves
+    # a line, or a checkout under another path, is still a hit.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     jax.monitoring.register_event_listener(_count_cache_event)
 
 
