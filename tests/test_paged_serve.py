@@ -22,6 +22,7 @@ The acceptance pins:
   validation, page/speculate axis gating, never-slower-than-start.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -30,6 +31,7 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from tpudist import rules as rules_lib
@@ -49,7 +51,13 @@ TINY_MOE = ModelConfig(name="moe", vocab_size=64, n_layers=2,
                        d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
                        max_seq_len=32, n_experts=4, expert_top_k=2,
                        capacity_factor=4.0)
-CFGS = {"transformer": TINY_TF, "moe": TINY_MOE}
+# 9 layers: ``paged_hidden_states`` unrolls its layer loop up to 8, so
+# only a deeper model runs the ROLLED loop that carries the pool (the
+# form every real depth compiles to)
+DEEP_TF = dataclasses.replace(TINY_TF, n_layers=9, d_model=16, d_ff=32)
+DEEP_MOE = dataclasses.replace(TINY_MOE, n_layers=9, d_model=16, d_ff=32)
+CFGS = {"transformer": TINY_TF, "moe": TINY_MOE,
+        "transformer-l9": DEEP_TF, "moe-l9": DEEP_MOE}
 
 
 def _spec(slots=2, max_seq=16, pt=4, pages=0):
@@ -184,7 +192,7 @@ def test_allocator_can_ever_admit():
 def test_paged_spec_bytes_counts_pool_trash_and_table():
     spec = _spec(slots=2, max_seq=16, pt=4, pages=6)
     assert spec.max_pages_per_slot == 4
-    assert spec.pool_shape == (2, 7, 4, 2, 8)  # +1 trash page
+    assert spec.pool_shape == (2, 2, 7, 4, 8)  # +1 trash page
     pool_elems = 2 * 7 * 4 * 2 * 8
     assert spec.table_bytes == 2 * 4 * 4
     assert spec.bytes == 2 * pool_elems * 4 + spec.table_bytes
@@ -200,12 +208,13 @@ def test_paged_spec_bytes_counts_pool_trash_and_table():
 # bitwise parity: dense vs paged vs paged+speculative                 #
 # ------------------------------------------------------------------ #
 
-@pytest.mark.parametrize("model_name", ["transformer", "moe"])
+@pytest.mark.parametrize("model_name", list(CFGS))
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_paged_greedy_matches_dense(devices8, model_name, n_dev):
     """The paged engine's whole serve lane (scatter prefill, gather-free
     write-then-attend decode, host page table) must emit the SAME token
-    streams as the dense arena — per request, bitwise."""
+    streams as the dense arena — per request, bitwise. The 9-layer
+    configs hold the rolled layer loop to it."""
     cfg = CFGS[model_name]
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
     params = init_params(cfg, mesh, seed=0)
@@ -263,19 +272,21 @@ def test_shared_prefix_paged_matches_dense(devices8, prefix_len):
     assert outs["dense"] == outs["paged"]
 
 
+@pytest.mark.parametrize("model_name", ["transformer", "transformer-l9"])
 @pytest.mark.parametrize("n_dev", [1, 4])
-def test_speculative_greedy_bitwise_vs_dense(devices8, n_dev):
+def test_speculative_greedy_bitwise_vs_dense(devices8, n_dev, model_name):
     """Speculation is a pure latency play: k-token n-gram drafts
     verified in ONE batched target forward must reproduce the dense
     greedy stream bitwise — accepted or rejected, no token moves."""
+    cfg = CFGS[model_name]
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
-    params = init_params(TINY_TF, mesh, seed=0)
+    params = init_params(cfg, mesh, seed=0)
     shared = sched.shared_prefix_tokens(8, 64, seed=13)
     outs = {}
     for tag, engine, prefix in (
-            ("dense", ServeEngine(TINY_TF, mesh, slots=3, max_seq=32,
+            ("dense", ServeEngine(cfg, mesh, slots=3, max_seq=32,
                                   prompt_pad=16, decode_k=4), None),
-            ("spec", PagedServeEngine(TINY_TF, mesh, slots=3,
+            ("spec", PagedServeEngine(cfg, mesh, slots=3,
                                       max_seq=32, prompt_pad=16,
                                       decode_k=4, page_tokens=8,
                                       speculate_k=4), shared)):
@@ -319,6 +330,69 @@ def test_program_pins_paged_and_speculative(devices8):
     with pytest.raises(ValueError, match="speculate-k"):
         PagedServeEngine(TINY_TF, mesh, slots=2, max_seq=16,
                          prompt_pad=4, page_tokens=4, speculate_k=1)
+
+
+# ------------------------------------------------------------------ #
+# the pool rides the loops as a carry, never as scanned xs -> ys      #
+# ------------------------------------------------------------------ #
+
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of ``jaxpr``, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in _subjaxprs(eqn):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("model_name", ["transformer-l9", "moe-l9"])
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_pool_is_a_loop_carry_never_scanned(devices8, program, model_name):
+    """The mechanism of the in-place pool: in the paged decode and verify
+    programs no array of the pool's shape, nor of one layer's page set,
+    is among any scan's ``xs`` (a per-layer slice-out into a fresh
+    buffer) or ``ys`` (a restack into a second pool); the pool appears
+    only as a carry, which XLA updates in place."""
+    cfg = CFGS[model_name]
+    mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
+    params = init_params(cfg, mesh, seed=0)
+    eng = PagedServeEngine(cfg, mesh, slots=2, max_seq=16, prompt_pad=4,
+                           decode_k=2, page_tokens=4, speculate_k=3)
+    state = eng.init_state()
+    table = jnp.asarray(eng.alloc.table, jnp.int32)
+    da = jnp.ones((eng.slots,), bool)
+    with jax.set_mesh(mesh):
+        if program == "decode":
+            jaxpr = jax.make_jaxpr(eng._paged_decode_body,
+                                   static_argnums=(2,))(
+                params, state, 2, table, da)
+        else:
+            draft = jnp.zeros((eng.slots, eng.speculate_k - 1), jnp.int32)
+            jaxpr = jax.make_jaxpr(eng._paged_verify_body)(
+                params, state, draft, table, da)
+    pool = tuple(eng.spec.pool_shape)
+    assert pool[0] == cfg.n_layers == 9
+    carried = 0
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
+    for eqn in _scans(jaxpr.jaxpr):
+        nc, ncar = eqn.params["num_consts"], eqn.params["num_carry"]
+        carried += shapes(eqn.invars[nc:nc + ncar]).count(pool)
+        for what, seen in (("xs", shapes(eqn.invars[nc + ncar:])),
+                           ("ys", shapes(eqn.outvars[ncar:]))):
+            for shape in seen:
+                assert shape not in (pool, pool[1:]), (
+                    f"a scan's {what} holds a KV pool array {shape}")
+    # pool_k and pool_v in the layer loop's carry (and, in the decode
+    # program, in the token-step loop's around it)
+    assert carried >= 2, carried
 
 
 # ------------------------------------------------------------------ #

@@ -434,37 +434,49 @@ def _cached_hidden_states(params: Params, tokens: jax.Array,
     return rmsnorm(x, params["final_norm"]), {"k": ck, "v": cv}
 
 
-def _paged_attention(q, k_new, v_new, pool_k, pool_v, page_table,
+def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
                      positions, write_ok, page_tokens: int):
     """Windowed incremental attention against a PAGED KV pool,
     gather-free on the read path.
 
     q: (slots, window, heads, head_dim); k_new/v_new: (slots, window,
     kv, head_dim), ALREADY rotated at ``positions`` (slots, window);
-    pool_k/pool_v: (pages+1, page_tokens, kv, head_dim) — one layer of
-    the pool, last page the TRASH page; page_table: (slots, max_pages)
-    int32, -1 = unmapped; write_ok: (slots, window) bool — False routes
-    the write to the trash page (inactive slots, positions past
-    capacity, shared-prefix positions another slot's registration
+    pool_k/pool_v: (n_layers, kv, pages+1, page_tokens, head_dim) — the
+    WHOLE pool, last page of every layer the TRASH page; layer: int32
+    scalar, the layer this call writes and reads. page_table: (slots,
+    max_pages) int32, -1 = unmapped; write_ok: (slots, window) bool —
+    False routes the write to the trash page (inactive slots, positions
+    past capacity, shared-prefix positions another slot's registration
     already wrote).
+
+    The pool is the layer loop's CARRY: written in place and handed
+    back whole, never sliced out per layer and restacked (which cost a
+    read and a write of the layer's page set, twice, every layer of
+    every token step). Its kv-head axis sits OUTSIDE the pages so that
+    one layer's slice is already the ``(kv, keys, head_dim)`` operand
+    the two attention matmuls batch over: with the heads inside a page
+    the compiler staged every layer's K and V through a second copy to
+    move them out.
 
     WRITE: the only dynamic indexing is a tiny ``take_along_axis`` on
     the int32 page table (slots × window entries) plus the scatter of
-    the new k/v — the same shape of scatter the dense path's
-    ``.at[slot, pos].set`` does. READ: no gathers at all — ownership
+    the new k/v, one ``head_dim`` row per token and kv head at
+    ``(layer, head, page, offset)``. READ: the layer's page set is one
+    dynamic index on the layer axis, and no gathers — ownership
     is a one-hot compare of the page table against the pool's page ids
     (the trash page id appears in no table, so it is masked out by
     construction), each owned page's LOGICAL position comes from the
-    same one-hot, and attention runs over the whole flattened pool with
-    ``owned & (key_pos <= query_pos)`` masking — stale pages, other
-    slots' pages and the trash page all mask to exp(-inf) = 0 exactly,
-    the same discipline that keeps the dense arena's stale rows
-    unreadable. Write-then-attend with the position mask also gives
-    intra-window causality for free: a window query at position p never
-    sees the window's own later writes (their positions exceed p).
-    Same f32-softmax discipline as :func:`_attention`."""
+    same one-hot, and attention runs over the layer's whole flattened
+    page set with ``owned & (key_pos <= query_pos)`` masking — stale
+    pages, other slots' pages and the trash page all mask to
+    exp(-inf) = 0 exactly, the same discipline that keeps the dense
+    arena's stale rows unreadable. Write-then-attend with the position
+    mask also gives intra-window causality for free: a window query at
+    position p never sees the window's own later writes (their
+    positions exceed p). Same f32-softmax discipline as
+    :func:`_attention`."""
     s, w, h, hd = q.shape
-    n_pool, pt = pool_k.shape[0], page_tokens
+    n_pool, pt = pool_k.shape[2], page_tokens
     kv = k_new.shape[2]
     maxp = page_table.shape[1]
     trash = n_pool - 1
@@ -475,11 +487,13 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, page_table,
         off = positions % pt
         pg = jnp.take_along_axis(page_table, j, axis=1)       # (s, w)
         pg = jnp.where(write_ok & (pg >= 0), pg, trash)
-        pool_k = pool_k.at[pg, off].set(k_new.astype(pool_k.dtype))
-        pool_v = pool_v.at[pg, off].set(v_new.astype(pool_v.dtype))
+        at = (layer, jnp.arange(kv)[None, None, :], pg[:, :, None],
+              off[:, :, None])                                # (s, w, kv)
+        pool_k = pool_k.at[at].set(k_new.astype(pool_k.dtype))
+        pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
 
     # ---- read: ownership + position masks from one one-hot, and the
-    # whole pool flattened in the compute dtype ----
+    # layer's page set flattened in the compute dtype ----
     with scope("attn/kv_gather"):
         onehot = page_table[:, :, None] \
             == jnp.arange(n_pool)[None, None, :]
@@ -490,28 +504,31 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, page_table,
         mask = owned[:, None, :, None] \
             & (kpos[:, None, :, :] <= positions[:, :, None, None])
         mask = mask.reshape(s, w, n_pool * pt)                # (s, w, keys)
-        kf = pool_k.reshape(n_pool * pt, kv, hd).astype(q.dtype)
-        vf = pool_v.reshape(n_pool * pt, kv, hd).astype(q.dtype)
+        kf = lax.dynamic_index_in_dim(pool_k, layer, keepdims=False)
+        vf = lax.dynamic_index_in_dim(pool_v, layer, keepdims=False)
+        kf = kf.reshape(kv, n_pool * pt, hd).astype(q.dtype)
+        vf = vf.reshape(kv, n_pool * pt, hd).astype(q.dtype)
 
     with scope("attn/core"):
         qg = q.reshape(s, w, kv, h // kv, hd)   # GQA: group per kv head
-        scores = jnp.einsum("swkgd,nkd->swkgn", qg, kf) / jnp.sqrt(
+        scores = jnp.einsum("swkgd,knd->swkgn", qg, kf) / jnp.sqrt(
             jnp.asarray(hd, q.dtype))
         scores = jnp.where(mask[:, :, None, None, :], scores,
                            jnp.asarray(-1e30, scores.dtype))
         probs = jax.nn.softmax(scores.astype(jnp.float32),
                                axis=-1).astype(q.dtype)
-        o = jnp.einsum("swkgn,nkd->swkgd", probs, vf).reshape(s, w, h, hd)
+        o = jnp.einsum("swkgn,knd->swkgd", probs, vf).reshape(s, w, h, hd)
     return o, pool_k, pool_v
 
 
 def _attn_sublayer_paged(x, lp, cfg: ModelConfig, positions, write_ok,
-                         pool_k, pool_v, page_table, page_tokens: int):
+                         pool_k, pool_v, layer, page_table,
+                         page_tokens: int):
     """The paged twin of :func:`_attn_sublayer_cached`: a WINDOW of new
     tokens per slot, q/k/v projected and rotated at each token's own
-    position, attention against the layer's paged pool. Returns
-    ``(out, pool_k', pool_v')``. Shared with the MoE model, whose
-    layers differ only in the FFN half."""
+    position, attention against layer ``layer`` of the whole paged pool.
+    Returns ``(out, pool_k', pool_v')``. Shared with the MoE model,
+    whose layers differ only in the FFN half."""
     b, w, d = x.shape
     h, kv = cfg.n_heads, cfg.n_kv_heads
     hd = d // h
@@ -520,7 +537,7 @@ def _attn_sublayer_paged(x, lp, cfg: ModelConfig, positions, write_ok,
     with scope("attn/rope"):
         q = window_rope(q, positions, cfg.rope_theta)
         k = window_rope(k, positions, cfg.rope_theta)
-    o, pool_k, pool_v = _paged_attention(q, k, v, pool_k, pool_v,
+    o, pool_k, pool_v = _paged_attention(q, k, v, pool_k, pool_v, layer,
                                          page_table, positions,
                                          write_ok, page_tokens)
     return _attn_out(x, o.reshape(b, w, h * hd), lp), pool_k, pool_v
@@ -534,25 +551,31 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
     paged twin of :func:`_cached_hidden_states`'s decode branch.
 
     tokens/positions/write_ok: (slots, window); pool_k/pool_v:
-    (n_layers, pages+1, page_tokens, kv, head_dim); page_table:
+    (n_layers, kv, pages+1, page_tokens, head_dim); page_table:
     (slots, max_pages) int32 — a per-dispatch argument, never device
     state (the host allocator owns it). Window 1 is the paged decode
     step; window k is the speculative VERIFY forward (one batched
     target forward scoring a whole draft window). ``ffn(x, lp, cfg)``
     is the per-layer FFN half — the ONE thing the MoE model swaps.
-    Returns ``(h, pool_k', pool_v')`` with ``h`` final-normed."""
+    The pool rides the layer loop as its CARRY (with ``x``), updated in
+    place; the loop scans the stacked layer parameters and the layer
+    index only. Returns ``(h, pool_k', pool_v')`` with ``h``
+    final-normed."""
     x = embed_tokens(params, tokens, dtype)
     unroll = cfg.n_layers <= 8
 
-    def body(x, xs):
-        lp, pk_l, pv_l = xs
-        x, pk_l, pv_l = _attn_sublayer_paged(
-            x, lp, cfg, positions, write_ok, pk_l, pv_l, page_table,
+    def body(carry, xs):
+        x, pk, pv = carry
+        lp, layer = xs
+        x, pk, pv = _attn_sublayer_paged(
+            x, lp, cfg, positions, write_ok, pk, pv, layer, page_table,
             page_tokens)
-        return ffn(x, lp, cfg), (pk_l, pv_l)
+        return (ffn(x, lp, cfg), pk, pv), None
 
-    x, (pool_k, pool_v) = lax.scan(
-        body, x, (params["layers"], pool_k, pool_v), unroll=unroll)
+    (x, pool_k, pool_v), _ = lax.scan(
+        body, (x, pool_k, pool_v),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        unroll=unroll)
     return rmsnorm(x, params["final_norm"]), pool_k, pool_v
 
 

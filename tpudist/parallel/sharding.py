@@ -193,8 +193,8 @@ def kv_cache_specs(layout: str = "st") -> P:
 
 
 def paged_kv_cache_specs() -> P:
-    """PartitionSpec for the PAGED serving KV pool ``(layers, pages+1,
-    page_tokens, kv_heads, head_dim)`` (tpudist.serve.kvcache): pages —
+    """PartitionSpec for the PAGED serving KV pool ``(layers, kv_heads,
+    pages+1, page_tokens, head_dim)`` (tpudist.serve.kvcache): pages —
     the pool's embarrassingly-parallel dim, playing the role slots play
     in the dense arena — ride the batch axes, kv heads ride tensor (the
     same Megatron head split the attention weights use), and the layer
@@ -202,7 +202,7 @@ def paged_kv_cache_specs() -> P:
     :func:`sanitize_specs` so a pool size the batch axes don't divide
     falls back to replicated instead of erroring (the +1 trash page
     makes odd pool sizes the COMMON case, not the exception)."""
-    return P(None, ("data", "fsdp"), None, "tensor", None)
+    return P(None, "tensor", ("data", "fsdp"), None, None)
 
 
 def norm_shard_index(idx, shape) -> tuple:

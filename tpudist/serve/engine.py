@@ -364,7 +364,7 @@ class PagedServeState(NamedTuple):
     state (``PageAllocator.table``), passed into every dispatch as a
     small traced int32 array."""
 
-    pool_k: jax.Array        # (L, pages+1, page_tokens, kv, head_dim)
+    pool_k: jax.Array        # (L, kv, pages+1, page_tokens, head_dim)
     pool_v: jax.Array
     lengths: jax.Array       # (slots,) int32: tokens in cache per slot
     last_token: jax.Array    # (slots,) int32: newest token, not yet cached
@@ -457,11 +457,15 @@ class PagedServeEngine(ServeEngine):
         pt = spec.page_tokens
         # dense prefill into a throwaway scratch row — the model's
         # existing cache-aware full forward, so the K/V bytes are
-        # BITWISE the ones the dense engine would store — then scatter
-        # the slot's true positions into its pages. Positions below
-        # ``shared_len`` are skipped (their pages are the shared prefix,
-        # already holding bitwise-identical content); the padded tail
-        # and the skipped prefix route to the trash page.
+        # BITWISE the ones the dense engine would store — then copy the
+        # prompt's pages into the slot's pages, a whole page at a time.
+        # Pages wholly below ``shared_len`` are skipped (they are the
+        # shared prefix, already holding bitwise-identical content);
+        # those, the pages past the prompt and unmapped ones route to
+        # the trash page. The prompt's last page carries pad-token junk
+        # past ``prompt_len``: positions beyond the slot's length, which
+        # the decode mask (keys <= pos) never reads and write-then-attend
+        # overwrites first, exactly like the dense arena's padded tail.
         scratch_shape = (spec.n_layers, 1, self.prompt_pad,
                          spec.n_kv_heads, spec.head_dim)
         scratch = {"k": jnp.zeros(scratch_shape, self.dtype),
@@ -473,13 +477,31 @@ class PagedServeEngine(ServeEngine):
                                           keepdims=False)
         first = self._greedy(params, h_last)[0]
         with scope("kv_scatter"):
-            t = jnp.arange(self.prompt_pad)
-            write = (t >= shared_len) & (t < prompt_len)
-            pg = page_row[t // pt]
+            n_pp = -(-self.prompt_pad // pt)         # pages of a prompt
+            start = jnp.arange(n_pp) * pt
+            write = (start < prompt_len) & (start + pt > shared_len)
+            pg = page_row[:n_pp]
             pg = jnp.where(write & (pg >= 0), pg, spec.pages)  # else: trash
-            off = t % pt
-            pk = state.pool_k.at[:, pg, off].set(scratch["k"][:, 0])
-            pv = state.pool_v.at[:, pg, off].set(scratch["v"][:, 0])
+
+            def page_blocks(c):
+                # (L, 1, pad, kv, hd) -> per page (L, kv, 1, pt, hd)
+                c = jnp.pad(c[:, 0], ((0, 0),
+                                      (0, n_pp * pt - self.prompt_pad),
+                                      (0, 0), (0, 0)))
+                c = c.reshape(spec.n_layers, n_pp, pt, spec.n_kv_heads,
+                              spec.head_dim)
+                return c.transpose(1, 0, 3, 2, 4)[:, :, :, None]
+
+            blocks = (page_blocks(scratch["k"]), page_blocks(scratch["v"]))
+
+            def put(j, pools):
+                return tuple(
+                    lax.dynamic_update_slice(pool, b[j],
+                                             (0, 0, pg[j], 0, 0))
+                    for pool, b in zip(pools, blocks))
+
+            pk, pv = lax.fori_loop(0, n_pp, put,
+                                   (state.pool_k, state.pool_v))
         rem = max_new - 1            # the prefill itself produced token 1
         active = (rem > 0) & (prompt_len < self.max_seq)
         return PagedServeState(

@@ -197,9 +197,11 @@ class PagedCacheSpec:
 
     @property
     def pool_shape(self) -> tuple:
-        # +1: the trash page
-        return (self.n_layers, self.pages + 1, self.page_tokens,
-                self.n_kv_heads, self.head_dim)
+        # +1: the trash page. kv heads OUTSIDE the pages: a layer's
+        # slice is then the (kv, keys, head_dim) operand the attention
+        # matmuls batch over, with no relayout on the way
+        return (self.n_layers, self.n_kv_heads, self.pages + 1,
+                self.page_tokens, self.head_dim)
 
     @property
     def table_bytes(self) -> int:
