@@ -609,6 +609,27 @@ def test_report_serving_section_and_verdict():
     assert rep2["serving"] == {"enabled": False}
 
 
+def test_report_serving_prints_what_the_engine_converted():
+    """The ``weights_resident`` span (one a params tree the engine had to
+    convert to its dtype) is the serving section's one line; a run whose
+    weights were at rest in that dtype already has no span and no line."""
+    ev = {"ph": "X", "pid": 0, "tid": 1, "cat": "serve", "ts": 0.0,
+          "name": "weights_resident", "dur": 15e3,
+          "args": {"leaves": 11, "leaves_cast": 11,
+                   "bytes_in": 6_798_319_616, "bytes_out": 3_399_159_808}}
+    rep = report_lib.build_report(_serve_metrics(), {"traceEvents": [ev]})
+    assert rep["serving"]["weights_resident"] == {
+        "trees": 1, "seconds": 0.015, "leaves": 11, "leaves_cast": 11,
+        "bytes_in": 6_798_319_616, "bytes_out": 3_399_159_808}
+    line = next(ln for ln in report_lib.to_markdown(rep).splitlines()
+                if "weights at rest" in ln)
+    assert "11 of 11 leaves" in line
+    assert "6.798 GB -> 3.399 GB in 0.015s (1 tree(s))" in line
+    plain = report_lib.build_report(_serve_metrics(), {})
+    assert plain["serving"]["weights_resident"] is None
+    assert "weights at rest" not in report_lib.to_markdown(plain)
+
+
 def test_report_serving_regrades_through_rules(monkeypatch):
     """The report does not trust the run's own grade: the section
     re-grades the measured numbers through the rules table at fold

@@ -650,14 +650,18 @@ def regression_section(timing: Optional[Dict],
 
 
 def serving_section(metrics: List[Dict[str, Any]],
-                    baseline: Optional[Dict] = None) -> Dict[str, Any]:
+                    baseline: Optional[Dict] = None,
+                    events: Sequence[Dict[str, Any]] = ()
+                    ) -> Dict[str, Any]:
     """The serving slice of the report (tpudist.serve): the run's
     latency percentiles and throughput RE-GRADED through the shared SLO
     gates (tpudist.serve.slo over the rules table — same thresholds the
     serve loop's on-line alerts and exit verdict applied, env read at
     fold time), queue depth over time from the ``kind=serve_tick``
-    stream, and an optional throughput comparison against a baseline
-    BENCH_SERVE.json / prior report. Runs without serve records read as
+    stream, an optional throughput comparison against a baseline
+    BENCH_SERVE.json / prior report, and from the trace's
+    ``weights_resident`` spans what the engine converted to hold the
+    weights at rest in its dtype. Runs without serve records read as
     ``enabled: False`` — a training run has no SLO to grade."""
     serves = [r for r in metrics if r.get("kind") == "serve"]
     if not serves:
@@ -675,6 +679,7 @@ def serving_section(metrics: List[Dict[str, Any]],
     tps = s.get("tokens_per_sec_per_chip")
     ratio = (round(tps / base_tps, 4)
              if isinstance(tps, (int, float)) and base_tps else None)
+    resident = [e for e in events if e.get("name") == "weights_resident"]
     return {
         "enabled": True,
         "status": graded["status"],
@@ -714,6 +719,16 @@ def serving_section(metrics: List[Dict[str, Any]],
         "kv_window_tokens_peak": s.get("kv_window_tokens_peak"),
         "moe_pairs_per_expert_mean": s.get("moe_pairs_per_expert_mean"),
         "moe_experts_hit_mean": s.get("moe_experts_hit_mean"),
+        # one span a params tree the engine had to convert (none where
+        # the tree rests in the engine's dtype already)
+        "weights_resident": ({
+            "trees": len(resident),
+            "seconds": round(sum(float(e["dur"]) for e in resident) / 1e6,
+                             6),
+            **{k: sum(int((e.get("args") or {}).get(k) or 0)
+                      for e in resident)
+               for k in ("leaves", "leaves_cast", "bytes_in",
+                         "bytes_out")}} if resident else None),
         "spec_accept_rate": s.get("spec_accept_rate"),
         "spec_accept_status": slo_mod.rule_status(
             "spec_accept", s.get("spec_accept_rate")),
@@ -970,7 +985,7 @@ def build_report(metrics: List[Dict[str, Any]],
     stragglers = straggler_section(hosts, metrics)
     devtime = devtime_section(all_events, metrics, baseline)
     alerts = alerts_section(metrics, alert_history, timing)
-    serving = serving_section(metrics, baseline)
+    serving = serving_section(metrics, baseline, events)
     flights = flights_section(metrics, trace_doc)
     goodput_sec = goodput_section(metrics, goodput)
     memory = memory_section(metrics, memledger, baseline)
@@ -1220,6 +1235,14 @@ def to_markdown(report: Dict[str, Any]) -> str:
                       f"{sv['kv_window_tokens_peak']}/"
                       f"{sv['kv_window_tokens_total']} window tokens a "
                       f"window layer", ""]
+        if sv.get("weights_resident"):
+            wr = sv["weights_resident"]
+            lines += [f"- weights at rest: {wr['leaves_cast']} of "
+                      f"{wr['leaves']} leaves converted to the engine's "
+                      f"dtype on the device, "
+                      f"{wr['bytes_in'] / 1e9:.3f} GB -> "
+                      f"{wr['bytes_out'] / 1e9:.3f} GB in "
+                      f"{wr['seconds']:.3f}s ({wr['trees']} tree(s))", ""]
         if sv.get("arrived") is not None:
             lines += [f"- admission: {sv['arrived']} arrived = "
                       f"{sv['admitted']} admitted + "

@@ -38,7 +38,12 @@ SCOPES: Tuple[str, ...] = (
     "attn/kv_gather",   # KV read-side layout: GQA expand, page ownership
     "ffn",              # SwiGLU FFN + residual
     "lm_head",          # tied output head (+ cross-entropy in training)
-    "cast",             # stored weight -> compute dtype
+    "cast",             # stored weight -> compute dtype where they
+                        # differ: nothing on the serve path once the
+                        # engine holds the weights in its dtype
+                        # (``ServeEngine._resident``); in training, the
+                        # embedding table's (the others fuse into their
+                        # matmuls)
     "loss",             # the whole loss function (forward and backward)
     "optimizer",        # optax update + apply_updates
     "prefill",          # serve: the prefill program
@@ -94,7 +99,10 @@ def scoped(name: str):
 def cast(w, dtype):
     """A stored weight converted to the compute dtype, under the scope
     ``cast``: a conversion XLA does not fuse into its consumer shows in
-    a capture under that name, not as an anonymous ``convert``."""
+    a capture under that name, not as an anonymous ``convert``. A weight
+    already in ``dtype`` passes through and leaves no op: the serve
+    engine converts its tree once at rest, so its programs hold none;
+    the training step, whose master weights stay float32, still does."""
     with scope("cast"):
         return w.astype(dtype)
 

@@ -44,6 +44,7 @@ from jax import lax
 from tpudist.config import ModelConfig
 from tpudist.engine import OnMesh, _arg_specs
 from tpudist.models import get_model
+from tpudist.obs import trace as trace_lib
 from tpudist.parallel import sharding as shd
 from tpudist.scopes import cast, scope, scoped
 from tpudist.serve import kvcache
@@ -65,7 +66,10 @@ class ServeState(NamedTuple):
 def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
     """Seeded model params placed to their sanitised param_specs layout
     — the same init + sharding recipe the training engine uses, minus
-    the optimizer state serving has no use for."""
+    the optimizer state serving has no use for. The dtype is the
+    model's own (``model.init``'s float32, or what a leafwise init makes
+    in place): the ENGINE owns the dtype at rest and converts a tree it
+    is handed once (``ServeEngine._resident``)."""
     model = get_model(model_cfg.name)
     if getattr(model, "LEAFWISE_INIT", False):
         # a model whose float32 whole does not fit the chip that serves
@@ -93,6 +97,15 @@ class ServeEngine:
     program — the latency SLO never pays a recompile for degrading.
     The default ladder is ``(decode_k,)``, which keeps the original
     two-program contract bit-for-bit.
+
+    The weights rest on the device in ``dtype``: every public method
+    that takes ``params`` hands the programs ``_resident(params)``, the
+    tree with each floating leaf of another dtype converted once. The
+    engine keeps the LAST tree it was handed beside its converted twin,
+    on the instance and nowhere else, until another tree arrives
+    (swapped weights convert again) or the engine is dropped (both go
+    with it); a tree already in ``dtype`` is its own twin and costs
+    nothing.
     """
 
     paged = False          # the scheduler branches on this, not on type
@@ -137,6 +150,8 @@ class ServeEngine:
             layout=layout)
         self.prefill_traces: list = []
         self.decode_traces: list = []
+        # the last params tree handed in, and what the programs get for it
+        self._resident_of = self._resident_tree = None
         # per-program lowering skeletons, captured at each program's
         # first call (program_memory / the memledger's per-program
         # memory_analysis reads these off the request clock)
@@ -148,6 +163,39 @@ class ServeEngine:
         self._decode = OnMesh(
             jax.jit(self._decode_body, static_argnums=(2,),
                     donate_argnums=(1,)), mesh)
+
+    # --------------------------------------------------------- weights
+
+    def _resident(self, params):
+        """``params`` as the programs take it: every floating
+        ``jax.Array`` leaf whose dtype is not the engine's is converted
+        on the device, leaf by leaf and with its sharding kept, so that
+        ``scopes.cast`` inside the programs is a no-op and they emit no
+        convert of a weight. Every other leaf is handed back as the same
+        object (no copy), and a tree with nothing to convert as itself.
+        The result is remembered for that tree by identity (and is its
+        own result): the hot path pays two ``is``."""
+        if params is self._resident_of or params is self._resident_tree:
+            return self._resident_tree
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        todo = [i for i, w in enumerate(leaves)
+                if isinstance(w, jax.Array) and w.dtype != self.dtype
+                and jnp.issubdtype(w.dtype, jnp.floating)]
+        tree = params
+        if todo:
+            itemsize = jnp.dtype(self.dtype).itemsize
+            with trace_lib.get().span(
+                    "weights_resident", cat="serve", leaves=len(leaves),
+                    leaves_cast=len(todo),
+                    bytes_in=sum(leaves[i].nbytes for i in todo),
+                    bytes_out=sum(leaves[i].size * itemsize
+                                  for i in todo)):
+                for i in todo:
+                    leaves[i] = leaves[i].astype(self.dtype)
+                jax.block_until_ready([leaves[i] for i in todo])
+            tree = treedef.unflatten(leaves)
+        self._resident_of, self._resident_tree = params, tree
+        return tree
 
     # ----------------------------------------------------------- state
 
@@ -254,6 +302,7 @@ class ServeEngine:
         admission reuses the one compiled program. Returns the updated
         state and the request's FIRST generated token (a device scalar
         — ``int()`` it to fence)."""
+        params = self._resident(params)
         tokens = jnp.asarray(tokens, jnp.int32).reshape(1, self.prompt_pad)
         args = (params, state, tokens, jnp.int32(prompt_len),
                 jnp.int32(slot), jnp.int32(max_new))
@@ -320,6 +369,7 @@ class ServeEngine:
         slots), valid (k, slots))`` — entries with ``valid=False`` are
         placeholders (-1) and must not be read. Async: fence on the
         returned tokens."""
+        params = self._resident(params)
         k = self.decode_k if k is None else int(k)
         if k not in self.ladder:
             # fail at the fault site: a foreign k would silently trace
@@ -344,6 +394,7 @@ class ServeEngine:
         state (donated away), fences, and leaves the jit caches warm —
         after this, a whole serve run (adapt transitions included)
         compiles nothing (``assert_two_programs``)."""
+        params = self._resident(params)
         state = self.init_state()
         dummy = jnp.zeros((1, self.prompt_pad), jnp.int32)
         state, first = self.prefill(params, state, dummy, 1, 0, 2)
@@ -609,6 +660,7 @@ class PagedServeEngine(ServeEngine):
         slot's page-table ROW (defaults to the allocator's current row
         for ``slot``) and the shared-prefix watermark ``shared_len``
         (``alloc.admit_shared_len``) — both traced, one program."""
+        params = self._resident(params)
         tokens = jnp.asarray(tokens, jnp.int32).reshape(1, self.prompt_pad)
         if page_row is None:
             page_row = self.alloc.row(slot)
@@ -634,6 +686,7 @@ class PagedServeEngine(ServeEngine):
         page (``prefix_len % page_tokens`` positions) routes to trash
         here; admissions recompute it into their first private page —
         the copy-on-write fork, done eagerly by recomputation."""
+        params = self._resident(params)
         pages = self.alloc.register_shared(prefix_len)
         if not pages:
             return state
@@ -729,6 +782,7 @@ class PagedServeEngine(ServeEngine):
         allocator's) and the dispatch's slot mask go in as small traced
         int32/bool arrays — fixed shapes, so every dispatch reuses the
         rung's one compiled program."""
+        params = self._resident(params)
         k = self.decode_k if k is None else int(k)
         if k not in self.ladder:
             raise ValueError(
@@ -804,6 +858,7 @@ class PagedServeEngine(ServeEngine):
         drafts' junk K/V lands beyond the new length and is overwritten
         (write-then-attend) before any query can reach it, which is
         what makes greedy output bitwise speculation-free."""
+        params = self._resident(params)
         if self.speculate_k < 2:
             raise ValueError("verify() requires speculate_k >= 2")
         draft = jnp.asarray(draft, jnp.int32).reshape(
@@ -824,6 +879,7 @@ class PagedServeEngine(ServeEngine):
         speculating) off the request clock, on a throwaway state and a
         junk page table (compilation only sees shapes; the junk writes
         route to the trash page)."""
+        params = self._resident(params)
         state = self.init_state()
         dummy = jnp.zeros((1, self.prompt_pad), jnp.int32)
         row = np.full((self.spec.max_pages_per_slot,), -1, np.int32)
