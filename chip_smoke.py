@@ -309,7 +309,7 @@ def smoke(device: dict, phases: list) -> None:
          f"devtime comm_status {dev.get('comm_status')!r}"),
     ])
 
-    # ---- C: the fused LM head (what ``bench.py --fused-xent`` runs)
+    # ---- C: the fused LM head (``--lm-head fused``)
     c = run(phases, "C_train_fused_head",
             [*TRAIN, "--lm-head", "fused", "--remat", "--train-batch-size",
              "96", "--n-samples", "384", "--epochs", "1", "--log-every",

@@ -24,7 +24,7 @@ from tpudist.models import cohere2moe as M
 from tpudist.models import get_model
 from tpudist.parallel.mesh import build_mesh
 from tpudist.serve import kvcache
-from tpudist.serve.engine import PagedServeEngine, ServeEngine, init_params
+from tpudist.serve.engine import PagedServeEngine, init_params
 
 jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -386,11 +386,9 @@ def test_run_serve_carries_the_counts_on_its_spans(mesh):
     assert 0 < summary["kv_pages_used_peak"] <= 20
 
 
-def test_the_engines_refuse_what_they_do_not_build(mesh):
+def test_the_engine_refuses_what_it_does_not_build(mesh):
     cfg, _ = configs()
     assert get_model("cohere2moe") is M
-    with pytest.raises(ValueError, match="paged engine only"):
-        ServeEngine(cfg, mesh, slots=2, max_seq=44, prompt_pad=16)
     with pytest.raises(ValueError, match="speculate"):
         PagedServeEngine(cfg, mesh, slots=2, max_seq=44, prompt_pad=16,
                          page_tokens=PAGE, speculate_k=3)

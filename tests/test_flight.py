@@ -37,8 +37,7 @@ from tpudist.serve import flight as flight_lib
 from tpudist.serve import resilience as res_lib
 from tpudist.serve import scheduler as sched
 from tpudist.serve import slo as slo_lib
-from tpudist.serve.engine import (PagedServeEngine, ServeEngine,
-                                  init_params)
+from tpudist.serve.engine import PagedServeEngine, init_params
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -267,20 +266,20 @@ def test_trace_cross_check_token_drift_and_drop_skip():
 # -------------------------------------- in-process end-to-end exactness
 
 
-def _tiny_engine(devices8, cls=ServeEngine, **kw):
+def _tiny_engine(devices8, **kw):
     mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
     params = init_params(TINY_TF, mesh, seed=0)
     kw.setdefault("slots", 2)
     kw.setdefault("max_seq", 16)
     kw.setdefault("prompt_pad", 4)
     kw.setdefault("decode_k", 4)
-    return cls(TINY_TF, mesh, **kw), params
+    return PagedServeEngine(TINY_TF, mesh, **kw), params
 
 
-def _overload_run(devices8, metrics, *, cls=ServeEngine, engine_kw=None,
+def _overload_run(devices8, metrics, *, engine_kw=None,
                   shared_prefix=None, n=40, rate=800.0, prompt_pad=4,
                   prefix_len=0):
-    engine, params = _tiny_engine(devices8, cls=cls, **(engine_kw or {}))
+    engine, params = _tiny_engine(devices8, **(engine_kw or {}))
     engine.warmup(params)
     requests = sched.make_requests(n, prompt_pad=prompt_pad,
                                    vocab_size=64, max_new=6, rate=rate,
@@ -334,7 +333,7 @@ def test_paged_spec_run_kv_counters_and_slot_tracks(devices8,
     shared = sched.shared_prefix_tokens(8, 64, seed=11)  # = request seed
     m = RecMetrics()
     s = _overload_run(
-        devices8, m, cls=PagedServeEngine,
+        devices8, m,
         engine_kw=dict(slots=3, max_seq=32, prompt_pad=16, decode_k=4,
                        page_tokens=8, speculate_k=4),
         shared_prefix=shared, n=24, rate=400.0, prompt_pad=16,

@@ -2,9 +2,10 @@
 
 The acceptance pins:
 
-* greedy token streams are BITWISE identical across the dense arena,
-  the paged engine, and the paged engine with speculative decoding —
-  transformer and MoE, 1- and 4-device CPU meshes;
+* greedy token streams of the paged engine, with and without a shared
+  prefix and speculative decoding, are the naive full-forward greedy
+  loop's, token for token — transformer and MoE, 1- and 4-device CPU
+  meshes;
 * the generalized program budget holds: one prefill, one decode per
   ladder rung, plus exactly one verify program iff speculation is on;
 * the host page allocator's invariants: FIFO determinism, all-or-
@@ -14,8 +15,8 @@ The acceptance pins:
 * admission denied by page exhaustion is backpressure (request stays
   queued) while a structurally unservable prompt is rejected — with
   the shed ledger's partition exact either way;
-* the fixed-HBM headline: a paged pool strictly smaller in bytes than
-  the dense arena sustains strictly more concurrent sequences;
+* the fixed-HBM headline: a pool strictly smaller in bytes than four
+  slots' full capacity sustains strictly more concurrent sequences;
 * the paged footprint (pool + table, trash included) is what
   serve_tick / the summary / the live Prometheus gauges report;
 * the serve tuner's paged coordinates: fingerprint schema bump, cache
@@ -41,8 +42,9 @@ from tpudist.parallel import build_mesh
 from tpudist.serve import kvcache
 from tpudist.serve import scheduler as sched
 from tpudist.serve import tune as serve_tune
-from tpudist.serve.engine import (PagedServeEngine, ServeEngine,
-                                  init_params)
+from tpudist.serve.engine import PagedServeEngine, init_params
+
+from serve_reference import greedy_tokens
 
 TINY_TF = ModelConfig(name="transformer", vocab_size=64, n_layers=2,
                       d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
@@ -196,7 +198,7 @@ def test_paged_spec_bytes_counts_pool_trash_and_table():
     pool_elems = 2 * 7 * 4 * 2 * 8
     assert spec.table_bytes == 2 * 4 * 4
     assert spec.bytes == 2 * pool_elems * 4 + spec.table_bytes
-    # default pool = full dense capacity (slots x max pages)
+    # default pool = full capacity (slots x max pages)
     assert _spec(slots=2, max_seq=16, pt=4, pages=0).pages == 8
     with pytest.raises(ValueError):
         _spec(pt=0)
@@ -205,97 +207,79 @@ def test_paged_spec_bytes_counts_pool_trash_and_table():
 
 
 # ------------------------------------------------------------------ #
-# bitwise parity: dense vs paged vs paged+speculative                 #
+# token parity: the engine, +shared prefix, +speculation vs reference #
 # ------------------------------------------------------------------ #
 
 @pytest.mark.parametrize("model_name", list(CFGS))
 @pytest.mark.parametrize("n_dev", [1, 4])
-def test_paged_greedy_matches_dense(devices8, model_name, n_dev):
-    """The paged engine's whole serve lane (scatter prefill, gather-free
+def test_paged_greedy_matches_reference(devices8, model_name, n_dev):
+    """The engine's whole serve lane (scatter prefill, gather-free
     write-then-attend decode, host page table) must emit the SAME token
-    streams as the dense arena — per request, bitwise. The 9-layer
-    configs hold the rolled layer loop to it."""
+    streams as the naive full-forward greedy loop — per request. The
+    9-layer configs hold the rolled layer loop to it."""
     cfg = CFGS[model_name]
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
     params = init_params(cfg, mesh, seed=0)
-    outs = {}
-    for tag, engine in (
-            ("dense", ServeEngine(cfg, mesh, slots=2, max_seq=32,
-                                  prompt_pad=8, decode_k=4)),
-            ("paged", PagedServeEngine(cfg, mesh, slots=2, max_seq=32,
-                                       prompt_pad=8, decode_k=4,
-                                       page_tokens=8))):
-        engine.warmup(params)
-        reqs = sched.make_requests(5, prompt_pad=8,
-                                   vocab_size=cfg.vocab_size,
-                                   max_new=6, rate=0.0, seed=3)
-        summary = sched.run_serve(engine, params, reqs)
-        engine.assert_two_programs()
-        assert summary["completed"] == 5, summary["partition"]
-        outs[tag] = _outputs(summary)
-    assert outs["dense"] == outs["paged"]
+    engine = PagedServeEngine(cfg, mesh, slots=2, max_seq=32,
+                              prompt_pad=8, decode_k=4, page_tokens=8)
+    engine.warmup(params)
+    reqs = sched.make_requests(5, prompt_pad=8, vocab_size=cfg.vocab_size,
+                               max_new=6, rate=0.0, seed=3)
+    summary = sched.run_serve(engine, params, reqs)
+    engine.assert_two_programs()
+    assert summary["completed"] == 5, summary["partition"]
+    assert _outputs(summary) == greedy_tokens(cfg, params, reqs)
 
 
 @pytest.mark.parametrize("prefix_len", [8, 12])
-def test_shared_prefix_paged_matches_dense(devices8, prefix_len):
+def test_shared_prefix_matches_reference(devices8, prefix_len):
     """One cached system prompt serving every request must not move a
-    single token: paged + shared prefix vs dense over the same stream.
+    single token: shared prefix vs the naive loop over the same stream.
     prefix 8 ends exactly on the page boundary (the COW fork takes no
     private page); prefix 12 forks its partial tail by recomputation."""
     mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
     params = init_params(TINY_TF, mesh, seed=0)
     shared = sched.shared_prefix_tokens(prefix_len, 64, seed=5)
-    outs = {}
-    for tag, engine, prefix in (
-            ("dense", ServeEngine(TINY_TF, mesh, slots=2, max_seq=32,
-                                  prompt_pad=16, decode_k=4), None),
-            ("paged", PagedServeEngine(TINY_TF, mesh, slots=2,
-                                       max_seq=32, prompt_pad=16,
-                                       decode_k=4, page_tokens=8),
-             shared)):
-        engine.warmup(params)
-        reqs = sched.make_requests(6, prompt_pad=16, vocab_size=64,
-                                   max_new=6, rate=0.0, seed=5,
-                                   prefix_len=prefix_len)
-        summary = sched.run_serve(engine, params, reqs,
-                                  shared_prefix=prefix)
-        engine.assert_two_programs()
-        assert summary["completed"] == 6, summary["partition"]
-        outs[tag] = _outputs(summary)
-        if tag == "paged":
-            assert summary["shared_prefix_len"] == prefix_len
-            # the registry hold keeps the full prefix pages cached
-            # after every slot has drained
-            full = (prefix_len // 8) * 8
-            assert engine.alloc.shared_len == full
-            assert engine.alloc.pages_used() == full // 8
-    assert outs["dense"] == outs["paged"]
+    engine = PagedServeEngine(TINY_TF, mesh, slots=2, max_seq=32,
+                              prompt_pad=16, decode_k=4, page_tokens=8)
+    engine.warmup(params)
+    reqs = sched.make_requests(6, prompt_pad=16, vocab_size=64,
+                               max_new=6, rate=0.0, seed=5,
+                               prefix_len=prefix_len)
+    summary = sched.run_serve(engine, params, reqs, shared_prefix=shared)
+    engine.assert_two_programs()
+    assert summary["completed"] == 6, summary["partition"]
+    assert summary["shared_prefix_len"] == prefix_len
+    # the registry hold keeps the full prefix pages cached after every
+    # slot has drained
+    full = (prefix_len // 8) * 8
+    assert engine.alloc.shared_len == full
+    assert engine.alloc.pages_used() == full // 8
+    assert _outputs(summary) == greedy_tokens(TINY_TF, params, reqs)
 
 
 @pytest.mark.parametrize("model_name", ["transformer", "transformer-l9"])
 @pytest.mark.parametrize("n_dev", [1, 4])
-def test_speculative_greedy_bitwise_vs_dense(devices8, n_dev, model_name):
+def test_speculative_greedy_matches_reference(devices8, n_dev, model_name):
     """Speculation is a pure latency play: k-token n-gram drafts
-    verified in ONE batched target forward must reproduce the dense
-    greedy stream bitwise — accepted or rejected, no token moves."""
+    verified in ONE batched target forward must reproduce the plain
+    greedy stream — accepted or rejected, no token moves: bitwise
+    against the engine's own plain decode, and the naive loop's tokens."""
     cfg = CFGS[model_name]
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
     params = init_params(cfg, mesh, seed=0)
     shared = sched.shared_prefix_tokens(8, 64, seed=13)
     outs = {}
-    for tag, engine, prefix in (
-            ("dense", ServeEngine(cfg, mesh, slots=3, max_seq=32,
-                                  prompt_pad=16, decode_k=4), None),
-            ("spec", PagedServeEngine(cfg, mesh, slots=3,
-                                      max_seq=32, prompt_pad=16,
-                                      decode_k=4, page_tokens=8,
-                                      speculate_k=4), shared)):
+    for tag, spec_k in (("plain", 0), ("spec", 4)):
+        engine = PagedServeEngine(cfg, mesh, slots=3, max_seq=32,
+                                  prompt_pad=16, decode_k=4,
+                                  page_tokens=8, speculate_k=spec_k)
         engine.warmup(params)
         reqs = sched.make_requests(8, prompt_pad=16, vocab_size=64,
                                    max_new=10, rate=0.0, seed=13,
                                    prefix_len=8)
         summary = sched.run_serve(engine, params, reqs,
-                                  shared_prefix=prefix)
+                                  shared_prefix=shared)
         engine.assert_two_programs()
         assert summary["completed"] == 8, summary["partition"]
         outs[tag] = _outputs(summary)
@@ -304,7 +288,8 @@ def test_speculative_greedy_bitwise_vs_dense(devices8, n_dev, model_name):
             assert summary["speculate_k"] == 4
             rate = summary["spec_accept_rate"]
             assert rate is not None and 0.0 <= rate <= 1.0
-    assert outs["dense"] == outs["spec"]
+    assert outs["plain"] == outs["spec"]
+    assert outs["spec"] == greedy_tokens(cfg, params, reqs)
 
 
 def test_program_pins_paged_and_speculative(devices8):
@@ -470,9 +455,9 @@ def test_growth_failure_evicts_and_frees_pages(devices8):
 # the fixed-HBM headline: more concurrency in fewer bytes             #
 # ------------------------------------------------------------------ #
 
-def test_fixed_hbm_paged_sustains_more_slots_than_dense(devices8):
-    """The tentpole's acceptance: a paged pool STRICTLY smaller in
-    bytes than the dense arena (trash page and page table included)
+def test_fixed_hbm_small_pool_sustains_more_slots(devices8):
+    """The tentpole's acceptance: a pool STRICTLY smaller in bytes than
+    four slots at full capacity (trash page and page table included)
     sustains STRICTLY more concurrent sequences under the same
     shared-prefix load."""
     mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
@@ -480,19 +465,20 @@ def test_fixed_hbm_paged_sustains_more_slots_than_dense(devices8):
     # make_requests derives the in-prompt prefix from ITS seed — the
     # registered prefix must use the same one or no prompt byte-matches
     shared = sched.shared_prefix_tokens(8, 64, seed=21)
-    dense = ServeEngine(TINY_TF, mesh, slots=4, max_seq=32,
-                        prompt_pad=16, decode_k=8)
-    # dense arena = 16 page-equivalents (4 slots x 32/8); the paged
-    # pool holds 6 slots in 14 pages: worst case 6 x 2 private pages
-    # (final length <= 24 -> 3 pages, 1 of them shared) + 1 shared
-    paged = PagedServeEngine(TINY_TF, mesh, slots=6, max_seq=32,
+    full = PagedServeEngine(TINY_TF, mesh, slots=4, max_seq=32,
+                            prompt_pad=16, decode_k=8, page_tokens=8)
+    # full capacity = 16 pages (4 slots x 32/8); the small pool holds 6
+    # slots in 14 pages: worst case 6 x 2 private pages (final length
+    # <= 24 -> 3 pages, 1 of them shared) + 1 shared
+    small = PagedServeEngine(TINY_TF, mesh, slots=6, max_seq=32,
                              prompt_pad=16, decode_k=8, page_tokens=8,
                              pages=14)
-    assert paged.spec.bytes < dense.spec.bytes, (
-        paged.spec.bytes, dense.spec.bytes)
+    assert full.spec.pages == 16
+    assert small.spec.bytes < full.spec.bytes, (
+        small.spec.bytes, full.spec.bytes)
     peaks = {}
-    for tag, engine, prefix in (("dense", dense, None),
-                                ("paged", paged, shared)):
+    for tag, engine, prefix in (("full", full, None),
+                                ("small", small, shared)):
         engine.warmup(params)
         reqs = sched.make_requests(16, prompt_pad=16, vocab_size=64,
                                    max_new=8, rate=0.0, seed=21,
@@ -502,9 +488,8 @@ def test_fixed_hbm_paged_sustains_more_slots_than_dense(devices8):
         engine.assert_two_programs()
         assert summary["completed"] == 16, summary["partition"]
         peaks[tag] = summary["active_slots_peak"]
-        if tag == "paged":
-            assert summary["kv_pages_used_peak"] <= paged.spec.pages
-    assert peaks["paged"] > peaks["dense"], peaks
+        assert summary["kv_pages_used_peak"] <= engine.spec.pages
+    assert peaks["small"] > peaks["full"], peaks
 
 
 # ------------------------------------------------------------------ #
@@ -548,8 +533,8 @@ def test_spec_accept_rule_in_rules_table(monkeypatch):
 
 def test_live_gauges_ingest_and_render(tmp_path):
     """Consumer parity for the three paged gauges: a serve_tick record
-    flows through the aggregator into /metrics; a dense run (no paged
-    keys) renders none of them."""
+    flows through the aggregator into /metrics; a tick without those
+    keys renders none of them."""
     agg = live_lib.LiveAggregator(out_dir=str(tmp_path),
                                   start_ticker=False)
     agg.ingest({"kind": "serve_tick", "completed": 2,
@@ -563,7 +548,7 @@ def test_live_gauges_ingest_and_render(tmp_path):
     assert "tpudist_serve_kv_pages_used 5" in text
     assert "tpudist_serve_kv_pages_total 24" in text
     assert "tpudist_serve_spec_accept_rate 0.75" in text
-    # absent keys render nothing (the golden dense exposition is safe)
+    # absent keys render nothing (the golden exposition is safe)
     agg2 = live_lib.LiveAggregator(out_dir=str(tmp_path / "d"),
                                    start_ticker=False)
     agg2.ingest({"kind": "serve_tick", "completed": 1,
@@ -591,57 +576,53 @@ def test_ngram_draft_lookup_and_fallback():
 # ------------------------------------------------------------------ #
 
 def test_validate_serve_tuned_paged_schema():
-    ok = {"decode_k": 8, "layout": "st", "kv_page_tokens": 8,
-          "speculate_k": 4}
+    ok = {"decode_k": 8, "kv_page_tokens": 8, "speculate_k": 4}
     assert serve_tune.validate_serve_tuned(ok)
     # pre-paging records are a cache MISS, never a crash
-    assert not serve_tune.validate_serve_tuned(
-        {"decode_k": 8, "layout": "st"})
+    assert not serve_tune.validate_serve_tuned({"decode_k": 8})
     assert not serve_tune.validate_serve_tuned(
         dict(ok, speculate_k=1))               # window of 1 is invalid
     assert not serve_tune.validate_serve_tuned(
-        dict(ok, kv_page_tokens=0))            # speculation needs pages
-    assert serve_tune.validate_serve_tuned(
-        dict(ok, kv_page_tokens=0, speculate_k=0))
+        dict(ok, kv_page_tokens=0))            # a page holds a position
+    assert serve_tune.validate_serve_tuned(dict(ok, speculate_k=0))
     assert not serve_tune.validate_serve_tuned(
         dict(ok, kv_page_tokens=-1))
 
 
 def test_search_walks_paged_axes_with_real_win_bar():
     """The axis walk adopts a page size / speculate window only on a
-    REAL measured win, gates speculation behind a committed page size,
-    and never commits a point slower than the measured start."""
+    REAL measured win, and never commits a point slower than the
+    measured start."""
     def measure_from(table):
         def measure(cand):
             return serve_tune.ServeProbeResult(
                 tokens_per_sec=table(cand), dispatch_ms=1.0)
         return measure
 
-    start = serve_tune.ServeCandidate(decode_k=8, layout="st")
-    # paged wins big, then speculation wins on top of it
+    start = serve_tune.ServeCandidate(decode_k=8, kv_page_tokens=64)
+    # a smaller page wins big, then speculation wins on top of it
     res = serve_tune._search(
         measure_from(lambda c: 100.0 + 50 * (c.kv_page_tokens == 16)
                      + 50 * (c.speculate_k == 4)),
-        start, max_decode_k=8, trial_budget=32, max_page_tokens=32)
+        start, max_decode_k=8, trial_budget=32, max_page_tokens=64)
     assert res["best"].kv_page_tokens == 16
     assert res["best"].speculate_k == 4
     assert res["best_tps"] >= res["baseline_tps"]
-    # a tie keeps the dense arena (simpler program), so speculation
-    # never probes at all
+    # a tie keeps the start's page and plain decode
     res = serve_tune._search(
         measure_from(lambda c: 100.0), start, max_decode_k=8,
-        trial_budget=32, max_page_tokens=32)
-    assert res["best"].kv_page_tokens == 0
+        trial_budget=32, max_page_tokens=64)
+    assert res["best"].kv_page_tokens == 64
     assert res["best"].speculate_k == 0
     # paged axes are OFF without the opt-in bound
     res = serve_tune._search(
-        measure_from(lambda c: 100.0 + 500 * (c.kv_page_tokens > 0)),
+        measure_from(lambda c: 100.0 + 500 * (c.kv_page_tokens != 64)),
         start, max_decode_k=8, trial_budget=32)
-    assert res["best"].kv_page_tokens == 0
+    assert res["best"].kv_page_tokens == 64
     # the hard floor: everything measures slower than start -> start
     res = serve_tune._search(
         measure_from(lambda c: 100.0 if c == start else 1.0),
-        start, max_decode_k=32, trial_budget=32, max_page_tokens=32)
+        start, max_decode_k=32, trial_budget=32, max_page_tokens=64)
     assert res["best"] == start
     assert res["best_tps"] == res["baseline_tps"] == 100.0
 
@@ -680,11 +661,13 @@ def test_serve_fingerprint_distinct_from_pre_paging_schema(devices8):
 # CLI wiring                                                          #
 # ------------------------------------------------------------------ #
 
-def test_cli_speculate_requires_paging(tmp_path):
+def test_cli_refuses_a_speculation_window_of_one(tmp_path, capsys):
+    """``--speculate-k 1`` is a window with no draft in it: the engine's
+    ValueError, as the CLI's fail verdict and exit code."""
     from tpudist.serve import cli
-    with pytest.raises(SystemExit, match="kv-page-tokens"):
-        cli.main(["--speculate-k", "2", "--requests", "1",
-                  "--save-dir", str(tmp_path)])
+    assert cli.main(["--speculate-k", "1", "--requests", "1",
+                     "--save-dir", str(tmp_path)]) == 1
+    assert "--speculate-k must be 0 (off) or >= 2" in capsys.readouterr().err
 
 
 @pytest.mark.slow

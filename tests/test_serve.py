@@ -3,10 +3,10 @@
 The two correctness anchors the ISSUE names, plus the machinery around
 them:
 
-* decode-with-KV-cache logits must match the full-forward model apply
-  ULP-close, on a 1- AND 4-device CPU mesh, for the dense transformer
-  and the MoE model (the cache-aware incremental path must not fork the
-  math);
+* decode-over-the-KV-pool logits must match the full-forward model
+  apply ULP-close, on a 1- AND 4-device CPU mesh, for the dense
+  transformer and the MoE model (the paged incremental path must not
+  fork the math);
 * greedy decodes are bitwise reproducible run-to-run;
 * exactly TWO compiled programs per serve run (one prefill, one decode
   superstep), warmup included;
@@ -39,7 +39,9 @@ from tpudist.parallel import sharding as shd
 from tpudist.serve import kvcache, slo
 from tpudist.serve import scheduler as sched
 from tpudist.serve import tune as serve_tune
-from tpudist.serve.engine import ServeEngine, init_params
+from tpudist.serve.engine import PagedServeEngine, init_params
+
+from serve_reference import greedy_tokens, ref_logits
 
 TINY_TF = ModelConfig(name="transformer", vocab_size=64, n_layers=2,
                       d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
@@ -56,22 +58,6 @@ TINY_MOE = ModelConfig(name="moe", vocab_size=64, n_layers=2,
                        max_seq_len=32, n_experts=4, expert_top_k=2,
                        capacity_factor=4.0)
 CFGS = {"transformer": TINY_TF, "moe": TINY_MOE}
-
-
-def _ref_logits(model, params, seq) -> np.ndarray:
-    """Full-forward reference: logits (seq, vocab) f32 for one sequence
-    through the TRAINING path (no cache) — the anchor the cached path
-    is graded against."""
-    cfg = CFGS[_model_name(model)]
-    out = model.hidden_states(params, jnp.asarray(seq, jnp.int32)[None],
-                              cfg, dtype=jnp.float32)
-    h = out[0] if isinstance(out, tuple) else out
-    emb = params["embed"].astype(jnp.float32)
-    return np.asarray((h @ emb.T).astype(jnp.float32))[0]
-
-
-def _model_name(model) -> str:
-    return model.__name__.rsplit(".", 1)[-1]
 
 
 def _assert_ulp_close(a: np.ndarray, b: np.ndarray, ulps: int = 64,
@@ -104,32 +90,48 @@ def _assert_ulp_close(a: np.ndarray, b: np.ndarray, ulps: int = 64,
     "transformer", pytest.param("moe", marks=pytest.mark.slow)])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_cached_logits_match_full_forward(devices8, model_name, n_dev):
-    """Prefill seeds the cache, then each decode step's logits must
-    match the full forward over the growing true sequence ULP-close —
-    per slot, at per-slot positions (the continuous batch decodes 4
-    sequences of DIFFERENT lengths in one program)."""
+    """Prefill seeds the slots' pages, then each ``paged_hidden_states``
+    token step's logits must match the full forward over the growing
+    true sequence ULP-close — per slot, at per-slot positions (the
+    continuous batch decodes 4 sequences of DIFFERENT lengths in one
+    program)."""
     cfg = CFGS[model_name]
     model = get_model(model_name)
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
     params = init_params(cfg, mesh, seed=0)
-    b, pad, max_seq = 4, 8, 16
+    b, pad, max_seq, pt = 4, 8, 16, 4
     lens = [3, 5, 8, 2]
     rng = np.random.default_rng(7)
     prompts = rng.integers(0, cfg.vocab_size, size=(b, pad)).astype(
         np.int32)
 
-    spec = kvcache.CacheSpec.from_model(cfg, slots=b, max_seq=max_seq)
-    cache = kvcache.init_cache(spec, mesh)
-    h, cache = model.hidden_states(
+    spec = kvcache.PagedCacheSpec.from_model(cfg, slots=b, max_seq=max_seq,
+                                             page_tokens=pt)
+    scratch = jnp.zeros((spec.n_layers, b, pad, spec.n_kv_heads,
+                         spec.head_dim), jnp.float32)
+    h, kv = model.prefill_kv_hidden_states(
         params, jnp.asarray(prompts), cfg, dtype=jnp.float32,
-        kv_cache=cache, cur_index=None)
+        kv_cache={"k": scratch, "v": scratch})
     emb = params["embed"].astype(jnp.float32)
     prefill_logits = np.asarray((h @ emb.T).astype(jnp.float32))
+
+    # each prompt's K/V into the pages its slot was granted
+    alloc = kvcache.PageAllocator(spec)
+    pools = {n: np.zeros(spec.pool_shape, np.float32) for n in "kv"}
+    for i in range(b):
+        assert alloc.admit(i, lens[i])
+        for p in range(lens[i]):
+            page = alloc.table[i, p // pt]
+            for n in "kv":      # (L, kv, hd) of position p
+                pools[n][:, :, page, p % pt] = np.asarray(kv[n][:, i, p])
+    sh = kvcache.paged_cache_shardings(spec, mesh)
+    pool_k = jax.device_put(pools["k"], sh)
+    pool_v = jax.device_put(pools["v"], sh)
 
     seqs = [list(prompts[i, :lens[i]]) for i in range(b)]
     last = np.zeros((b,), np.int32)
     for i in range(b):
-        ref = _ref_logits(model, params, seqs[i])
+        ref = ref_logits(cfg, params, seqs[i])
         _assert_ulp_close(prefill_logits[i, lens[i] - 1], ref[-1],
                           what=f"{model_name}/{n_dev}dev prefill "
                                f"slot{i}")
@@ -138,12 +140,17 @@ def test_cached_logits_match_full_forward(devices8, model_name, n_dev):
 
     pos = np.asarray(lens, np.int32)
     for step in range(4):
-        h, cache = model.hidden_states(
+        for i in range(b):
+            assert alloc.ensure(i, int(pos[i]))
+        h, pool_k, pool_v = model.paged_hidden_states(
             params, jnp.asarray(last[:, None]), cfg, dtype=jnp.float32,
-            kv_cache=cache, cur_index=jnp.asarray(pos))
+            pool_k=pool_k, pool_v=pool_v,
+            page_table=jnp.asarray(alloc.table, jnp.int32),
+            positions=jnp.asarray(pos[:, None]),
+            write_ok=jnp.ones((b, 1), bool), page_tokens=pt)
         dec = np.asarray((h[:, 0] @ emb.T).astype(jnp.float32))
         for i in range(b):
-            ref = _ref_logits(model, params, seqs[i])
+            ref = ref_logits(cfg, params, seqs[i])
             _assert_ulp_close(dec[i], ref[-1],
                               what=f"{model_name}/{n_dev}dev step{step} "
                                    f"slot{i}")
@@ -161,11 +168,10 @@ def test_engine_greedy_matches_reference(devices8, model_name, n_dev):
     superstep, continuous admission) must greedily decode the SAME
     token sequences as a naive full-forward greedy loop."""
     cfg = CFGS[model_name]
-    model = get_model(model_name)
     mesh = build_mesh(ParallelConfig(), devices=devices8[:n_dev])
     params = init_params(cfg, mesh, seed=0)
-    engine = ServeEngine(cfg, mesh, slots=2, max_seq=32, prompt_pad=8,
-                         decode_k=4)
+    engine = PagedServeEngine(cfg, mesh, slots=2, max_seq=32,
+                              prompt_pad=8, decode_k=4)
     engine.warmup(params)
     requests = sched.make_requests(5, prompt_pad=8,
                                    vocab_size=cfg.vocab_size,
@@ -173,16 +179,12 @@ def test_engine_greedy_matches_reference(devices8, model_name, n_dev):
     summary = sched.run_serve(engine, params, requests)
     engine.assert_two_programs()
     assert summary["completed"] == 5 and summary["truncated"] == 0
+    want = greedy_tokens(cfg, params, requests)
     for req in requests:
-        seq = list(req.tokens[:req.prompt_len])
-        want = []
-        for _ in range(req.max_new):
-            want.append(int(np.argmax(_ref_logits(model, params,
-                                                  seq)[-1])))
-            seq.append(want[-1])
         got = summary["results"][req.rid]["tokens"]
-        assert got == want, (
-            f"{model_name}/{n_dev}dev rid{req.rid}: {got} != {want}")
+        assert got == want[req.rid], (
+            f"{model_name}/{n_dev}dev rid{req.rid}: {got} != "
+            f"{want[req.rid]}")
 
 
 def test_greedy_decode_bitwise_run_to_run(devices8):
@@ -192,8 +194,8 @@ def test_greedy_decode_bitwise_run_to_run(devices8):
     for _ in range(2):
         mesh = build_mesh(ParallelConfig(), devices=devices8[:4])
         params = init_params(TINY_TF, mesh, seed=1)
-        engine = ServeEngine(TINY_TF, mesh, slots=4, max_seq=32,
-                             prompt_pad=8, decode_k=8)
+        engine = PagedServeEngine(TINY_TF, mesh, slots=4, max_seq=32,
+                                  prompt_pad=8, decode_k=8)
         engine.warmup(params)
         requests = sched.make_requests(8, prompt_pad=8, vocab_size=64,
                                        max_new=10, rate=0.0, seed=11)
@@ -213,7 +215,7 @@ def _tiny_engine(devices8, **kw):
     kw.setdefault("max_seq", 16)
     kw.setdefault("prompt_pad", 4)
     kw.setdefault("decode_k", 4)
-    return ServeEngine(TINY_TF, mesh, **kw), params
+    return PagedServeEngine(TINY_TF, mesh, **kw), params
 
 
 def test_exactly_two_compiled_programs(devices8):
@@ -304,64 +306,69 @@ def test_all_full_admission_queues(devices8):
 def test_engine_arg_validation(devices8):
     mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
     with pytest.raises(ValueError, match="--slots"):
-        ServeEngine(TINY_TF, mesh, slots=0, max_seq=16, prompt_pad=4)
+        PagedServeEngine(TINY_TF, mesh, slots=0, max_seq=16, prompt_pad=4)
     with pytest.raises(ValueError, match="decode-steps"):
-        ServeEngine(TINY_TF, mesh, slots=1, max_seq=16, prompt_pad=4,
-                    decode_k=0)
+        PagedServeEngine(TINY_TF, mesh, slots=1, max_seq=16, prompt_pad=4,
+                         decode_k=0)
     with pytest.raises(ValueError, match="prompt_pad"):
-        ServeEngine(TINY_TF, mesh, slots=1, max_seq=16, prompt_pad=32)
+        PagedServeEngine(TINY_TF, mesh, slots=1, max_seq=16,
+                         prompt_pad=32)
 
 
 # ------------------------------------------------------------------ #
-# KV cache: spec, layouts, sharding                                   #
+# KV pool: spec, sharding; one forward type per model                 #
 # ------------------------------------------------------------------ #
 
 def test_cache_spec_gqa_compact():
-    spec = kvcache.CacheSpec.from_model(TINY_TF, slots=4, max_seq=16)
+    spec = kvcache.PagedCacheSpec.from_model(TINY_TF, slots=4, max_seq=16,
+                                             page_tokens=4)
     assert spec.n_kv_heads == 2          # compact, not n_heads=4
-    assert spec.canonical_shape == (2, 4, 16, 2, 8)
-    assert spec.bytes == 2 * 2 * 4 * 16 * 2 * 8 * 4
+    # full capacity: 4 slots x 4 pages, and the trash page
+    assert spec.pool_shape == (2, 2, 16 + 1, 4, 8)
+    assert spec.bytes == 2 * 2 * 2 * 17 * 4 * 8 * 4 + spec.table_bytes
 
 
-def test_cache_layout_roundtrip():
-    spec = kvcache.CacheSpec.from_model(TINY_TF, slots=4, max_seq=16,
-                                        layout="hs")
-    assert spec.storage_shape == (2, 4, 2, 16, 8)
-    x = jnp.arange(np.prod(spec.storage_shape),
-                   dtype=jnp.float32).reshape(spec.storage_shape)
-    rt = kvcache.from_canonical(kvcache.to_canonical(x, "hs"), "hs")
-    np.testing.assert_array_equal(np.asarray(rt), np.asarray(x))
-    with pytest.raises(ValueError, match="layout"):
-        kvcache.to_canonical(x, "zz")
-
-
-@pytest.mark.parametrize("layout", ["st", "hs"])
-def test_cache_sharded_over_mesh(devices8, layout):
-    """Slots ride the batch axes: a 4-slot cache on a 4-device data
-    mesh puts one slot page per device; odd slot counts sanitise to
-    replicated instead of erroring."""
+@pytest.mark.parametrize("pages", [7, 8], ids=["divides", "odd"])
+def test_pool_sharded_over_mesh(devices8, pages):
+    """Pages ride the batch axes: a pool of 7 pages and the trash page
+    on a 4-device data mesh puts two pages on each device; a pool the
+    axes do not divide (the common case: the trash page makes full
+    capacity odd) sanitises to replicated instead of erroring."""
     mesh = build_mesh(ParallelConfig(), devices=devices8[:4])
-    spec = kvcache.CacheSpec.from_model(TINY_TF, slots=4, max_seq=16,
-                                        layout=layout)
-    cache = kvcache.init_cache(spec, mesh)
-    shard_shapes = {s.data.shape for s in cache["k"].addressable_shards}
-    want = list(spec.storage_shape)
-    want[1] = 1
-    assert shard_shapes == {tuple(want)}
-    odd = kvcache.CacheSpec.from_model(TINY_TF, slots=3, max_seq=16,
-                                       layout=layout)
-    c3 = kvcache.init_cache(odd, mesh)
-    assert {s.data.shape for s in c3["k"].addressable_shards} \
-        == {odd.storage_shape}
+    spec = kvcache.PagedCacheSpec.from_model(
+        TINY_TF, slots=4, max_seq=16, page_tokens=4, pages=pages)
+    want = list(spec.pool_shape)
+    if (pages + 1) % 4 == 0:
+        want[2] = (pages + 1) // 4
+        assert kvcache.paged_cache_shardings(spec, mesh).spec \
+            == shd.paged_kv_cache_specs()
+    cache = kvcache.init_paged_cache(spec, mesh)
+    for pool in (cache["k"], cache["v"]):
+        assert {s.data.shape for s in pool.addressable_shards} \
+            == {tuple(want)}
 
 
-def test_kv_cache_specs_table():
-    assert shd.kv_cache_specs("st") == shd.P(
-        None, ("data", "fsdp"), None, "tensor", None)
-    assert shd.kv_cache_specs("hs") == shd.P(
-        None, ("data", "fsdp"), "tensor", None, None)
-    with pytest.raises(ValueError, match="layout"):
-        shd.kv_cache_specs("sx")
+@pytest.mark.parametrize("model_name", ["transformer", "moe"])
+def test_hidden_states_returns_one_type(model_name):
+    """``hidden_states`` is the training forward and nothing else: no
+    argument turns its return into a cache pair (serving has forwards
+    of its own, ``prefill_kv_hidden_states`` and
+    ``paged_hidden_states``)."""
+    import inspect
+    cfg = CFGS[model_name]
+    model = get_model(model_name)
+    accepted = set(inspect.signature(model.hidden_states).parameters)
+    assert not {"kv_cache", "cur_index"} & accepted
+    params = model.init(jax.random.PRNGKey(0), cfg)
+    toks = jnp.zeros((1, 5), jnp.int32)
+    kinds = set()
+    for kw in ({}, {"remat": True}, {"rope_offset": 3},
+               {"rope_positions": jnp.arange(5)}):
+        out = model.hidden_states(params, toks, cfg, dtype=jnp.float32,
+                                  **kw)
+        kinds.add(jax.tree.structure(out))
+        assert jax.tree.leaves(out)[0].shape == (1, 5, cfg.d_model)
+    assert len(kinds) == 1
 
 
 # ------------------------------------------------------------------ #
@@ -445,15 +452,13 @@ def test_poisson_arrivals_seeded():
 # serve autotuner: search discipline + fingerprint cache              #
 # ------------------------------------------------------------------ #
 
-def _scripted_measure(curve, layouts=None):
-    """A fake probe: tokens/s by decode_k from ``curve``, scaled per
-    layout by ``layouts`` (default: hs slightly worse)."""
-    layouts = layouts or {"st": 1.0, "hs": 0.9}
+def _scripted_measure(curve):
+    """A fake probe: tokens/s by decode_k from ``curve``."""
     calls = []
 
     def measure(cand):
         calls.append(cand)
-        tps = curve.get(cand.decode_k, 0.0) * layouts[cand.layout]
+        tps = curve.get(cand.decode_k, 0.0)
         if tps <= 0:
             return serve_tune.ServeProbeResult(0.0, float("inf"),
                                                feasible=False,
@@ -485,19 +490,6 @@ def test_search_never_commits_slower_than_start():
     assert out["best_tps"] == 500.0
 
 
-def test_search_layout_needs_a_real_win():
-    curve = {1: 100.0, 2: 200.0, 4: 200.0}
-    # hs measures 1% better: inside PLATEAU_TOL, start's layout keeps
-    m = _scripted_measure(curve, layouts={"st": 1.0, "hs": 1.01})
-    out = serve_tune._search(m, serve_tune.ServeCandidate(decode_k=1),
-                             max_decode_k=4, trial_budget=16)
-    assert out["best"].layout == "st"
-    m2 = _scripted_measure(curve, layouts={"st": 1.0, "hs": 1.5})
-    out2 = serve_tune._search(m2, serve_tune.ServeCandidate(decode_k=1),
-                              max_decode_k=4, trial_budget=16)
-    assert out2["best"].layout == "hs"
-
-
 def test_search_infeasible_point_prunes():
     curve = {1: 100.0, 2: 200.0, 4: 0.0, 8: 400.0}   # 4 OOMs
     m = _scripted_measure(curve)
@@ -508,19 +500,45 @@ def test_search_infeasible_point_prunes():
 
 
 def test_validate_serve_tuned():
-    # the paged axes are part of the schema now — a pre-paging 2-key
-    # record is stale by construction and must re-probe
-    assert serve_tune.validate_serve_tuned(
-        {"decode_k": 8, "layout": "st",
-         "kv_page_tokens": 0, "speculate_k": 0})
-    assert not serve_tune.validate_serve_tuned({"decode_k": 8,
-                                                "layout": "st"})
+    # the record's keys ARE the candidate's: a pre-paging 2-key record
+    # or one that still carries a storage layout is stale by
+    # construction and must re-probe
+    ok = {"decode_k": 8, "kv_page_tokens": 8, "speculate_k": 0}
+    assert serve_tune.validate_serve_tuned(ok)
+    assert not serve_tune.validate_serve_tuned({"decode_k": 8})
+    assert not serve_tune.validate_serve_tuned({**ok, "layout": "st"})
+    assert not serve_tune.validate_serve_tuned({**ok, "decode_k": 0})
     assert not serve_tune.validate_serve_tuned(
-        {"decode_k": 0, "layout": "st",
-         "kv_page_tokens": 0, "speculate_k": 0})
-    assert not serve_tune.validate_serve_tuned(
-        {"decode_k": 8, "layout": "zz",
-         "kv_page_tokens": 0, "speculate_k": 0})
+        {**ok, "kv_page_tokens": 0})
+
+
+def test_cached_tuning_with_a_layout_key_is_a_miss(devices8, tmp_path,
+                                                   monkeypatch):
+    """A tuning persisted while the tuner still walked storage layouts
+    is any other stale entry: ``cache-only`` serves the heuristic start,
+    ``probe`` measures again and overwrites it — never an error."""
+    from tpudist.tune import cache as cache_mod
+    mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
+    kw = dict(slots=2, max_seq=32, prompt_pad=8, cache_dir=str(tmp_path))
+    fp = serve_tune.fingerprint(TINY_TF, mesh, slots=2, max_seq=32,
+                                prompt_pad=8)
+    cache_mod.store(str(tmp_path), fp, {
+        "tuned": {"decode_k": 16, "layout": "hs", "kv_page_tokens": 0,
+                  "speculate_k": 0},
+        "tokens_per_sec": 9e9}, prefix="serve")
+    out = serve_tune.autotune_serve(TINY_TF, mesh, None,
+                                    mode="cache-only", **kw)
+    assert out.source == "heuristic" and out.trials == 0
+    assert out.tuned == serve_tune.ServeCandidate()
+    monkeypatch.setattr(
+        serve_tune, "probe_candidate",
+        lambda *a, **k: serve_tune.ServeProbeResult(100.0, 1.0))
+    out = serve_tune.autotune_serve(TINY_TF, mesh, None, mode="probe",
+                                    **kw)
+    assert out.source == "probe" and out.trials > 0
+    again = serve_tune.autotune_serve(TINY_TF, mesh, None,
+                                      mode="cache-only", **kw)
+    assert again.source == "cache" and again.tuned == out.tuned
 
 
 def test_autotune_serve_cache_hit_zero_trials(devices8, tmp_path,
@@ -579,7 +597,7 @@ def _serve_metrics(status="success", tps=50.0):
          "itl_p99_s": 0.001, "tokens_per_sec_per_chip": tps},
         {"kind": "serve", "requests": 8, "completed": 8,
          "generated_tokens": 64, "truncated": 0, "wall_s": 1.25,
-         "slots": 4, "decode_k": 8, "kv_layout": "st",
+         "slots": 4, "decode_k": 8, "kv_page_tokens": 64,
          "kv_cache_bytes": 1 << 20, "tokens_per_sec": tps * 4,
          "tokens_per_sec_per_chip": tps, "ttft_p50_s": 0.01,
          "ttft_p99_s": 0.02, "itl_p50_s": 0.001, "itl_p99_s": 0.002,
@@ -799,28 +817,77 @@ def test_probe_tokens_honest_at_oversized_decode_k(devices8):
     params = init_params(TINY_TF, mesh, seed=0)
     res = serve_tune.probe_candidate(
         TINY_TF, mesh, params,
-        serve_tune.ServeCandidate(decode_k=16, layout="st"),
+        serve_tune.ServeCandidate(decode_k=16),
         slots=2, max_seq=16, prompt_pad=4, n_dispatches=4, repeats=1)
     assert res.feasible, res.error
     # room for 16-4=12 decode tokens per slot, not 16
     assert res.tokens == 2 * 12, res
 
 
-def test_serve_sweep_all_infeasible_is_a_clean_error(monkeypatch):
-    """bench --serve-sweep with no feasible point dies with an honest
-    SystemExit naming the situation, not a bare max-of-empty
-    ValueError (probe failures are pruned points by contract)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_bench_under_test", os.path.join(
-            os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+# ------------------------------------------------------------------ #
+# one engine: the CLI's defaults, and backpressure against the        #
+# reference                                                           #
+# ------------------------------------------------------------------ #
 
-    def all_infeasible(*a, **kw):
-        return serve_tune.ServeProbeResult(0.0, float("inf"),
-                                           feasible=False, error="OOM")
+def _cli_summary(tmp_path, *flags):
+    from tpudist.serve import cli
+    return cli.run(cli.parse_args(
+        ["--requests", "3", "--max-new-tokens", "3", "--trace", "off",
+         "--save-dir", str(tmp_path), *flags]))
 
-    monkeypatch.setattr(serve_tune, "probe_candidate", all_infeasible)
-    with pytest.raises(SystemExit, match="infeasible"):
-        bench.run_serve_sweep("/dev/null")
+
+@pytest.mark.parametrize("max_seq", [64, 32])
+def test_cli_serves_pages_by_default(tmp_path, max_seq):
+    """No ``--kv-page-tokens``: the run is served from the pool, at the
+    page of every chip run on the ledger, held to ``--max-seq``."""
+    summary = _cli_summary(tmp_path, "--max-seq", str(max_seq))
+    assert summary["completed"] == 3
+    assert summary["kv_page_tokens"] == min(64, max_seq)
+    assert summary["kv_pages_total"] > 0
+    assert summary["kv_pages_used_peak"] >= 1
+    assert "kv_layout" not in summary
+
+
+@pytest.mark.parametrize("flags", [["--kv-page-tokens", "0"],
+                                   ["--kv-page-tokens", "-8"],
+                                   ["--kv-layout", "st"]],
+                         ids=["page0", "page-8", "kv-layout"])
+def test_cli_has_no_value_that_selects_an_engine(flags, capsys):
+    from tpudist.serve import cli
+    with pytest.raises(SystemExit) as e:
+        cli.parse_args(flags)
+    assert e.value.code == 2
+    assert flags[0] in capsys.readouterr().err
+
+
+def test_cli_speculates_without_a_page_flag(tmp_path):
+    summary = _cli_summary(tmp_path, "--speculate-k", "4")
+    assert summary["completed"] == 3
+    assert summary["speculate_k"] == 4
+    assert summary["verify_compiles"] == 1
+
+
+def test_backpressured_run_emits_the_reference_tokens(devices8):
+    """A pool too small for two prompts at once: the second request
+    WAITS for pages (``kv_backpressure``, nothing shed), and every
+    request still completes with the naive full-forward greedy tokens."""
+    from tpudist.obs import trace as trace_lib
+    engine, params = _tiny_engine(devices8, slots=2, max_seq=16,
+                                  prompt_pad=8, decode_k=2, page_tokens=4,
+                                  pages=3)
+    engine.warmup(params)
+    requests = sched.make_requests(4, prompt_pad=8, vocab_size=64,
+                                   max_new=4, rate=0.0, seed=4,
+                                   prompt_min=6)
+    tracer = trace_lib.configure(enabled=True)
+    try:
+        summary = sched.run_serve(engine, params, requests)
+    finally:
+        trace_lib.configure()
+    names = [ev["name"] for ev in tracer.events()]
+    assert "kv_backpressure" in names
+    assert summary["completed"] == 4 and summary["truncated"] == 0
+    assert summary["shed_total"] == 0 and summary["active_slots_peak"] == 1
+    assert {rid: r["tokens"] for rid, r in summary["results"].items()} \
+        == greedy_tokens(TINY_TF, params, requests)
+    assert engine.alloc.pages_used() == 0

@@ -1,6 +1,6 @@
 """The serve engine holds the weights at rest in its compute dtype.
 
-``ServeEngine._resident`` converts a params tree once, on the device, and
+``PagedServeEngine._resident`` converts a params tree once, on the device, and
 the programs then take weights for which ``scopes.cast`` is a no-op. What
 is held here:
 
@@ -27,7 +27,7 @@ from tpudist.config import ModelConfig, ParallelConfig
 from tpudist.obs import trace as trace_mod
 from tpudist.parallel import build_mesh
 from tpudist.serve import scheduler as sched
-from tpudist.serve.engine import PagedServeEngine, ServeEngine, init_params
+from tpudist.serve.engine import PagedServeEngine, init_params
 
 BF16 = jnp.dtype(jnp.bfloat16)
 
@@ -47,16 +47,15 @@ TINY_C2M = ModelConfig(
     expert_first=0, expert_top_k=4, n_shared_experts=2, sliding_window=6,
     rope_theta=50000.0, logit_scale=0.5)
 
-# name -> (model config, engine class, engine keywords, shared prefix)
+# name -> (model config, engine keywords, shared prefix)
 CASES = {
-    "paged-transformer": (TINY_TF, PagedServeEngine,
-                          dict(page_tokens=8, speculate_k=3), 8),
-    "paged-moe": (TINY_MOE, PagedServeEngine, dict(page_tokens=8), 0),
-    "paged-cohere2moe": (TINY_C2M, PagedServeEngine,
-                         dict(page_tokens=4, ring_margin=2), 0),
-    "dense-transformer": (TINY_TF, ServeEngine, {}, 0),
-    "paged-transformer-l9": (DEEP_TF, PagedServeEngine,
-                             dict(page_tokens=8, speculate_k=3), 0),
+    "paged-transformer": (TINY_TF, dict(page_tokens=8, speculate_k=3), 8),
+    "paged-moe": (TINY_MOE, dict(page_tokens=8), 0),
+    "paged-cohere2moe": (TINY_C2M, dict(page_tokens=4, ring_margin=2), 0),
+    # plain decode over one page a slot: no verify program, no prefix
+    "transformer-one-page": (TINY_TF, dict(page_tokens=32), 0),
+    "paged-transformer-l9": (DEEP_TF, dict(page_tokens=8, speculate_k=3),
+                             0),
 }
 SERVED = [c for c in CASES if not c.endswith("l9")]
 
@@ -77,24 +76,20 @@ def float32_params(cfg, mesh, seed=0):
 
 
 def engine_for(case, mesh, dtype=jnp.bfloat16, probe=False):
-    cfg, cls, kw, _ = CASES[case]
-    if probe:
-        cls = _probe(cls)
+    cfg, kw, _ = CASES[case]
+    cls = Probe if probe else PagedServeEngine
     return cls(cfg, mesh, slots=2, max_seq=32, prompt_pad=16, decode_k=4,
                dtype=dtype, **kw)
 
 
-def _probe(cls):
-    class Probe(cls):
-        """The engine, with every logit it samples from handed to the
-        host."""
+class Probe(PagedServeEngine):
+    """The engine, with every logit it samples from handed to the host."""
 
-        def _tied_logits(self, params, h):
-            logits = super()._tied_logits(params, h)
-            jax.debug.callback(lambda x: self.seen.append(np.asarray(x)),
-                               logits, ordered=True)
-            return logits
-    return Probe
+    def _tied_logits(self, params, h):
+        logits = super()._tied_logits(params, h)
+        jax.debug.callback(lambda x: self.seen.append(np.asarray(x)),
+                           logits, ordered=True)
+        return logits
 
 
 def serve(case, mesh, params):
@@ -102,7 +97,7 @@ def serve(case, mesh, params):
     dispatches; on the paged transformer ``register_prefix`` and
     ``verify`` too): the tokens of every request and every logit row the
     engine sampled from, in order."""
-    cfg, _, _, prefix_len = CASES[case]
+    cfg, _, prefix_len = CASES[case]
     eng = engine_for(case, mesh, probe=True)
     eng.seen = []
     eng.warmup(params)
@@ -285,13 +280,6 @@ def programs(eng, params):
     tokens = jnp.zeros((1, eng.prompt_pad), jnp.int32)
     one = jnp.int32(1)
     with jax.set_mesh(eng.mesh):
-        if not eng.paged:
-            return {
-                "prefill": jax.make_jaxpr(eng._prefill_body)(
-                    params, state, tokens, one, one, one),
-                "decode": jax.make_jaxpr(
-                    eng._decode_body, static_argnums=(2,))(
-                        params, state, eng.decode_k)}
         table = jnp.asarray(eng.alloc.table, jnp.int32)
         da = jnp.ones((eng.slots,), bool)
         out = {

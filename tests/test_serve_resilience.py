@@ -28,7 +28,7 @@ from tpudist.serve import drill as drill_mod
 from tpudist.serve import resilience as res_lib
 from tpudist.serve import scheduler as sched
 from tpudist.serve import slo
-from tpudist.serve.engine import ServeEngine, init_params
+from tpudist.serve.engine import PagedServeEngine, init_params
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -44,7 +44,7 @@ def _tiny_engine(devices8, **kw):
     kw.setdefault("max_seq", 16)
     kw.setdefault("prompt_pad", 4)
     kw.setdefault("decode_k", 4)
-    return ServeEngine(TINY_TF, mesh, **kw), params
+    return PagedServeEngine(TINY_TF, mesh, **kw), params
 
 
 class RecMetrics:
@@ -457,16 +457,15 @@ def test_engine_ladder_program_budget(devices8):
     for k in (4, 2, 1):
         state, _, _ = engine.decode(params, state, k)
     assert engine.compile_counts() == (1, 3)
+    mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
     with pytest.raises(ValueError, match="ladder"):
-        ServeEngine(TINY_TF, build_mesh(ParallelConfig(),
-                                        devices=devices8[:1]),
-                    slots=2, max_seq=16, prompt_pad=4, decode_k=4,
-                    adapt_ladder=(4, 4, 2))   # not strictly descending
+        PagedServeEngine(TINY_TF, mesh, slots=2, max_seq=16, prompt_pad=4,
+                         decode_k=4,
+                         adapt_ladder=(4, 4, 2))  # not strictly descending
     with pytest.raises(ValueError, match="ladder"):
-        ServeEngine(TINY_TF, build_mesh(ParallelConfig(),
-                                        devices=devices8[:1]),
-                    slots=2, max_seq=16, prompt_pad=4, decode_k=4,
-                    adapt_ladder=(8, 4))      # must start at decode_k
+        PagedServeEngine(TINY_TF, mesh, slots=2, max_seq=16, prompt_pad=4,
+                         decode_k=4,
+                         adapt_ladder=(8, 4))     # must start at decode_k
 
 
 # ------------------------------------------------- rules/report wiring
@@ -510,7 +509,7 @@ def test_report_cross_checks_serve_fail_against_alerts():
 def test_report_serving_section_carries_shed_partition():
     recs = [{"kind": "serve", "requests": 10, "completed": 6,
              "generated_tokens": 30, "wall_s": 1.0, "slots": 2,
-             "decode_k": 4, "kv_layout": "st", "ttft_p99_s": 0.01,
+             "decode_k": 4, "kv_page_tokens": 8, "ttft_p99_s": 0.01,
              "itl_p99_s": 0.001, "tokens_per_sec_per_chip": 30.0,
              "arrived": 10, "admitted": 6, "shed_at_admission": 2,
              "expired_in_queue": 1, "rejected": 1, "lost": 0,

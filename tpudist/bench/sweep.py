@@ -106,10 +106,8 @@ def collectives_artifact(records: List[dict]) -> dict:
 
 
 def write_collectives_artifact(records: List[dict], path: str) -> dict:
-    """The ONE writer of BENCH_COLLECTIVES.json — `bench.py
-    --collective-sweep` (CI/dev) and this module's ``--bench-out``
-    (pods, where bench.py is not shipped) both land here, so the two
-    artifacts cannot drift."""
+    """The ONE writer of BENCH_COLLECTIVES.json (this module's
+    ``--bench-out``; the launcher's sweep lane calls it)."""
     art = collectives_artifact(records)
     with open(path, "w") as f:
         json.dump(art, f, indent=1)
@@ -204,8 +202,8 @@ def main(argv=None) -> int:
                    help="also write the BENCH_COLLECTIVES.json artifact "
                         "here (the BASELINE.json harness shape: headline "
                         "metric + per-kind per-size rows with ICI/DCN "
-                        "fabric labels; bench.py --collective-sweep and "
-                        "the launcher share this path)")
+                        "fabric labels; the launcher's sweep lane "
+                        "writes it through this flag)")
     # strict: a mistyped flag must error, not silently run a full 1GB sweep
     args = p.parse_args(argv)
     records = run_sweep(tuple(args.kinds.split(",")), args.axis,

@@ -279,9 +279,8 @@ def verify_family(run_dir: str, result: Dict[str, Any]
 
 def bench_artifact(report: Dict[str, Any]) -> Dict[str, Any]:
     """BENCH_CHAOS.json on the shared BENCH_* harness shape: headline =
-    fault families ending green, detail = the full report. The ONE
-    shaper behind ``python -m tpudist.chaos``, ``bench.py
-    --chaos-drill`` and any future consumer."""
+    fault families ending green, detail = the full report. The shaper
+    behind ``python -m tpudist.chaos drill --bench-out``."""
     fams = report.get("families", {})
     return {
         "metric": "chaos_families_green",
@@ -295,10 +294,9 @@ def run_and_verify(run_dir: Optional[str] = None, *,
                    families=None) -> Dict[str, Any]:
     """The whole acceptance sequence in one call — drill the matrix,
     replay the invariants, persist ``chaos_report.json`` — shared by
-    the CLI, ``bench.py --chaos-drill`` and ``selfcheck check_chaos``
-    so the dir-resolution and orchestration cannot drift. ``run_dir``
-    defaults to ``$TPUDIST_CHAOS_DRILL_DIR`` (CI uploads it), else a
-    temp dir; the report carries the resolved path as ``run_dir``."""
+    the CLI and ``selfcheck check_chaos`` so the dir-resolution and
+    orchestration cannot drift. ``run_dir`` defaults to
+    ``$TPUDIST_CHAOS_DRILL_DIR`` (CI uploads it), else a temp dir; the report carries the resolved path as ``run_dir``."""
     import tempfile
 
     if run_dir is None:

@@ -223,7 +223,8 @@ class TrainConfig:
 
 # auto superstep cap: past ~32 steps per dispatch the per-dispatch
 # overhead is already amortised to noise and longer scans only delay
-# log/fence boundaries (bench.py --dispatch-sweep measures the curve)
+# log/fence boundaries (a CPU sweep of a toy preset said so; on the
+# chip the curve is not measured: ROADMAP C3)
 SUPERSTEP_CAP = 32
 
 

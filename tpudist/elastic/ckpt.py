@@ -29,8 +29,8 @@ builds on, with three properties orbax's opaque layout cannot give us:
 
 :class:`ShardedCheckpointer` mirrors ``checkpoint.Checkpointer``'s
 interface (``save(state, epoch=, step_in_epoch=)`` / ``wait`` /
-``close`` / ``last_enqueue_ms`` / ``drain_ms``) so the train loop and
-``bench.py --ckpt-sweep`` treat the modes interchangeably. ``save``
+``close`` / ``last_enqueue_ms`` / ``drain_ms``) so the train loop
+treats the modes interchangeably. ``save``
 returns after the device→host snapshot (donation-safe: the next step
 may reuse the donated buffers); the file writes and the commit run on
 a background thread unless ``use_async=False``.

@@ -720,8 +720,8 @@ def make_superstep(cfg: TrainConfig, mesh: Mesh, k: int) -> Callable:
     and the mid-epoch-resume realignment slab (``lo > 0``) included —
     where the old variable-length tail forced a second compile per
     epoch. ``lo``/``hi`` are traced scalars, so their values never
-    recompile; ``superstep.traces`` counts actual retraces (tests and
-    ``bench.py --staging-sweep`` pin it to 1).
+    recompile; ``superstep.traces`` counts actual retraces (tests pin
+    it to 1).
 
     Donation contract (audited for the staging pipeline): the incoming
     ``state`` and ``total`` are donated — the update writes in place, so

@@ -54,10 +54,9 @@ from tpudist.scopes import cast, scope
 
 Params = Dict
 
-# serve.engine reads these: leaves are made in place by ``init`` (never a
-# float32 whole), and only the paged engine knows the two kinds of cache
+# serve.engine reads this: leaves are made in place by ``init`` (never a
+# float32 whole)
 LEAFWISE_INIT = True
-PAGED_ONLY = True
 
 # rows of one expert's block in the grouped product: enough rows to keep a
 # product of a long prompt on the MXU's side of its roofline; a decode

@@ -180,7 +180,7 @@ def _moe_layer(x, lp, cfg: ModelConfig, cos, sin, attn_impl):
 
 def _moe_ffn_sublayer(x, lp, cfg: ModelConfig):
     """Pre-norm expert FFN + residual — the MoE FFN half in the shape
-    ``transformer._cached_hidden_states`` expects. The router aux loss
+    the transformer's serving forwards expect. The router aux loss
     is a TRAINING regulariser and is dropped here: serving has no
     objective to add it to. Expert dispatch runs fine at decode shapes
     (tokens = slots × 1): ``group_size`` degenerates to one group and
@@ -191,16 +191,14 @@ def _moe_ffn_sublayer(x, lp, cfg: ModelConfig):
     return x + ffn
 
 
-def _cached_hidden_states(params: Params, tokens: jax.Array,
-                          cfg: ModelConfig, *, dtype, kv_cache,
-                          cur_index):
-    """Serving path: the transformer's cache contract verbatim
-    (prefill/decode split on ``cur_index``, one implementation) with
-    only the FFN half swapped for the experts."""
-    return T._cached_hidden_states(params, tokens, cfg, dtype=dtype,
-                                   kv_cache=kv_cache,
-                                   cur_index=cur_index,
-                                   ffn=_moe_ffn_sublayer)
+def prefill_kv_hidden_states(params: Params, tokens: jax.Array,
+                             cfg: ModelConfig, *, dtype, kv_cache):
+    """Serving prefill: the transformer's verbatim
+    (:func:`transformer.prefill_kv_hidden_states`) with only the FFN
+    half swapped for the experts; the aux loss is dropped."""
+    return T.prefill_kv_hidden_states(params, tokens, cfg, dtype=dtype,
+                                      kv_cache=kv_cache,
+                                      ffn=_moe_ffn_sublayer)
 
 
 def paged_hidden_states(params: Params, tokens: jax.Array,
@@ -221,18 +219,10 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
 def hidden_states(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
                   dtype=jnp.bfloat16, attn_impl=T._attention,
                   rope_offset=0, rope_positions=None,
-                  remat: bool = False, kv_cache=None,
-                  cur_index=None) -> Tuple[jax.Array, jax.Array]:
+                  remat: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Backbone forward → (final-norm hidden states, mean aux loss).
     ``rope_offset``/``rope_positions``: per-shard absolute positions for
-    context-parallel callers (same contract as the dense transformer).
-    ``kv_cache``/``cur_index`` select the serving path — the return
-    becomes ``(h, kv_cache')`` and the aux loss is dropped
-    (:func:`_cached_hidden_states`)."""
-    if kv_cache is not None:
-        return _cached_hidden_states(params, tokens, cfg, dtype=dtype,
-                                     kv_cache=kv_cache,
-                                     cur_index=cur_index)
+    context-parallel callers (same contract as the dense transformer)."""
     s = tokens.shape[1]
     hd = cfg.d_model // cfg.n_heads
     cos, sin = T.precompute_rope(s, hd, cfg.rope_theta,

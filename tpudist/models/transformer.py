@@ -312,10 +312,12 @@ def window_rope(x: jax.Array, positions: jax.Array,
                 theta: float) -> jax.Array:
     """Rotate a WINDOW of new tokens per slot at their own absolute
     positions. x: (batch, window, heads, head_dim); positions: (batch,
-    window) int32 — the windowed generalisation of :func:`decode_rope`
-    (window 1 recovers it bit-for-bit), used by the paged decode/verify
-    programs where a speculative window appends several tokens per slot
-    per dispatch. The frequency derivation stays in
+    window) int32 — each slot in a continuously-batched decode step
+    sits at its OWN sequence position, so the table-based
+    :func:`apply_rope` (one shared position per column) does not fit.
+    Used by the paged decode/verify programs, where a speculative
+    window appends several tokens per slot per dispatch (window 1 is
+    the plain decode step). The frequency derivation stays in
     :func:`precompute_rope` (``positions=``) so there is ONE site for
     any future theta/interpolation change. Same pair convention as
     apply_rope: channel i rotates with channel i + head_dim/2."""
@@ -329,120 +331,38 @@ def window_rope(x: jax.Array, positions: jax.Array,
                            axis=-1)
 
 
-def decode_rope(x: jax.Array, positions: jax.Array,
-                theta: float) -> jax.Array:
-    """Rotate one new token per slot at its absolute position.
-
-    x: (batch, 1, heads, head_dim); positions: (batch,) int32 — each
-    slot in a continuously-batched decode step sits at its OWN sequence
-    position, so the table-based :func:`apply_rope` (one shared position
-    per column) does not fit. The window-1 case of
-    :func:`window_rope` (same flattened positions feed the same
-    precompute_rope call, so the delegation is bitwise)."""
-    return window_rope(x, positions[:, None], theta)
-
-
-def _cached_attention(q, k_new, v_new, cache_k, cache_v, pos):
-    """One-token incremental attention against a per-slot KV cache.
-
-    q/k_new/v_new: (batch, 1, heads|kv, head_dim), ALREADY rotated at
-    ``pos``; cache_k/cache_v: (batch, max_seq, kv, head_dim) holding the
-    rotated keys/values of positions ``[0, pos)``; pos: (batch,) int32
-    per-slot write positions. The new k/v land at ``pos`` and attention
-    covers keys ``[0, pos]`` inclusive — positions beyond each slot's
-    own length are masked, so stale cache rows (a freed slot's tail, a
-    padded prompt's tail) can never leak into another sequence. Same
-    f32-softmax discipline as :func:`_attention`, which is what keeps
-    decode logits ULP-close to the full forward."""
-    from tpudist.ops.gqa import expand_gqa
-    b, t = cache_k.shape[0], cache_k.shape[1]
-    slot = jnp.arange(b)
-    with scope("attn/kv_write"):
-        cache_k = cache_k.at[slot, pos].set(
-            k_new[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[slot, pos].set(
-            v_new[:, 0].astype(cache_v.dtype))
-    with scope("attn/kv_gather"):
-        k, v = expand_gqa(q, cache_k, cache_v)
-    with scope("attn/core"):
-        hd = q.shape[-1]
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
-            jnp.asarray(hd, q.dtype))
-        mask = jnp.arange(t)[None, :] <= pos[:, None]            # (b, t)
-        scores = jnp.where(mask[:, None, None, :], scores,
-                           jnp.asarray(-1e30, scores.dtype))
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    return o, cache_k, cache_v
-
-
-def _attn_sublayer_cached(x, lp, cfg: ModelConfig, pos, cache_k, cache_v):
-    """The incremental (decode) twin of :func:`_attn_sublayer`: one new
-    token per slot, q/k/v projected and rotated at the slot's own
-    position, attention against the layer's KV cache. Returns
-    ``(out, cache_k', cache_v')``. Shared with the MoE model, whose
-    decode layers differ only in the FFN half."""
-    b, s, d = x.shape           # s == 1 (one appended token per slot)
-    h, kv = cfg.n_heads, cfg.n_kv_heads
-    hd = d // h
-    y = rmsnorm(x, lp["attn_norm"])
-    q, k, v = _qkv(y, lp, b, s, h, kv, hd)
-    with scope("attn/rope"):
-        q = decode_rope(q, pos, cfg.rope_theta)
-        k = decode_rope(k, pos, cfg.rope_theta)
-    o, cache_k, cache_v = _cached_attention(q, k, v, cache_k, cache_v,
-                                            pos)
-    return _attn_out(x, o.reshape(b, s, h * hd), lp), cache_k, cache_v
-
-
-def _cached_hidden_states(params: Params, tokens: jax.Array,
-                          cfg: ModelConfig, *, dtype, kv_cache,
-                          cur_index, ffn=_ffn_sublayer):
-    """Incremental forward against a per-sequence KV cache.
+def prefill_kv_hidden_states(params: Params, tokens: jax.Array,
+                             cfg: ModelConfig, *, dtype, kv_cache,
+                             ffn=_ffn_sublayer):
+    """The serving PREFILL forward: full causal forward over ``tokens``
+    (batch, prompt_pad) that also hands back every layer's rotated k/v.
 
     ``kv_cache`` is ``{"k", "v"}`` of shape (n_layers, batch, max_seq,
-    n_kv_heads, head_dim) — the canonical layout (tpudist.serve.kvcache
-    owns any alternative storage layouts and transposes around this).
-
-    * ``cur_index=None`` → PREFILL: full causal forward over ``tokens``
-      (batch, prompt_pad); each layer's rotated k/v are written into
-      cache positions ``[0, prompt_pad)``. Positions past a prompt's
-      true length hold pad-token junk, which the decode mask (keys
-      ``<= pos``) never reads.
-    * ``cur_index`` (batch,) int32 → DECODE: ``tokens`` (batch, 1), one
-      token appended per slot at its own position.
+    n_kv_heads, head_dim); each layer's k/v are written into its
+    positions ``[0, prompt_pad)``. Positions past a prompt's true length
+    hold pad-token junk, which the decode mask (keys ``<= pos``) never
+    reads. Decode is :func:`paged_hidden_states`.
 
     ``ffn(x, lp, cfg)`` is the per-layer FFN half (residual included) —
-    the ONE thing the MoE model swaps; the whole cache contract lives
-    here once. Returns ``(h, kv_cache')`` with ``h`` final-normed."""
+    the ONE thing the MoE model swaps. Returns ``(h, kv_cache')`` with
+    ``h`` final-normed."""
     ck, cv = kv_cache["k"], kv_cache["v"]
     x = embed_tokens(params, tokens, dtype)
     unroll = cfg.n_layers <= 8
-    if cur_index is None:
-        s = tokens.shape[1]
-        hd = cfg.d_model // cfg.n_heads
-        cos, sin = precompute_rope(s, hd, cfg.rope_theta)
+    s = tokens.shape[1]
+    hd = cfg.d_model // cfg.n_heads
+    cos, sin = precompute_rope(s, hd, cfg.rope_theta)
 
-        def body(x, lp):
-            x, k, v = _attn_sublayer(x, lp, cfg, cos, sin, _attention,
-                                     return_kv=True)
-            return ffn(x, lp, cfg), (k, v)
+    def body(x, lp):
+        x, k, v = _attn_sublayer(x, lp, cfg, cos, sin, _attention,
+                                 return_kv=True)
+        return ffn(x, lp, cfg), (k, v)
 
-        x, (ks, vs) = lax.scan(body, x, params["layers"], unroll=unroll)
-        # ks: (L, b, s, kv, hd) — seed cache columns [0, s)
-        with scope("attn/kv_write"):
-            ck = ck.at[:, :, :s].set(ks.astype(ck.dtype))
-            cv = cv.at[:, :, :s].set(vs.astype(cv.dtype))
-    else:
-        def body(x, xs):
-            lp, ck_l, cv_l = xs
-            x, ck_l, cv_l = _attn_sublayer_cached(x, lp, cfg, cur_index,
-                                                  ck_l, cv_l)
-            return ffn(x, lp, cfg), (ck_l, cv_l)
-
-        x, (ck, cv) = lax.scan(body, x, (params["layers"], ck, cv),
-                               unroll=unroll)
+    x, (ks, vs) = lax.scan(body, x, params["layers"], unroll=unroll)
+    # ks: (L, b, s, kv, hd) — seed cache columns [0, s)
+    with scope("attn/kv_write"):
+        ck = ck.at[:, :, :s].set(ks.astype(ck.dtype))
+        cv = cv.at[:, :, :s].set(vs.astype(cv.dtype))
     return rmsnorm(x, params["final_norm"]), {"k": ck, "v": cv}
 
 
@@ -481,8 +401,7 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
     same one-hot, and attention runs over the layer's whole flattened
     page set with ``owned & (key_pos <= query_pos)`` masking — stale
     pages, other slots' pages and the trash page all mask to
-    exp(-inf) = 0 exactly, the same discipline that keeps the dense
-    arena's stale rows unreadable. Write-then-attend with the position
+    exp(-inf) = 0 exactly. Write-then-attend with the position
     mask also gives intra-window causality for free: a window query at
     position p never sees the window's own later writes (their
     positions exceed p). Same f32-softmax discipline as
@@ -536,9 +455,9 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
 def _attn_sublayer_paged(x, lp, cfg: ModelConfig, positions, write_ok,
                          pool_k, pool_v, layer, page_table,
                          page_tokens: int):
-    """The paged twin of :func:`_attn_sublayer_cached`: a WINDOW of new
-    tokens per slot, q/k/v projected and rotated at each token's own
-    position, attention against layer ``layer`` of the whole paged pool.
+    """The incremental (decode) twin of :func:`_attn_sublayer`: a WINDOW
+    of new tokens per slot, q/k/v projected and rotated at each token's
+    own position, attention against layer ``layer`` of the whole paged pool.
     Returns ``(out, pool_k', pool_v')``. Shared with the MoE model,
     whose layers differ only in the FFN half."""
     b, w, d = x.shape
@@ -560,7 +479,8 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
                         page_table, positions, write_ok,
                         page_tokens: int, ffn=_ffn_sublayer):
     """Windowed incremental forward against the PAGED KV pool — the
-    paged twin of :func:`_cached_hidden_states`'s decode branch.
+    decode half of serving (:func:`prefill_kv_hidden_states` is the
+    prefill).
 
     tokens/positions/write_ok: (slots, window); pool_k/pool_v:
     (n_layers, kv, pages+1, page_tokens, head_dim); page_table:
@@ -594,21 +514,13 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
 def hidden_states(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
                   dtype=jnp.bfloat16, attn_impl=_attention,
                   rope_offset=0, rope_positions=None,
-                  remat: bool = False, kv_cache=None,
-                  cur_index=None) -> jax.Array:
+                  remat: bool = False) -> jax.Array:
     """Backbone forward: tokens (batch, seq) -> final-norm hidden states
     (batch, seq, d_model) in ``dtype``. ``remat`` checkpoints each layer
     (recompute activations in backward — HBM for FLOPs, the standard TPU
-    trade when memory, not compute, limits batch size).
-
-    ``kv_cache``/``cur_index`` select the serving path
-    (:func:`_cached_hidden_states`): prefill seeds the cache, decode
-    appends one token per slot — return type becomes ``(h, kv_cache')``.
-    """
-    if kv_cache is not None:
-        return _cached_hidden_states(params, tokens, cfg, dtype=dtype,
-                                     kv_cache=kv_cache,
-                                     cur_index=cur_index)
+    trade when memory, not compute, limits batch size). The serving
+    forwards are :func:`prefill_kv_hidden_states` and
+    :func:`paged_hidden_states`."""
     s = tokens.shape[1]
     hd = cfg.d_model // cfg.n_heads
     cos, sin = precompute_rope(s, hd, cfg.rope_theta, offset=rope_offset,
@@ -636,20 +548,13 @@ def tied_logits(params: Params, h: jax.Array, dtype) -> jax.Array:
 def apply(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
           dtype=jnp.bfloat16, attn_impl=_attention,
           rope_offset=0, rope_positions=None,
-          remat: bool = False, kv_cache=None, cur_index=None) -> jax.Array:
+          remat: bool = False) -> jax.Array:
     """Forward: tokens (batch, seq) int32 -> logits (batch, seq, vocab) f32.
 
     ``attn_impl`` lets context-parallel callers substitute ring attention;
     ``rope_offset`` / ``rope_positions`` give each context shard its
-    absolute positions. With ``kv_cache`` the serving path runs instead
-    and the return is ``(logits, kv_cache')`` (see
-    :func:`_cached_hidden_states`).
+    absolute positions.
     """
-    if kv_cache is not None:
-        x, kv_cache = hidden_states(params, tokens, cfg, dtype=dtype,
-                                    kv_cache=kv_cache,
-                                    cur_index=cur_index)
-        return tied_logits(params, x, dtype), kv_cache
     x = hidden_states(params, tokens, cfg, dtype=dtype, attn_impl=attn_impl,
                       rope_offset=rope_offset, rope_positions=rope_positions,
                       remat=remat)
@@ -782,7 +687,8 @@ def pick_lm_head(n_tokens_per_device: int, vocab: int, d_model: int,
     whole-logits path is FLOP-optimal (3 head matmuls; fused/chunked pay a
     4th for the backward's logits recompute) and measured fastest — v5e
     matrix: plain 80.1% MFU vs chunked-c4 73.9% at batch 56/seq 512, and
-    still ahead at seq 2048-8192 (BENCH_MATRIX.json). Past the memory
+    still ahead at seq 2048-8192 (round-5 chip matrix, README perf
+    history). Past the memory
     cliff the plain path first forces XLA into rematerialisation (measured
     31 ms/step at batch 56 already) and then OOMs (batch 96); the fused
     pallas kernel — logits never in HBM at all, strictly less traffic

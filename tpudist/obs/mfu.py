@@ -1,8 +1,9 @@
 """MFU / roofline accounting from the compiled program itself.
 
-``bench.py`` has always reported an *analytic* MFU (FLOPs counted from
-the model formula). The training run can do better: the superstep is
-already compiled, and XLA's cost analysis on that exact executable
+An *analytic* MFU counts FLOPs from the model formula
+(``perfbench/lib/flops.py`` keeps that one, with the benchmark). The
+training run can do better: the superstep is already compiled, and
+XLA's cost analysis on that exact executable
 (``Compiled.cost_analysis()``) reports the FLOPs and bytes the program
 actually executes — remat recompute, masked padding steps, fused
 epilogues and all. Divided by the ``StepTimer``'s steady-state wall
@@ -13,9 +14,9 @@ The per-chip convention: ``cost_analysis`` describes the per-device SPMD
 program, and ``StepTimer`` wall time is the same on every host, so
 ``flops / k / step_s`` IS the per-chip achieved rate.
 
-The bf16 peak table lives here (bench.py imports it — single source of
-truth); ``TPUDIST_PEAK_TFLOPS`` overrides it for chips the table does
-not know, and makes MFU testable on the CPU backend.
+The bf16 peak table lives here; ``TPUDIST_PEAK_TFLOPS`` overrides it
+for chips the table does not know, and makes MFU testable on the CPU
+backend.
 """
 
 from __future__ import annotations

@@ -475,9 +475,9 @@ def _find_exposed_frac(doc: Any) -> Optional[float]:
 
 
 def collectives_section(doc: Optional[Dict]) -> Optional[Dict[str, Any]]:
-    """Fold BENCH_COLLECTIVES.json (bench.py --collective-sweep) into
-    the report: per collective kind, the best-bucket bus bandwidth and
-    % of ring peak. Purely informational — the sweep gate already ran
+    """Fold BENCH_COLLECTIVES.json (``python -m tpudist.bench.sweep
+    --bench-out``) into the report: per collective kind, the
+    best-bucket bus bandwidth and % of ring peak. Purely informational — the sweep gate already ran
     live; this puts the numbers next to the exposed-comm split they
     explain."""
     if not doc:
@@ -691,7 +691,6 @@ def serving_section(metrics: List[Dict[str, Any]],
         "generated_tokens": s.get("generated_tokens"),
         "truncated": s.get("truncated"), "wall_s": s.get("wall_s"),
         "slots": s.get("slots"), "decode_k": s.get("decode_k"),
-        "kv_layout": s.get("kv_layout"),
         "kv_cache_bytes": s.get("kv_cache_bytes"),
         "tokens_per_sec": s.get("tokens_per_sec"),
         "tokens_per_sec_per_chip": tps,
@@ -749,7 +748,8 @@ def serving_section(metrics: List[Dict[str, Any]],
                                    "decode_k", "reason")}
             for r in metrics if r.get("kind") == "serve_adapt"],
         "tuning": ({k: tunes[-1].get(k) for k in
-                    ("status", "source", "trials", "decode_k", "layout")}
+                    ("status", "source", "trials", "decode_k",
+                     "kv_page_tokens", "speculate_k")}
                    if tunes else None),
         "baseline_tokens_per_sec_per_chip": base_tps,
         "tokens_per_chip_ratio": ratio,
@@ -1220,7 +1220,8 @@ def to_markdown(report: Dict[str, Any]) -> str:
                   f"{sv['ttft_p99_s']}s; ITL p50/p99: "
                   f"{sv['itl_p50_s']}/{sv['itl_p99_s']}s",
                   f"- {sv['slots']} slot(s), decode_k "
-                  f"{sv['decode_k']}, kv layout {sv['kv_layout']}, "
+                  f"{sv['decode_k']}, kv pages of "
+                  f"{sv['kv_page_tokens']} token(s), "
                   f"queue depth max {sv['queue_depth_max']}, compiles "
                   f"{sv['prefill_compiles']} prefill / "
                   f"{sv['decode_compiles']} decode", ""]
@@ -1264,8 +1265,9 @@ def to_markdown(report: Dict[str, Any]) -> str:
             t = sv["tuning"]
             lines += [f"- serve tune: {t.get('status')} "
                       f"({t.get('source')}, {t.get('trials')} trial(s)) "
-                      f"→ decode_k {t.get('decode_k')}, layout "
-                      f"{t.get('layout')}", ""]
+                      f"→ decode_k {t.get('decode_k')}, kv page "
+                      f"tokens {t.get('kv_page_tokens')}, speculate_k "
+                      f"{t.get('speculate_k')}", ""]
     fl = r.get("flights") or {}
     if fl.get("enabled"):
         cn = fl.get("counts") or {}
@@ -1473,8 +1475,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "— a prior report also baselines the exposed-"
                         "comm fraction for the devtime delta")
     p.add_argument("--collectives", type=str, default=None,
-                   help="BENCH_COLLECTIVES.json (bench.py "
-                        "--collective-sweep) folded into the report's "
+                   help="BENCH_COLLECTIVES.json (python -m "
+                        "tpudist.bench.sweep --bench-out) folded into "
+                        "the report's "
                         "Collectives section (default: <run-dir>/"
                         "BENCH_COLLECTIVES.json when present)")
     p.add_argument("--alerts", type=str, default=None,

@@ -168,37 +168,14 @@ def plan_slabs(n_steps: int, k: int, step_bytes: int,
                     budget_bytes, streamed=True)
 
 
-KV_CACHE_LAYOUTS = ("st", "hs")
-
-
-def kv_cache_specs(layout: str = "st") -> P:
-    """``param_specs``-style PartitionSpec for the serving KV cache
-    (tpudist.serve): one spec serves both the K and V arrays.
-
-    Canonical ``"st"`` layout: ``(layers, slots, seq, kv_heads,
-    head_dim)`` — the slot (per-sequence) dim rides the batch axes like
-    every activation (:func:`batch_spec`), kv heads ride the tensor axis
-    (the Megatron head split the attention weights already use), and
-    the layer/seq/head_dim dims stay unsharded. ``"hs"`` stores heads
-    ahead of the sequence dim (``(layers, slots, kv_heads, seq,
-    head_dim)``) — an alternative physical layout the serve autotuner
-    probes. Compose with :func:`sanitize_specs` so odd slot/head counts
-    fall back to replicated instead of erroring."""
-    if layout == "st":
-        return P(None, ("data", "fsdp"), None, "tensor", None)
-    if layout == "hs":
-        return P(None, ("data", "fsdp"), "tensor", None, None)
-    raise ValueError(f"unknown kv-cache layout {layout!r}: "
-                     f"{' | '.join(KV_CACHE_LAYOUTS)}")
-
-
 def paged_kv_cache_specs() -> P:
-    """PartitionSpec for the PAGED serving KV pool ``(layers, kv_heads,
-    pages+1, page_tokens, head_dim)`` (tpudist.serve.kvcache): pages —
-    the pool's embarrassingly-parallel dim, playing the role slots play
-    in the dense arena — ride the batch axes, kv heads ride tensor (the
-    same Megatron head split the attention weights use), and the layer
-    / in-page-position / head_dim dims stay unsharded. Compose with
+    """``param_specs``-style PartitionSpec for the serving KV pool
+    ``(layers, kv_heads, pages+1, page_tokens, head_dim)``
+    (tpudist.serve.kvcache); one spec serves both the K and V arrays.
+    Pages — the pool's embarrassingly-parallel dim — ride the batch axes
+    like every activation (:func:`batch_spec`), kv heads ride tensor
+    (the same Megatron head split the attention weights use), and the
+    layer / in-page-position / head_dim dims stay unsharded. Compose with
     :func:`sanitize_specs` so a pool size the batch axes don't divide
     falls back to replicated instead of erroring (the +1 trash page
     makes odd pool sizes the COMMON case, not the exception)."""

@@ -3,12 +3,13 @@
 The fourth subsystem beside ``elastic/``, ``tune/`` and ``obs/``: the
 "millions of users" half of the north star. A prefill/decode-split
 engine over the training models (``models.transformer`` / ``models.moe``
-grow a cache-aware incremental path — serve does not fork the model),
-with
+grow a prefill and a paged incremental forward — serve does not fork
+the model), with
 
-* an incremental KV cache sharded on the existing mesh machinery
-  (per-sequence slots, GQA-compact head layout,
-  ``parallel.sharding.kv_cache_specs``) — :mod:`tpudist.serve.kvcache`;
+* a paged KV pool sharded on the existing mesh machinery (pages mapped
+  to slots by a host allocator, GQA-compact head layout,
+  ``parallel.sharding.paged_kv_cache_specs``) —
+  :mod:`tpudist.serve.kvcache`;
 * exactly TWO compiled programs per run — one prefill, one ``lax.scan``
   decode superstep over the whole slot batch — :mod:`tpudist.serve.engine`;
 * a continuous-batching scheduler: Poisson arrivals, admission into
@@ -16,8 +17,9 @@ with
 * latency-SLO verdicts (p50/p99 TTFT, inter-token latency, tokens/s/chip)
   through the shared :mod:`tpudist.rules` table —
   :mod:`tpudist.serve.slo`;
-* a measured-probe autotuner for decode batch size and KV layout on the
-  PR-4 fingerprint-cache machinery — :mod:`tpudist.serve.tune`;
+* a measured-probe autotuner for the decode superstep, the page size
+  and the speculation window on the PR-4 fingerprint-cache machinery —
+  :mod:`tpudist.serve.tune`;
 * the resilience plane (PR 15): admission control with deadline-based
   load shedding and an exactly-checked arrival partition, a hysteretic
   pressure controller over a pre-compiled decode_k ladder, and honest

@@ -4,12 +4,12 @@ One command drives the whole serve stack end to end on whatever mesh
 the platform gives it (the scripted CPU mesh in CI, a pod slice under
 ``launch_tpu.sh MODE=serve``): build the model and its sharded KV
 cache, warm the compiled programs (one prefill + one decode per adapt
-rung), optionally let the serve autotuner pick ``decode_k``/layout by
-measured probe, run the continuous-batching loop — with admission
-control, deadline shedding and graceful degradation when the
-resilience knobs are on (:mod:`tpudist.serve.resilience`) — over a
-seeded Poisson request stream, and grade the latency SLOs plus the
-shed gate. Under the launcher's requeue loop (``--requeue-attempt``),
+rung), optionally let the serve autotuner pick ``decode_k``, the page
+size and the speculation window by measured probe, run the
+continuous-batching loop — with admission control, deadline shedding
+and graceful degradation when the resilience knobs are on
+(:mod:`tpudist.serve.resilience`) — over a seeded Poisson request
+stream, and grade the latency SLOs plus the shed gate. Under the launcher's requeue loop (``--requeue-attempt``),
 a restarted attempt replays the still-live requests from the seeded
 schedule and classifies the dead attempt's in-flight slots as lost.
 
@@ -20,7 +20,7 @@ records) under ``--save-dir``, the span trace — on by default, same
 ``trace.worker<i>.json`` plus the merged ``pod_trace.json`` with
 per-request flight timelines, per-slot tracks and a KV-pool occupancy
 counter track (verify offline with ``python -m tpudist.serve.flight``),
-an optional ``BENCH_SERVE.json`` (``--bench-out``),
+the run summary as one JSON document (``--bench-out``),
 a Prometheus exporter while the run lives (``--live-port``), and the
 machine-readable verdict file (``TPUDIST_VERDICT_PATH``) carrying the
 three-valued SLO verdict. Exit code: 0 unless an SLO gate FAILED — an
@@ -42,6 +42,7 @@ DEFAULT_SLOTS = 4
 DEFAULT_MAX_SEQ = 64
 DEFAULT_PROMPT_PAD = 16
 DEFAULT_DECODE_K = 8
+DEFAULT_KV_PAGE_TOKENS = 64    # the page of every chip run on the ledger
 
 
 def parse_args(argv: Optional[Sequence[str]] = None
@@ -55,8 +56,7 @@ def parse_args(argv: Optional[Sequence[str]] = None
                    help="cohere2moe: parallel block, window and NoPE-full "
                         "attention by layer, sigmoid-routed experts of "
                         "which --n-experts-held live here, averaged "
-                        "shared experts; weights at rest in bfloat16; "
-                        "paged engine only (--kv-page-tokens)")
+                        "shared experts; weights at rest in bfloat16")
     p.add_argument("--vocab-size", type=int, default=256)
     p.add_argument("--n-layers", type=int, default=2)
     p.add_argument("--d-model", type=int, default=64)
@@ -87,27 +87,24 @@ def parse_args(argv: Optional[Sequence[str]] = None
                    default=DEFAULT_DECODE_K, dest="decode_k",
                    help="decode superstep length (tokens per dispatch "
                         "per slot)")
-    p.add_argument("--kv-layout", choices=("st", "hs"), default="st",
-                   help="KV cache physical storage layout "
-                        "(tpudist.serve.kvcache)")
-    # ---- the paged plane (PagedServeEngine) ----
-    p.add_argument("--kv-page-tokens", type=int,
+    # ---- the KV pool (tpudist.serve.kvcache) ----
+    p.add_argument("--kv-page-tokens", type=_page_tokens,
                    default=_env_int("TPUDIST_SERVE_KV_PAGE_TOKENS")
-                   or 0,
-                   help="PAGED KV cache: fixed page length in "
-                        "positions; 0 keeps the dense per-slot arena "
+                   or DEFAULT_KV_PAGE_TOKENS,
+                   help="KV cache page length in positions, >= 1; held "
+                        "to at most --max-seq "
                         "($TPUDIST_SERVE_KV_PAGE_TOKENS)")
     p.add_argument("--kv-pages", type=int,
                    default=_env_int("TPUDIST_SERVE_KV_PAGES") or 0,
-                   help="paged pool size in pages (+1 trash page is "
-                        "added internally); 0 = full dense capacity "
+                   help="pool size in pages (+1 trash page is added "
+                        "internally); 0 = full capacity "
                         "slots*ceil(max_seq/page_tokens) "
                         "($TPUDIST_SERVE_KV_PAGES)")
     p.add_argument("--shared-prefix", type=int,
                    default=_env_int("TPUDIST_SERVE_SHARED_PREFIX")
                    or 0,
                    help="every request starts with this many shared "
-                        "system-prompt tokens; the paged engine stores "
+                        "system-prompt tokens; the engine stores "
                         "their full pages ONCE (refcounted, "
                         "copy-on-write fork of the partial tail) "
                         "($TPUDIST_SERVE_SHARED_PREFIX)")
@@ -115,8 +112,7 @@ def parse_args(argv: Optional[Sequence[str]] = None
                    default=_env_int("TPUDIST_SERVE_SPECULATE_K") or 0,
                    help="speculative decoding verify-window width: "
                         "last token + k-1 n-gram draft tokens scored "
-                        "in ONE batched target forward; 0 = off, "
-                        "needs --kv-page-tokens "
+                        "in ONE batched target forward; 0 = off "
                         "($TPUDIST_SERVE_SPECULATE_K)")
     p.add_argument("--requests", type=int, default=32,
                    help="synthetic request count")
@@ -174,7 +170,8 @@ def parse_args(argv: Optional[Sequence[str]] = None
     p.add_argument("--virtual-decode-ms", type=float, default=4.0)
     p.add_argument("--serve-tune", choices=("off", "probe", "cache-only"),
                    default=os.environ.get("TPUDIST_SERVE_TUNE", "off"),
-                   help="autotune decode_k/kv-layout by measured probe "
+                   help="autotune decode_k, the page size and the "
+                        "speculation window by measured probe "
                         "(tpudist.serve.tune; $TPUDIST_SERVE_TUNE)")
     p.add_argument("--tune-cache-dir", type=str, default=None,
                    help="serve tuner cache dir (default "
@@ -184,7 +181,8 @@ def parse_args(argv: Optional[Sequence[str]] = None
     p.add_argument("--save-dir", type=str, default="ckpt",
                    help="metrics.jsonl destination")
     p.add_argument("--bench-out", type=str, default=None,
-                   help="write the run summary as BENCH_SERVE.json here")
+                   help="write the run summary as one JSON document "
+                        "here")
     p.add_argument("--trace", choices=("on", "off"), default=None,
                    help="span tracing (request flight timelines + KV "
                         "occupancy counters); default on — same "
@@ -199,6 +197,14 @@ def parse_args(argv: Optional[Sequence[str]] = None
         help="serve Prometheus /metrics + /status.json on this port "
              "while the run lives ($TPUDIST_LIVE_PORT)")
     return p.parse_args(argv)
+
+
+def _page_tokens(raw: str) -> int:
+    n = int(raw)
+    if n < 1:
+        raise argparse.ArgumentTypeError(
+            f"a page holds at least one position, got {n}")
+    return n
 
 
 def _env_int(name: str) -> Optional[int]:
@@ -274,8 +280,7 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     from tpudist.serve import flight as flight_lib
     from tpudist.serve import scheduler as sched
     from tpudist.serve import tune as serve_tune
-    from tpudist.serve.engine import (PagedServeEngine, ServeEngine,
-                                      init_params)
+    from tpudist.serve.engine import PagedServeEngine, init_params
 
     model_cfg = ModelConfig(
         name=args.model, vocab_size=args.vocab_size,
@@ -359,12 +364,9 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
 
     params = init_params(model_cfg, mesh, seed=args.seed)
 
-    if args.speculate_k and not args.kv_page_tokens:
-        raise SystemExit("tpudist: --speculate-k needs the paged KV "
-                         "cache (--kv-page-tokens > 0)")
     cand = serve_tune.ServeCandidate(
-        decode_k=args.decode_k, layout=args.kv_layout,
-        kv_page_tokens=max(args.kv_page_tokens, 0),
+        decode_k=args.decode_k,
+        kv_page_tokens=min(args.kv_page_tokens, args.max_seq),
         speculate_k=max(args.speculate_k, 0))
     if args.serve_tune != "off":
         cache_dir = (args.tune_cache_dir
@@ -379,52 +381,41 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
                 metrics=metrics)
         cand = out.tuned
         log0(f"tpudist: serve tune {out.status} ({out.source}): "
-             f"decode_k={cand.decode_k} layout={cand.layout} "
+             f"decode_k={cand.decode_k} "
              f"kv_page_tokens={cand.kv_page_tokens} "
              f"speculate_k={cand.speculate_k} "
              f"[{out.trials} trial(s)]")
 
     ladder = (res_lib.default_ladder(cand.decode_k)
               if resilience.adapt else None)
-    if cand.kv_page_tokens > 0:
-        engine = PagedServeEngine(
-            model_cfg, mesh, slots=args.slots, max_seq=args.max_seq,
-            prompt_pad=args.prompt_pad, decode_k=cand.decode_k,
-            page_tokens=cand.kv_page_tokens,
-            pages=max(args.kv_pages, 0),
-            speculate_k=max(cand.speculate_k, 0),
-            adapt_ladder=ladder)
-    else:
-        engine = ServeEngine(model_cfg, mesh, slots=args.slots,
-                             max_seq=args.max_seq,
-                             prompt_pad=args.prompt_pad,
-                             decode_k=cand.decode_k, layout=cand.layout,
-                             adapt_ladder=ladder)
+    engine = PagedServeEngine(
+        model_cfg, mesh, slots=args.slots, max_seq=args.max_seq,
+        prompt_pad=args.prompt_pad, decode_k=cand.decode_k,
+        page_tokens=cand.kv_page_tokens, pages=max(args.kv_pages, 0),
+        speculate_k=cand.speculate_k, adapt_ladder=ladder)
     with trace_lib.span("serve_warmup", cat="serve"):
         engine.warmup(params)
 
     # program memory (obs.memledger): warmup just compiled every pinned
     # program, so their memory_analysis is readable off the request
-    # clock. For the paged plane it also feeds the allocator's memory
-    # bound: admission maps only the pages device HBM can afford beside
-    # the params and the programs' MEASURED scratch (falling back to the
-    # 4x-params heuristic on backends without memory planning — the
-    # choice is logged, and a shrunk cap backpressures at admission
-    # instead of dying in RESOURCE_EXHAUSTED)
+    # clock. It also feeds the allocator's memory bound: admission maps
+    # only the pages device HBM can afford beside the params and the
+    # programs' MEASURED scratch (falling back to the 4x-params
+    # heuristic on backends without memory planning — the choice is
+    # logged, and a shrunk cap backpressures at admission instead of
+    # dying in RESOURCE_EXHAUSTED)
     from tpudist import engine as engine_lib
     from tpudist.obs import memledger as memledger_lib
     program_mem = engine.program_memory()
     params_bytes = engine_lib.state_bytes_per_device(params)
     hbm_bytes = int(engine_lib._device_hbm_bytes())
-    if getattr(engine, "paged", False):
-        temp, temp_complete = memledger_lib.program_temp_bytes(
-            program_mem)
-        cap = engine.alloc.set_memory_bound(
-            hbm_bytes=hbm_bytes, params_bytes=params_bytes,
-            program_temp_bytes=temp if temp_complete else None)
-        log0(f"tpudist: serve kv memory bound "
-             f"({engine.alloc.bound_source}): {cap}/{engine.spec.pages} "
-             f"pages mappable in {hbm_bytes / 2**20:.0f} MB HBM")
+    temp, temp_complete = memledger_lib.program_temp_bytes(program_mem)
+    cap = engine.alloc.set_memory_bound(
+        hbm_bytes=hbm_bytes, params_bytes=params_bytes,
+        program_temp_bytes=temp if temp_complete else None)
+    log0(f"tpudist: serve kv memory bound "
+         f"({engine.alloc.bound_source}): {cap}/{engine.spec.pages} "
+         f"pages mappable in {hbm_bytes / 2**20:.0f} MB HBM")
 
     prefix_len = max(args.shared_prefix, 0)
     shared_prefix = (sched.shared_prefix_tokens(
@@ -506,8 +497,8 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     metrics.flush()
 
     # the serve lane's HBM ledger (obs.memledger): params + KV pool
-    # (paged: pool pages incl. the trash page + page table — the
-    # PagedCacheSpec.bytes number the bench lane reports) + the pinned
+    # (pool pages incl. the trash page + page table + window rings —
+    # PagedCacheSpec.bytes) + the pinned
     # programs' scratch, partitioned exactly against device HBM and
     # persisted as <save-dir>/memledger.json for the forensics CLI and
     # the next run's feed-forward margin. Advisory: never fails serve.
@@ -542,11 +533,10 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
          f"[{summary['prefill_compiles']} prefill / "
          f"{summary['decode_compiles']} decode / "
          f"{summary['verify_compiles']} verify compile(s), "
-         f"kv cache {cache_bytes / 2**20:.2f} MB"
-         + (f", {summary['kv_pages_used_peak']}"
-            f"/{summary['kv_pages_total']} pages peak, "
-            f"spec accept {summary['spec_accept_rate']}"
-            if getattr(engine, "paged", False) else "") + "]")
+         f"kv cache {cache_bytes / 2**20:.2f} MB, "
+         f"{summary['kv_pages_used_peak']}"
+         f"/{summary['kv_pages_total']} pages peak, "
+         f"spec accept {summary['spec_accept_rate']}]")
 
     if args.bench_out:
         _write_bench(args.bench_out, args, summary)
@@ -578,8 +568,9 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
 
 def _write_bench(path: str, args: argparse.Namespace,
                  summary: Dict[str, Any]) -> None:
-    """BENCH_SERVE.json — same harness shape as the other BENCH_*
-    artifacts: one metric headline, per-gate detail, thresholds."""
+    """``--bench-out``: the run summary as one JSON document — one
+    metric headline, per-gate detail, thresholds, the device it ran
+    on."""
     import jax
     doc = {
         "metric": "serve_tokens_per_sec_per_chip",
@@ -588,7 +579,7 @@ def _write_bench(path: str, args: argparse.Namespace,
         "detail": {k: summary.get(k) for k in (
             "run_id", "model", "requests", "completed",
             "generated_tokens", "truncated", "wall_s", "dispatches",
-            "slots", "decode_k", "kv_layout", "kv_cache_bytes",
+            "slots", "decode_k", "kv_cache_bytes",
             "tokens_per_sec", "queue_depth_max", "queue_depth_mean",
             "ttft_p50_s", "ttft_p99_s", "itl_p50_s", "itl_p99_s",
             "e2e_p50_s", "e2e_p99_s", "prefill_compiles",

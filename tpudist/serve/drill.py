@@ -36,8 +36,8 @@ jax-free AND numpy-free by design (the launcher-host contract shared
 with policy/goodput/chaos.verify); only the subprocesses need jax.
 ``python -m tpudist.serve.drill drill|verify`` is the CLI;
 ``tpudist.selfcheck check_serve_resilience`` runs the whole matrix as
-an acceptance gate and ``bench.py --serve-chaos-drill`` shapes the
-report into BENCH_SERVE_RESILIENCE.json.
+an acceptance gate; ``drill --bench-out`` shapes the report into
+BENCH_SERVE_RESILIENCE.json.
 """
 
 from __future__ import annotations
@@ -571,8 +571,8 @@ def verify_matrix(run_dir: str,
 def bench_artifact(report: Dict[str, Any]) -> Dict[str, Any]:
     """BENCH_SERVE_RESILIENCE.json on the shared BENCH_* harness shape:
     headline = resilience scenarios ending green, detail = the full
-    report. The ONE shaper behind ``python -m tpudist.serve.drill``,
-    ``bench.py --serve-chaos-drill`` and the CI lane."""
+    report. The shaper behind ``python -m tpudist.serve.drill drill
+    --bench-out`` and the CI lane."""
     sc = report.get("scenarios", {})
     return {
         "metric": "serve_resilience_scenarios_green",
@@ -586,9 +586,8 @@ def bench_artifact(report: Dict[str, Any]) -> Dict[str, Any]:
 def run_and_verify(run_dir: Optional[str] = None, *,
                    scenarios=None) -> Dict[str, Any]:
     """The whole acceptance sequence in one call — drill the matrix,
-    replay the invariants, persist the report — shared by the CLI,
-    ``bench.py --serve-chaos-drill`` and ``selfcheck
-    check_serve_resilience``. ``run_dir`` defaults to
+    replay the invariants, persist the report — shared by the CLI and
+    ``selfcheck check_serve_resilience``. ``run_dir`` defaults to
     ``$TPUDIST_SERVE_DRILL_DIR`` (CI uploads it), else a temp dir."""
     import tempfile
 

@@ -1,4 +1,5 @@
-"""The prefill/decode-split serving engine: exactly TWO compiled programs.
+"""The prefill/decode-split serving engine: exactly TWO compiled programs
+(three with speculation on), over a paged KV pool.
 
 The pjit/TPUv4 discipline that keeps the training loop honest (one
 compiled program per run, traced scalars for everything that varies)
@@ -9,32 +10,34 @@ pins it (``prefill.traces`` / ``decode.traces``, asserted in tests and
 the CI lane):
 
 * **prefill** — one request into one slot: full causal forward over the
-  padded prompt (the model's cache-aware path — ``hidden_states(...,
-  kv_cache=)`` — seeds the slot's KV columns), first token by greedy
-  argmax at the prompt's true last position. Slot index, prompt length
-  and the generation budget are traced scalars; the prompt is padded to
-  a fixed ``prompt_pad`` so every admission reuses the one program.
+  padded prompt (the model's ``prefill_kv_hidden_states`` hands back
+  every layer's rotated K/V, copied into the slot's pages a page at a
+  time), first token by greedy argmax at the prompt's true last
+  position. Slot index, prompt length, the generation budget and the
+  slot's page-table row are traced; the prompt is padded to a fixed
+  ``prompt_pad`` so every admission reuses the one program.
 * **decode** — a ``lax.scan`` superstep of ``decode_k`` steps over the
   WHOLE slot batch. Per-slot active masks (``jnp.where`` on every state
   update) keep finished/empty slots frozen, and a ``lax.cond`` skips an
   iteration outright when NO slot is active (mid-scan completion of the
   last request — the same masking discipline that kept PR 2's padded
   superstep bitwise) — so one compiled program serves every batch
-  occupancy from full to empty.
+  occupancy from full to empty. Each step is the model's
+  ``paged_hidden_states`` over the pool, which rides the layer loop as
+  its carry and is written in place.
 
 With graceful degradation on (``adapt_ladder``), the contract
 generalises to one decode program PER LADDER RUNG, all compiled at
 warmup: a pressure downshift switches programs, it never traces one.
 
-
 Greedy decoding is a pure function of (params, state), so runs are
-bitwise reproducible; decode-with-cache logits are pinned ULP-close to
+bitwise reproducible; decode-over-pages logits are pinned ULP-close to
 the full forward (tests/test_serve.py).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,16 +54,26 @@ from tpudist.serve import kvcache
 from tpudist.utils import compat
 
 
-class ServeState(NamedTuple):
+class PagedServeState(NamedTuple):
     """Device-resident serving state — the scan carry of the decode
-    superstep and the donation target of both programs."""
+    superstep and the donation target of every program. K/V live in one
+    shared pool of fixed-size pages (+1 trash page); the slot→page
+    mapping is HOST state (``PageAllocator.table``), passed into every
+    dispatch as a small traced int32 array."""
 
-    cache_k: jax.Array       # (L, slots, ...) in the storage layout
-    cache_v: jax.Array
+    pool_k: jax.Array        # (L, kv, pages+1, page_tokens, head_dim)
+    pool_v: jax.Array
     lengths: jax.Array       # (slots,) int32: tokens in cache per slot
     last_token: jax.Array    # (slots,) int32: newest token, not yet cached
     active: jax.Array        # (slots,) bool: slot holds a live sequence
     remaining: jax.Array     # (slots,) int32: generation budget left
+    # a model with window layers only (else empty / None): one ring per
+    # window layer, (kv, slots, ring_tokens, head_dim) each, and what the
+    # LAST program run counted: [token steps run, the model's own counts]
+    ring_k: tuple = ()
+    ring_v: tuple = ()
+    stats: Optional[jax.Array] = None
+
 
 
 def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
@@ -69,7 +82,7 @@ def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
     the optimizer state serving has no use for. The dtype is the
     model's own (``model.init``'s float32, or what a leafwise init makes
     in place): the ENGINE owns the dtype at rest and converts a tree it
-    is handed once (``ServeEngine._resident``)."""
+    is handed once (``PagedServeEngine._resident``)."""
     model = get_model(model_cfg.name)
     if getattr(model, "LEAFWISE_INIT", False):
         # a model whose float32 whole does not fit the chip that serves
@@ -83,12 +96,23 @@ def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
     return jax.device_put(params, shd.named(mesh, pspecs))
 
 
-class ServeEngine:
-    """Builds and owns the two compiled programs plus the state layout.
+class PagedServeEngine:
+    """The serving engine: paged KV pool, shared prefixes, speculation.
+
+    Builds and owns the compiled programs plus the state layout — ONE
+    prefill program, one decode program per ladder rung and, when
+    speculation is on, one more pinned program: the VERIFY forward, a
+    single batched target forward over a ``speculate_k``-token window
+    per slot that scores a whole host-proposed draft at once. Page
+    table and per-dispatch active mask ride as small traced arrays
+    (fixed shapes → no retrace); admission, eviction, page exhaustion
+    and drafting are pure host decisions between dispatches.
 
     ``prompt_pad`` is the static prompt width every admission pads to;
     ``decode_k`` the superstep length (tokens per dispatch per slot);
-    ``layout`` the KV storage layout (:mod:`tpudist.serve.kvcache`).
+    ``page_tokens`` / ``pages`` size the KV pool
+    (:mod:`tpudist.serve.kvcache`; ``pages=0`` is full capacity, a pool
+    no admission ever waits for).
 
     ``adapt_ladder`` is the graceful-degradation rung set
     (:func:`tpudist.serve.resilience.default_ladder`): ONE decode
@@ -97,6 +121,16 @@ class ServeEngine:
     program — the latency SLO never pays a recompile for degrading.
     The default ladder is ``(decode_k,)``, which keeps the original
     two-program contract bit-for-bit.
+
+    ``speculate_k`` is the verify WINDOW width: the window carries the
+    slot's pending ``last_token`` plus ``speculate_k - 1`` draft tokens,
+    so ``speculate_k >= 2`` turns speculation on (a window of 1 is
+    plain decode) and ``0`` turns it off. Greedy token output is
+    bitwise-identical to non-speculative greedy decode by construction:
+    every emitted token is the argmax after a verified-correct token,
+    and rejected drafts' junk KV sits at positions beyond the new
+    length, where write-then-attend overwrites it before any query can
+    attend it.
 
     The weights rest on the device in ``dtype``: every public method
     that takes ``params`` hands the programs ``_resident(params)``, the
@@ -108,13 +142,12 @@ class ServeEngine:
     nothing.
     """
 
-    paged = False          # the scheduler branches on this, not on type
-    speculate_k = 0        # speculation is a paged-engine feature
-
     def __init__(self, model_cfg: ModelConfig, mesh, *, slots: int,
                  max_seq: int, prompt_pad: int, decode_k: int = 8,
-                 layout: str = "st", dtype=jnp.float32,
-                 adapt_ladder: Optional[Sequence[int]] = None):
+                 page_tokens: int = 8, pages: int = 0,
+                 speculate_k: int = 0, dtype=jnp.float32,
+                 adapt_ladder: Optional[Sequence[int]] = None,
+                 ring_margin: int = 128):
         if slots < 1:
             raise ValueError(f"--slots must be >= 1, got {slots}")
         if decode_k < 1:
@@ -124,12 +157,12 @@ class ServeEngine:
             raise ValueError(
                 f"prompt_pad {prompt_pad} must be in (0, max_seq "
                 f"{max_seq}]")
+        if speculate_k == 1 or speculate_k < 0:
+            raise ValueError(
+                f"--speculate-k must be 0 (off) or >= 2 (window of "
+                f"last_token + drafts), got {speculate_k}")
         self.model_cfg = model_cfg
         self.model = get_model(model_cfg.name)
-        if getattr(self.model, "PAGED_ONLY", False) and not self.paged:
-            raise ValueError(
-                f"model {model_cfg.name!r} keeps two kinds of KV state and "
-                f"is served by the paged engine only (--kv-page-tokens)")
         self.mesh = mesh
         self.slots, self.max_seq = int(slots), int(max_seq)
         self.prompt_pad, self.decode_k = int(prompt_pad), int(decode_k)
@@ -144,12 +177,24 @@ class ServeEngine:
                 f"adapt_ladder {ladder} must be strictly descending "
                 f"positive superstep lengths")
         self.ladder = ladder
-        self.layout, self.dtype = layout, dtype
-        self.spec = kvcache.CacheSpec.from_model(
-            model_cfg, slots=slots, max_seq=max_seq, dtype=dtype,
-            layout=layout)
+        self.dtype = dtype
+        self.speculate_k = int(speculate_k)
+        self.spec = kvcache.PagedCacheSpec.from_model(
+            model_cfg, slots=slots, max_seq=max_seq,
+            page_tokens=page_tokens, pages=pages, dtype=dtype,
+            ring_margin=ring_margin)
+        # two kinds of cache state: the programs of such a model are
+        # bodies of their own below, the others' are untouched
+        self.windowed = self.spec.window_layers > 0
+        if self.windowed and self.speculate_k:
+            raise ValueError(
+                "--speculate-k over a model with window layers is not "
+                "built: a rejected draft would have to be unwound from "
+                "the ring")
+        self.alloc = kvcache.PageAllocator(self.spec)
         self.prefill_traces: list = []
         self.decode_traces: list = []
+        self.verify_traces: list = []
         # the last params tree handed in, and what the programs get for it
         self._resident_of = self._resident_tree = None
         # per-program lowering skeletons, captured at each program's
@@ -157,12 +202,14 @@ class ServeEngine:
         # memory_analysis reads these off the request clock)
         self._programs: dict = {}
         self._prefill = OnMesh(
-            jax.jit(self._prefill_body, donate_argnums=(1,)), mesh)
+            jax.jit(self._paged_prefill_body, donate_argnums=(1,)), mesh)
         # k is STATIC (it is the lax.scan length): one compiled decode
         # program per ladder rung, all traced at warmup
         self._decode = OnMesh(
-            jax.jit(self._decode_body, static_argnums=(2,),
+            jax.jit(self._paged_decode_body, static_argnums=(2,),
                     donate_argnums=(1,)), mesh)
+        self._verify = OnMesh(
+            jax.jit(self._paged_verify_body, donate_argnums=(1,)), mesh)
 
     # --------------------------------------------------------- weights
 
@@ -197,313 +244,6 @@ class ServeEngine:
         self._resident_of, self._resident_tree = params, tree
         return tree
 
-    # ----------------------------------------------------------- state
-
-    def init_state(self) -> ServeState:
-        cache = kvcache.init_cache(self.spec, self.mesh)
-        rep = shd.replicated(self.mesh)
-        vec = lambda v: jax.device_put(v, rep)
-        s = self.slots
-        return ServeState(
-            cache_k=cache["k"], cache_v=cache["v"],
-            lengths=vec(jnp.zeros((s,), jnp.int32)),
-            last_token=vec(jnp.zeros((s,), jnp.int32)),
-            active=vec(jnp.zeros((s,), bool)),
-            remaining=vec(jnp.zeros((s,), jnp.int32)))
-
-    # --------------------------------------------------------- prefill
-
-    def _tied_logits(self, params, h):
-        with scope("lm_head"):
-            emb = cast(params["embed"], self.dtype)
-            logits = (h @ emb.T).astype(jnp.float32)
-            if self.model_cfg.logit_scale != 1.0:
-                logits = logits * self.model_cfg.logit_scale
-            return logits
-
-    def _greedy(self, params, h):
-        """Greedy next token from final-normed hidden states."""
-        logits = self._tied_logits(params, h)
-        with scope("sample"):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    @scoped("prefill")
-    def _prefill_body(self, params, state: ServeState, tokens,
-                      prompt_len, slot, max_new
-                      ) -> Tuple[ServeState, jax.Array]:
-        self.prefill_traces.append(1)   # trace-time compile marker
-        # the slot's cache page, in canonical layout for the model
-        ck = lax.dynamic_slice_in_dim(state.cache_k, slot, 1, axis=1)
-        cv = lax.dynamic_slice_in_dim(state.cache_v, slot, 1, axis=1)
-        cache = {"k": kvcache.to_canonical(ck, self.layout),
-                 "v": kvcache.to_canonical(cv, self.layout)}
-        h, cache = self.model.hidden_states(
-            params, tokens, self.model_cfg, dtype=self.dtype,
-            kv_cache=cache, cur_index=None)
-        # greedy first token from the prompt's true last position — the
-        # padded tail's hidden states exist but are never consulted
-        h_last = lax.dynamic_index_in_dim(h, prompt_len - 1, axis=1,
-                                          keepdims=False)
-        first = self._greedy(params, h_last)[0]
-        zeros = (0,) * (state.cache_k.ndim - 2)
-        ck = lax.dynamic_update_slice(
-            state.cache_k, kvcache.from_canonical(cache["k"], self.layout),
-            (0, slot) + zeros)
-        cv = lax.dynamic_update_slice(
-            state.cache_v, kvcache.from_canonical(cache["v"], self.layout),
-            (0, slot) + zeros)
-        rem = max_new - 1            # the prefill itself produced token 1
-        active = (rem > 0) & (prompt_len < self.max_seq)
-        return ServeState(
-            cache_k=ck, cache_v=cv,
-            lengths=state.lengths.at[slot].set(prompt_len),
-            last_token=state.last_token.at[slot].set(first),
-            active=state.active.at[slot].set(active),
-            remaining=state.remaining.at[slot].set(
-                jnp.where(active, rem, 0))), first
-
-    def _note_program(self, name: str, jitted, args,
-                      static_idx: Tuple[int, ...] = ()) -> None:
-        """Remember how to ``.lower()`` one pinned program: shape/
-        dtype/sharding skeletons of its first call's traced arguments
-        (``engine._arg_specs`` — no buffer kept alive, the donation
-        contract survives) with static arguments kept verbatim in
-        place. A dict-membership check per call on the hot path,
-        nothing more."""
-        if name in self._programs:
-            return
-        statics = set(static_idx)
-        dyn = iter(_arg_specs(tuple(
-            a for i, a in enumerate(args) if i not in statics)))
-        lower_args = tuple(a if i in statics else next(dyn)
-                           for i, a in enumerate(args))
-        self._programs[name] = (jitted, lower_args)
-
-    def program_memory(self) -> dict:
-        """``{program_name: memory_analysis dict}`` for every pinned
-        program the run has called — prefill, each decode-ladder rung,
-        the speculative verify. An empty dict per program on backends
-        without memory planning (the memledger records the gap as a
-        note); lowering hits jit's trace cache, so this is cheap and
-        off the request clock."""
-        out: dict = {}
-        for name, (jitted, lower_args) in sorted(self._programs.items()):
-            try:
-                out[name] = compat.memory_analysis(
-                    jitted.lower(*lower_args).compile())
-            except Exception:
-                out[name] = {}
-        return out
-
-    def prefill(self, params, state: ServeState, tokens, prompt_len: int,
-                slot: int, max_new: int) -> Tuple[ServeState, jax.Array]:
-        """Admit one request into ``slot``. ``tokens`` is the padded
-        (1, prompt_pad) prompt; scalars go in as traced int32 so every
-        admission reuses the one compiled program. Returns the updated
-        state and the request's FIRST generated token (a device scalar
-        — ``int()`` it to fence)."""
-        params = self._resident(params)
-        tokens = jnp.asarray(tokens, jnp.int32).reshape(1, self.prompt_pad)
-        args = (params, state, tokens, jnp.int32(prompt_len),
-                jnp.int32(slot), jnp.int32(max_new))
-        self._note_program("prefill", self._prefill, args)
-        return self._prefill(*args)
-
-    # ---------------------------------------------------------- decode
-
-    @scoped("decode")
-    def _decode_body(self, params, state: ServeState, k: int
-                     ) -> Tuple[ServeState, jax.Array, jax.Array]:
-        self.decode_traces.append(k)    # trace-time compile marker
-        slots = self.slots
-
-        def step(st: ServeState, _):
-            def run(st: ServeState):
-                # write position per slot; inactive slots' (discarded)
-                # junk write is clamped in-bounds so a completed full
-                # slot can never scatter out of range
-                pos = jnp.minimum(st.lengths, self.max_seq - 1)
-                cache = {"k": kvcache.to_canonical(st.cache_k,
-                                                   self.layout),
-                         "v": kvcache.to_canonical(st.cache_v,
-                                                   self.layout)}
-                h, cache = self.model.hidden_states(
-                    params, st.last_token[:, None], self.model_cfg,
-                    dtype=self.dtype, kv_cache=cache, cur_index=pos)
-                nxt = self._greedy(params, h[:, 0])
-                act = st.active
-                new_len = jnp.where(act, st.lengths + 1, st.lengths)
-                new_rem = jnp.where(act, st.remaining - 1, st.remaining)
-                new_state = ServeState(
-                    cache_k=kvcache.from_canonical(cache["k"],
-                                                   self.layout),
-                    cache_v=kvcache.from_canonical(cache["v"],
-                                                   self.layout),
-                    lengths=new_len,
-                    last_token=jnp.where(act, nxt, st.last_token),
-                    # a slot completes on budget exhaustion or a full
-                    # cache page (forced eviction at max_seq)
-                    active=act & (new_rem > 0) & (new_len < self.max_seq),
-                    remaining=new_rem)
-                return new_state, jnp.where(act, nxt, -1), act
-
-            def skip(st: ServeState):
-                # nothing active (the batch emptied mid-scan): pass the
-                # state through untouched — same cond discipline that
-                # kept the training superstep's padded tail bitwise
-                return (st, jnp.full((slots,), -1, jnp.int32),
-                        jnp.zeros((slots,), bool))
-
-            st, tok, valid = lax.cond(st.active.any(), run, skip, st)
-            return st, (tok, valid)
-
-        state, (toks, valid) = lax.scan(step, state, None, length=k)
-        return state, toks, valid
-
-    def decode(self, params, state: ServeState, k: Optional[int] = None
-               ) -> Tuple[ServeState, jax.Array, jax.Array]:
-        """One decode superstep: up to ``k`` (default ``decode_k``)
-        tokens for every active slot. ``k`` must be a warmed ladder
-        rung — any other value would trace a new program mid-run and
-        break the program-budget pin. Returns ``(state, tokens (k,
-        slots), valid (k, slots))`` — entries with ``valid=False`` are
-        placeholders (-1) and must not be read. Async: fence on the
-        returned tokens."""
-        params = self._resident(params)
-        k = self.decode_k if k is None else int(k)
-        if k not in self.ladder:
-            # fail at the fault site: a foreign k would silently trace
-            # a NEW program mid-run — charging XLA compilation to
-            # exactly the latency a downshift is trying to relieve —
-            # and only surface at the end-of-run program pin, if ever
-            raise ValueError(
-                f"decode k={k} is not a warmed ladder rung "
-                f"{self.ladder}")
-        self._note_program(f"decode_k{k}", self._decode,
-                           (params, state, k), static_idx=(2,))
-        return self._decode(params, state, k)
-
-    # ---------------------------------------------------------- warmup
-
-    def warmup(self, params) -> None:
-        """Compile every program OFF the request clock: a cold first
-        admission would charge XLA compilation to that request's TTFT,
-        and a cold ladder rung would charge a recompile to the very
-        overload the downshift is trying to relieve. Runs a dummy
-        prefill + one decode superstep PER LADDER RUNG on a throwaway
-        state (donated away), fences, and leaves the jit caches warm —
-        after this, a whole serve run (adapt transitions included)
-        compiles nothing (``assert_two_programs``)."""
-        params = self._resident(params)
-        state = self.init_state()
-        dummy = jnp.zeros((1, self.prompt_pad), jnp.int32)
-        state, first = self.prefill(params, state, dummy, 1, 0, 2)
-        jax.device_get(first)
-        for k in self.ladder:
-            state, toks, valid = self.decode(params, state, k)
-            jax.device_get((toks, valid))
-
-    def compile_counts(self) -> Tuple[int, int]:
-        return len(self.prefill_traces), len(self.decode_traces)
-
-    def assert_two_programs(self) -> None:
-        """The compiled-program pin: one prefill + one decode trace PER
-        LADDER RUNG for the whole run, warmup included — exactly two
-        programs on the default single-rung ladder, and never a trace
-        the warmup didn't already pay."""
-        p, d = self.compile_counts()
-        want = (1, len(self.ladder))
-        if (p, d) != want:
-            raise AssertionError(
-                f"serve engine compiled {p} prefill / {d} decode "
-                f"program(s), expected {want[0]}/{want[1]} for ladder "
-                f"{self.ladder}; the two-program contract is broken")
-
-
-class PagedServeState(NamedTuple):
-    """Device-resident PAGED serving state. Unlike :class:`ServeState`
-    there is no per-slot cache arena: K/V live in one shared pool of
-    fixed-size pages (+1 trash page) and the slot→page mapping is HOST
-    state (``PageAllocator.table``), passed into every dispatch as a
-    small traced int32 array."""
-
-    pool_k: jax.Array        # (L, kv, pages+1, page_tokens, head_dim)
-    pool_v: jax.Array
-    lengths: jax.Array       # (slots,) int32: tokens in cache per slot
-    last_token: jax.Array    # (slots,) int32: newest token, not yet cached
-    active: jax.Array        # (slots,) bool: slot holds a live sequence
-    remaining: jax.Array     # (slots,) int32: generation budget left
-    # a model with window layers only (else empty / None): one ring per
-    # window layer, (kv, slots, ring_tokens, head_dim) each, and what the
-    # LAST program run counted: [token steps run, the model's own counts]
-    ring_k: tuple = ()
-    ring_v: tuple = ()
-    stats: Optional[jax.Array] = None
-
-
-class PagedServeEngine(ServeEngine):
-    """The paged + shared-prefix + speculative serving engine.
-
-    Same compiled-program discipline as the dense engine — ONE prefill
-    program, one decode program per ladder rung — generalised by one
-    more pinned program when speculation is on: the VERIFY forward, a
-    single batched target forward over a ``speculate_k``-token window
-    per slot that scores a whole host-proposed draft at once. Page
-    table and per-dispatch active mask ride as small traced arrays
-    (fixed shapes → no retrace); admission, eviction, page exhaustion
-    and drafting are pure host decisions between dispatches.
-
-    ``speculate_k`` is the verify WINDOW width: the window carries the
-    slot's pending ``last_token`` plus ``speculate_k - 1`` draft tokens,
-    so ``speculate_k >= 2`` turns speculation on (a window of 1 is
-    plain decode) and ``0`` turns it off. Greedy token output is
-    bitwise-identical to non-speculative greedy decode by construction:
-    every emitted token is the argmax after a verified-correct token,
-    and rejected drafts' junk KV sits at positions beyond the new
-    length, where write-then-attend overwrites it before any query can
-    attend it.
-    """
-
-    paged = True
-
-    def __init__(self, model_cfg: ModelConfig, mesh, *, slots: int,
-                 max_seq: int, prompt_pad: int, decode_k: int = 8,
-                 page_tokens: int = 8, pages: int = 0,
-                 speculate_k: int = 0, dtype=jnp.float32,
-                 adapt_ladder: Optional[Sequence[int]] = None,
-                 ring_margin: int = 128):
-        super().__init__(model_cfg, mesh, slots=slots, max_seq=max_seq,
-                         prompt_pad=prompt_pad, decode_k=decode_k,
-                         layout="st", dtype=dtype,
-                         adapt_ladder=adapt_ladder)
-        if speculate_k == 1 or speculate_k < 0:
-            raise ValueError(
-                f"--speculate-k must be 0 (off) or >= 2 (window of "
-                f"last_token + drafts), got {speculate_k}")
-        self.speculate_k = int(speculate_k)
-        self.spec = kvcache.PagedCacheSpec.from_model(
-            model_cfg, slots=slots, max_seq=max_seq,
-            page_tokens=page_tokens, pages=pages, dtype=dtype,
-            ring_margin=ring_margin)
-        # two kinds of cache state: the programs of such a model are
-        # bodies of their own below, the others' are untouched
-        self.windowed = self.spec.window_layers > 0
-        if self.windowed and self.speculate_k:
-            raise ValueError(
-                "--speculate-k over a model with window layers is not "
-                "built: a rejected draft would have to be unwound from "
-                "the ring")
-        self.page_tokens = self.spec.page_tokens
-        self.alloc = kvcache.PageAllocator(self.spec)
-        self.verify_traces: list = []
-        self._prefill = OnMesh(
-            jax.jit(self._paged_prefill_body, donate_argnums=(1,)), mesh)
-        self._decode = OnMesh(
-            jax.jit(self._paged_decode_body, static_argnums=(2,),
-                    donate_argnums=(1,)), mesh)
-        self._verify = OnMesh(
-            jax.jit(self._paged_verify_body, donate_argnums=(1,)), mesh)
-
     def new_allocator(self) -> kvcache.PageAllocator:
         """Fresh page bookkeeping (drops any shared-prefix registry) —
         one allocator per serve run, like one state per run."""
@@ -530,6 +270,20 @@ class PagedServeEngine(ServeEngine):
 
     # --------------------------------------------------------- prefill
 
+    def _tied_logits(self, params, h):
+        with scope("lm_head"):
+            emb = cast(params["embed"], self.dtype)
+            logits = (h @ emb.T).astype(jnp.float32)
+            if self.model_cfg.logit_scale != 1.0:
+                logits = logits * self.model_cfg.logit_scale
+            return logits
+
+    def _greedy(self, params, h):
+        """Greedy next token from final-normed hidden states."""
+        logits = self._tied_logits(params, h)
+        with scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
     @scoped("prefill")
     def _paged_prefill_body(self, params, state: PagedServeState,
                             tokens, prompt_len, slot, max_new, page_row,
@@ -537,10 +291,10 @@ class PagedServeEngine(ServeEngine):
                             ) -> Tuple[PagedServeState, jax.Array]:
         self.prefill_traces.append(1)   # trace-time compile marker
         spec = self.spec
-        # dense prefill into a throwaway scratch row — the model's
-        # existing cache-aware full forward, so the K/V bytes are
-        # BITWISE the ones the dense engine would store — then copy the
-        # prompt's pages into the slot's pages, a whole page at a time
+        # the model's full causal forward over the padded prompt, its
+        # rotated K/V kept in a throwaway scratch row
+        # (``prefill_kv_hidden_states``) — then copy the prompt's pages
+        # into the slot's pages, a whole page at a time
         # (``_scatter_pages``). A model with window layers hands back
         # every layer's k/v itself and seeds both kinds of state
         # (``_windowed_prefill``).
@@ -553,9 +307,9 @@ class PagedServeEngine(ServeEngine):
                              spec.n_kv_heads, spec.head_dim)
             scratch = {"k": jnp.zeros(scratch_shape, self.dtype),
                        "v": jnp.zeros(scratch_shape, self.dtype)}
-            h, scratch = self.model.hidden_states(
+            h, scratch = self.model.prefill_kv_hidden_states(
                 params, tokens, self.model_cfg, dtype=self.dtype,
-                kv_cache=scratch, cur_index=None)
+                kv_cache=scratch)
         h_last = lax.dynamic_index_in_dim(h, prompt_len - 1, axis=1,
                                           keepdims=False)
         first = self._greedy(params, h_last)[0]
@@ -583,8 +337,7 @@ class PagedServeEngine(ServeEngine):
         prompt and unmapped ones route to the trash page. The prompt's
         last page carries pad-token junk past ``prompt_len``: positions
         beyond the slot's length, which the decode mask (keys <= pos)
-        never reads and write-then-attend overwrites first, exactly like
-        the dense arena's padded tail."""
+        never reads and write-then-attend overwrites first."""
         spec = self.spec
         pt = spec.page_tokens
         n_pp = -(-self.prompt_pad // pt)         # pages of a prompt
@@ -652,14 +405,51 @@ class PagedServeEngine(ServeEngine):
         return h, {"pool_k": pk, "pool_v": pv, "ring_k": rk, "ring_v": rv,
                    "stats": stats}
 
+    def _note_program(self, name: str, jitted, args,
+                      static_idx: Tuple[int, ...] = ()) -> None:
+        """Remember how to ``.lower()`` one pinned program: shape/
+        dtype/sharding skeletons of its first call's traced arguments
+        (``engine._arg_specs`` — no buffer kept alive, the donation
+        contract survives) with static arguments kept verbatim in
+        place. A dict-membership check per call on the hot path,
+        nothing more."""
+        if name in self._programs:
+            return
+        statics = set(static_idx)
+        dyn = iter(_arg_specs(tuple(
+            a for i, a in enumerate(args) if i not in statics)))
+        lower_args = tuple(a if i in statics else next(dyn)
+                           for i, a in enumerate(args))
+        self._programs[name] = (jitted, lower_args)
+
+    def program_memory(self) -> dict:
+        """``{program_name: memory_analysis dict}`` for every pinned
+        program the run has called — prefill, each decode-ladder rung,
+        the speculative verify. An empty dict per program on backends
+        without memory planning (the memledger records the gap as a
+        note); lowering hits jit's trace cache, so this is cheap and
+        off the request clock."""
+        out: dict = {}
+        for name, (jitted, lower_args) in sorted(self._programs.items()):
+            try:
+                out[name] = compat.memory_analysis(
+                    jitted.lower(*lower_args).compile())
+            except Exception:
+                out[name] = {}
+        return out
+
     def prefill(self, params, state: PagedServeState, tokens,
                 prompt_len: int, slot: int, max_new: int,
                 page_row=None, shared_len: int = 0
                 ) -> Tuple[PagedServeState, jax.Array]:
-        """Admit one request into ``slot``: the dense contract plus the
-        slot's page-table ROW (defaults to the allocator's current row
-        for ``slot``) and the shared-prefix watermark ``shared_len``
-        (``alloc.admit_shared_len``) — both traced, one program."""
+        """Admit one request into ``slot``. ``tokens`` is the padded
+        (1, prompt_pad) prompt; scalars go in as traced int32 so every
+        admission reuses the one compiled program, and so do the slot's
+        page-table ROW (defaults to the allocator's current row for
+        ``slot``) and the shared-prefix watermark ``shared_len``
+        (``alloc.admit_shared_len``). Returns the updated state and the
+        request's FIRST generated token (a device scalar — ``int()`` it
+        to fence)."""
         params = self._resident(params)
         tokens = jnp.asarray(tokens, jnp.int32).reshape(1, self.prompt_pad)
         if page_row is None:
@@ -778,13 +568,22 @@ class PagedServeEngine(ServeEngine):
     def decode(self, params, state: PagedServeState,
                k: Optional[int] = None, dispatch_active=None
                ) -> Tuple[PagedServeState, jax.Array, jax.Array]:
-        """One paged decode superstep. The CURRENT page table (the host
+        """One decode superstep: up to ``k`` (default ``decode_k``)
+        tokens for every active slot of the dispatch. ``k`` must be a
+        warmed ladder rung. The CURRENT page table (the host
         allocator's) and the dispatch's slot mask go in as small traced
         int32/bool arrays — fixed shapes, so every dispatch reuses the
-        rung's one compiled program."""
+        rung's one compiled program. Returns ``(state, tokens (k,
+        slots), valid (k, slots))`` — entries with ``valid=False`` are
+        placeholders (-1) and must not be read. Async: fence on the
+        returned tokens."""
         params = self._resident(params)
         k = self.decode_k if k is None else int(k)
         if k not in self.ladder:
+            # fail at the fault site: a foreign k would silently trace
+            # a NEW program mid-run — charging XLA compilation to
+            # exactly the latency a downshift is trying to relieve —
+            # and only surface at the end-of-run program pin, if ever
             raise ValueError(
                 f"decode k={k} is not a warmed ladder rung "
                 f"{self.ladder}")
@@ -875,10 +674,16 @@ class PagedServeEngine(ServeEngine):
     # ---------------------------------------------------------- warmup
 
     def warmup(self, params) -> None:
-        """Compile prefill + every decode rung (+ verify when
-        speculating) off the request clock, on a throwaway state and a
-        junk page table (compilation only sees shapes; the junk writes
-        route to the trash page)."""
+        """Compile every program OFF the request clock: a cold first
+        admission would charge XLA compilation to that request's TTFT,
+        and a cold ladder rung would charge a recompile to the very
+        overload the downshift is trying to relieve. Runs a dummy
+        prefill, one decode superstep PER LADDER RUNG and, when
+        speculating, one verify, on a throwaway state and a junk page
+        table (compilation only sees shapes; the junk writes route to
+        the trash page), fences, and leaves the jit caches warm — after
+        this, a whole serve run (adapt transitions included) compiles
+        nothing (``assert_two_programs``)."""
         params = self._resident(params)
         state = self.init_state()
         dummy = jnp.zeros((1, self.prompt_pad), jnp.int32)
@@ -895,10 +700,22 @@ class PagedServeEngine(ServeEngine):
             state, toks, valid, e = self.verify(params, state, draft)
             jax.device_get((toks, valid, e))
 
+    def compile_counts(self) -> Tuple[int, int]:
+        return len(self.prefill_traces), len(self.decode_traces)
+
     def assert_two_programs(self) -> None:
-        """The dense pin (1 prefill + 1 decode per rung) plus exactly
-        one verify program when speculation is on."""
-        super().assert_two_programs()
+        """The compiled-program pin: one prefill + one decode trace PER
+        LADDER RUNG for the whole run, warmup included — exactly two
+        programs on the default single-rung ladder — plus exactly one
+        verify program when speculation is on, and never a trace the
+        warmup didn't already pay."""
+        p, d = self.compile_counts()
+        want = (1, len(self.ladder))
+        if (p, d) != want:
+            raise AssertionError(
+                f"serve engine compiled {p} prefill / {d} decode "
+                f"program(s), expected {want[0]}/{want[1]} for ladder "
+                f"{self.ladder}; the two-program contract is broken")
         want = 1 if self.speculate_k >= 2 else 0
         v = len(self.verify_traces)
         if v != want:

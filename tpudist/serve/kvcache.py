@@ -1,27 +1,19 @@
-"""Sharded KV cache for the serving engine: dense arena OR paged pool.
+"""Sharded KV cache for the serving engine: a paged pool.
 
-Two storage disciplines, one module:
-
-* **Dense arena** (:class:`CacheSpec`, the original): one K and one V
-  array of canonical shape ``(n_layers, slots, max_seq, n_kv_heads,
-  head_dim)`` — one private ``max_seq``-long row per slot. Simple, but
-  HBM scales with ``slots × max_seq`` even when most slots hold short
-  sequences, and an identical system-prompt prefix is stored once per
-  concurrent request.
-* **Paged pool** (:class:`PagedCacheSpec` + :class:`PageAllocator`,
-  vLLM-style): fixed-size pages of ``page_tokens`` positions in a pool
-  of ``pages`` (+1 sacrificial TRASH page), mapped to slots through a
-  host-owned slot→page table. A slot only holds pages for positions it
-  has actually written, so the pool can be sized well below
-  ``slots × max_seq`` — the freed HBM becomes sustained concurrency.
-  Full prefix pages of a common system prompt are REFCOUNTED and shared
-  across every slot (``register_shared``); the partial tail page is
-  "forked" copy-on-write at admission (the prefill recomputes those
-  positions into the slot's first private page — bitwise-identical
-  content, same tokens at the same absolute positions), so no slot ever
-  writes a shared page. Invalid/masked writes are routed to the trash
-  page (pool index ``pages``), which no page table ever references and
-  the ownership mask therefore never reads.
+K and V live in fixed-size pages of ``page_tokens`` positions in a pool
+of ``pages`` (+1 sacrificial TRASH page), mapped to slots through a
+host-owned slot→page table (:class:`PagedCacheSpec` +
+:class:`PageAllocator`, vLLM-style). A slot only holds pages for
+positions it has actually written, so the pool can be sized well below
+``slots × max_seq`` — the freed HBM becomes sustained concurrency.
+Full prefix pages of a common system prompt are REFCOUNTED and shared
+across every slot (``register_shared``); the partial tail page is
+"forked" copy-on-write at admission (the prefill recomputes those
+positions into the slot's first private page — bitwise-identical
+content, same tokens at the same absolute positions), so no slot ever
+writes a shared page. Invalid/masked writes are routed to the trash
+page (pool index ``pages``), which no page table ever references and
+the ownership mask therefore never reads.
 
 The page table itself never lives on device state: the HOST allocator
 owns it and each dispatch passes the current table in as a small traced
@@ -29,25 +21,16 @@ int32 array — the compiled programs stay exactly the programs the
 two-program discipline pinned (tpudist.serve.engine), and admission /
 eviction / page exhaustion are pure host decisions between dispatches.
 
-GQA-aware by construction either way: the cache stores the COMPACT kv
-heads (the same layout the models' ``wk``/``wv`` produce) and expansion
-to the query head count happens inside the attention math — an
-8×-grouped model's cache is 8× smaller than a naive full-head cache,
-which is the difference between fitting long contexts in HBM or not.
+GQA-aware by construction: the pool stores the COMPACT kv heads (the
+same layout the models' ``wk``/``wv`` produce) and expansion to the
+query head count happens inside the attention math — an 8×-grouped
+model's cache is 8× smaller than a naive full-head cache, which is the
+difference between fitting long contexts in HBM or not.
 
 Sharding rides the existing mesh machinery: ``parallel.sharding.
-kv_cache_specs`` / ``paged_kv_cache_specs`` are the ``param_specs``-
-style single sources for the PartitionSpecs (slots — or pages — over
-the batch axes, kv heads over tensor), sanitised per-mesh exactly like
-model params.
-
-``layout`` is a PHYSICAL storage knob the serve autotuner probes for
-the dense arena: ``"st"`` (canonical, seq-major) or ``"hs"``
-(heads-major). The models' cache API always sees canonical;
-:func:`to_canonical` / :func:`from_canonical` transpose inside the
-compiled program, so the layout's real cost/benefit is exactly what
-the probe measures. The paged pool has one physical layout (pages are
-already the placement unit).
+paged_kv_cache_specs`` is the ``param_specs``-style single source for
+the PartitionSpec (pages over the batch axes, kv heads over tensor),
+sanitised per-mesh exactly like model params.
 """
 
 from __future__ import annotations
@@ -61,92 +44,6 @@ import numpy as np
 
 from tpudist.config import ModelConfig
 from tpudist.parallel import sharding as shd
-from tpudist.parallel.sharding import KV_CACHE_LAYOUTS  # noqa: F401
-
-
-@dataclasses.dataclass(frozen=True)
-class CacheSpec:
-    """Static shape/dtype/layout of one serving run's KV cache."""
-
-    n_layers: int
-    slots: int
-    max_seq: int
-    n_kv_heads: int
-    head_dim: int
-    dtype: Any = jnp.float32
-    layout: str = "st"
-
-    @classmethod
-    def from_model(cls, cfg: ModelConfig, *, slots: int, max_seq: int,
-                   dtype=jnp.float32, layout: str = "st") -> "CacheSpec":
-        return cls(n_layers=cfg.n_layers, slots=slots, max_seq=max_seq,
-                   n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_size,
-                   dtype=dtype, layout=layout)
-
-    @property
-    def canonical_shape(self) -> tuple:
-        return (self.n_layers, self.slots, self.max_seq,
-                self.n_kv_heads, self.head_dim)
-
-    @property
-    def storage_shape(self) -> tuple:
-        l, s, t, h, d = self.canonical_shape
-        return (l, s, t, h, d) if self.layout == "st" else (l, s, h, t, d)
-
-    @property
-    def bytes(self) -> int:
-        """Total cache footprint (K + V) — the number an operator sizes
-        slots × max_seq against HBM with."""
-        n = 1
-        for d in self.canonical_shape:
-            n *= d
-        return 2 * n * jnp.dtype(self.dtype).itemsize
-
-
-def to_canonical(arr: jax.Array, layout: str) -> jax.Array:
-    """Storage layout → canonical (L, slots, seq, kv_heads, head_dim).
-    A no-op for ``"st"``; ``"hs"`` transposes (the swap is its own
-    inverse, so one permutation serves both directions)."""
-    if layout == "st":
-        return arr
-    if layout == "hs":
-        return jnp.transpose(arr, (0, 1, 3, 2, 4))
-    raise ValueError(f"unknown kv-cache layout {layout!r}: "
-                     f"{' | '.join(KV_CACHE_LAYOUTS)}")
-
-
-def from_canonical(arr: jax.Array, layout: str) -> jax.Array:
-    """Canonical → storage layout (see :func:`to_canonical`)."""
-    return to_canonical(arr, layout)
-
-
-def cache_shardings(spec: CacheSpec, mesh) -> Any:
-    """NamedSharding for the K/V arrays on ``mesh``, sanitised like
-    model params (a slot count the batch axes don't divide falls back
-    to replicated instead of erroring)."""
-    shape = jax.ShapeDtypeStruct(spec.storage_shape, spec.dtype)
-    pspec = shd.sanitize_specs(
-        shape, shd.kv_cache_specs(spec.layout), mesh)
-    return shd.named(mesh, pspec)
-
-
-def init_cache(spec: CacheSpec, mesh=None) -> Dict[str, jax.Array]:
-    """Zero-initialised ``{"k", "v"}`` cache in the storage layout,
-    placed to its mesh sharding when one is given. Zeros are never read
-    (the length mask guards every slot), but a deterministic initial
-    value keeps the whole serve run a pure function of (params, seed)."""
-    k = jnp.zeros(spec.storage_shape, spec.dtype)
-    v = jnp.zeros(spec.storage_shape, spec.dtype)
-    if mesh is not None:
-        sh = cache_shardings(spec, mesh)
-        k = jax.device_put(k, sh)
-        v = jax.device_put(v, sh)
-    return {"k": k, "v": v}
-
-
-# ------------------------------------------------------------------ #
-# paged pool                                                          #
-# ------------------------------------------------------------------ #
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,10 +53,9 @@ class PagedCacheSpec:
     ``pages`` is the usable pool size; the physical pool carries one
     extra sacrificial TRASH page at index ``pages`` where every
     masked/invalid write is routed (a page table never references it,
-    so the ownership mask never reads it — the paged twin of the dense
-    arena's clamped junk writes). ``page_tokens`` is the fixed page
-    length in positions; ``max_pages_per_slot`` is the page-table row
-    width (``ceil(max_seq / page_tokens)``).
+    so the ownership mask never reads it). ``page_tokens`` is the fixed
+    page length in positions; ``max_pages_per_slot`` is the page-table
+    row width (``ceil(max_seq / page_tokens)``).
 
     A model with WINDOW layers (``cfg.sliding_window``) keeps two kinds of
     state side by side. ``n_layers`` then counts the FULL layers only:
@@ -191,7 +87,7 @@ class PagedCacheSpec:
                 f"max_seq {max_seq}]")
         maxp = -(-max_seq // page_tokens)
         if pages <= 0:
-            # default pool = full dense capacity: correctness-neutral
+            # default pool = every slot at max_seq: correctness-neutral
             # sizing (admission can never be denied); operators shrink
             # it to trade capacity for sustained concurrency
             pages = slots * maxp
@@ -244,9 +140,8 @@ class PagedCacheSpec:
         """The PAGED footprint: pool pages (trash included — it is
         real HBM) × page bytes for K and V, plus the page-table
         overhead, plus the window layers' rings. This is the number
-        serve_tick / BENCH_SERVE report, so the fixed-HBM-budget
-        acceptance claim is measured against what is actually allocated,
-        not the dense formula."""
+        serve_tick reports: what is actually allocated, not ``slots ×
+        max_seq``."""
         n = 1
         for d in self.pool_shape:
             n *= d
@@ -256,8 +151,8 @@ class PagedCacheSpec:
 
 def paged_cache_shardings(spec: PagedCacheSpec, mesh) -> Any:
     """NamedSharding for the paged K/V pools: pages ride the batch axes
-    (the pool's embarrassingly-parallel dim, like slots in the dense
-    arena), kv heads ride tensor — sanitised like model params."""
+    (the pool's embarrassingly-parallel dim), kv heads ride tensor —
+    sanitised like model params."""
     shape = jax.ShapeDtypeStruct(spec.pool_shape, spec.dtype)
     pspec = shd.sanitize_specs(shape, shd.paged_kv_cache_specs(), mesh)
     return shd.named(mesh, pspec)

@@ -6,7 +6,8 @@ prompt lengths and generation budgets are all seeded, so a serve run is
 reproducible end to end), pass ADMISSION CONTROL (bounded queue,
 per-request TTFT deadlines, malformed-request rejection —
 :mod:`tpudist.serve.resilience`), queue until a slot frees, prefill
-into the free slot, and decode continuously: every dispatch is one
+into the free slot once the page allocator grants its pages, and
+decode continuously: every dispatch is one
 compiled superstep over the WHOLE slot batch, with completed slots
 freed and refilled between dispatches — no draining, no batch
 reshaping, no recompiles.
@@ -46,7 +47,7 @@ from tpudist.obs import trace as trace_lib
 from tpudist.obs.alerts import AlertEngine
 from tpudist.serve import resilience as res_lib
 from tpudist.serve import slo as slo_lib
-from tpudist.serve.engine import ServeEngine
+from tpudist.serve.engine import PagedServeEngine
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +86,7 @@ def make_requests(n: int, *, prompt_pad: int, vocab_size: int,
     from [prompt_min, prompt_pad]. ``prefix_len > 0`` gives every
     request the same :func:`shared_prefix_tokens` system-prompt prefix
     (per-request tails stay distinct; prompt lengths never undercut the
-    prefix) — the paged engine's shared-prefix workload. ``prefix_len
+    prefix) — the engine's shared-prefix workload. ``prefix_len
     = 0`` is bit-for-bit the original stream (identical rng draws)."""
     rng = np.random.default_rng(seed)
     if rate > 0:
@@ -214,7 +215,7 @@ class _Slot:
     budget: int               # max_new after any adapt-time truncation
 
 
-def run_serve(engine: ServeEngine, params, requests: List[Request], *,
+def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
               metrics: Any = None, tick_every: int = 8,
               clock: Callable[[], float] = time.perf_counter,
               n_chips: Optional[int] = None,
@@ -228,11 +229,12 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
     (percentiles, throughput, per-gate SLO statuses, the exact shed
     partition, compile counts).
 
-    The engine must already be warmed (:meth:`ServeEngine.warmup`) so
-    the request clock never pays XLA compilation. ``metrics`` (a
-    MetricsLogger) receives periodic ``kind=serve_tick`` records plus
-    per-request ``kind=serve_request`` outcome events; the caller logs
-    the final ``kind=serve`` summary so it can stamp its own fields in.
+    The engine must already be warmed
+    (:meth:`PagedServeEngine.warmup`) so the request clock never pays
+    XLA compilation. ``metrics`` (a MetricsLogger) receives periodic
+    ``kind=serve_tick`` records plus per-request
+    ``kind=serve_request`` outcome events; the caller logs the final
+    ``kind=serve`` summary so it can stamp its own fields in.
 
     ``resilience`` turns on admission control / degradation
     (:class:`~tpudist.serve.resilience.ResilienceConfig`; None keeps
@@ -248,14 +250,13 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
     chaos or resilience is armed; the CLI also arms it under the
     launcher's requeue supervision).
 
-    A PAGED engine (``engine.paged``) changes admission and dispatch,
-    never the accounting: slot admission additionally asks the page
-    allocator (a pool too full leaves the request WAITING —
-    backpressure, not shedding — while a request too big to EVER fit
-    this pool is ``rejected`` with reason ``kv_pages_exhausted``, in
-    the same exact ledger partition); each dispatch first grows every
-    live slot's page mapping to cover the positions it will write
-    (growth failure evicts, freeing the pages); finishing a slot
+    The KV pool shapes admission and dispatch, never the accounting:
+    slot admission asks the page allocator (a pool too full leaves the
+    request WAITING — backpressure, not shedding — while a request too
+    big to EVER fit this pool is ``rejected`` with reason
+    ``kv_pages_exhausted``, in the same exact ledger partition); each
+    dispatch first grows every live slot's page mapping to cover the
+    positions it will write (growth failure evicts, freeing the pages); finishing a slot
     returns its pages. ``shared_prefix`` (token array) registers a
     refcounted shared system-prompt prefix once, served from the same
     pages to every admission that starts with it. With
@@ -287,11 +288,10 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
     waiting: deque = deque()         # accepted, not yet slotted
     slots: List[Optional[_Slot]] = [None] * engine.slots
     state = engine.init_state()
-    paged = bool(getattr(engine, "paged", False))
-    alloc = engine.new_allocator() if paged else None
+    alloc = engine.new_allocator()
     prefix_len = 0
     prefix_arr: Optional[np.ndarray] = None
-    if paged and shared_prefix is not None and len(shared_prefix) > 0:
+    if shared_prefix is not None and len(shared_prefix) > 0:
         prefix_arr = np.asarray(shared_prefix, np.int32)
         prefix_len = int(min(len(prefix_arr), engine.prompt_pad))
         prefix_arr = prefix_arr[:prefix_len]
@@ -299,12 +299,12 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         # a pool that cannot hold the prefix is a config error, raised
         state = engine.register_prefix(params, state, prefix_arr,
                                        prefix_len)
-    spec_k = int(getattr(engine, "speculate_k", 0))
+    spec_k = engine.speculate_k
     # a model whose programs count what they did (pairs routed to the
     # experts held here): read back after the fence the loop makes anyway
     # and carried as arguments of the ``prefill`` and ``decode_step``
     # spans, with how full each kind of cache state is
-    counts_on = paged and bool(getattr(engine, "windowed", False))
+    counts_on = engine.windowed
     window_peak = 0
     moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0}
     results: Dict[int, Dict[str, Any]] = {}
@@ -345,7 +345,7 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
     def shared_refs() -> int:
         # refcounts currently held on the shared-prefix pages
         # (includes the registry's own keep-cached hold)
-        if alloc is None or not alloc.shared_pages:
+        if not alloc.shared_pages:
             return 0
         return int(sum(int(alloc.refcount[p])
                        for p in alloc.shared_pages))
@@ -370,12 +370,11 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
               e2e_s=round(t_done - s.req.arrival_s, 6),
               decode_s=round(t_done - s.first_token_s, 6))
         slots[i] = None
-        if paged:
-            # pages return to the pool (shared prefix pages drop one
-            # refcount; the registry hold keeps them cached). Safe: the
-            # device slot is frozen (budget/capacity) or masked out of
-            # every future dispatch until its next prefill
-            alloc.free_slot(i)
+        # pages return to the pool (shared prefix pages drop one
+        # refcount; the registry hold keeps them cached). Safe: the
+        # device slot is frozen (budget/capacity) or masked out of
+        # every future dispatch until its next prefill
+        alloc.free_slot(i)
 
     def expire(t: float) -> None:
         # the accepted queue's head is always the oldest (FIFO in
@@ -432,37 +431,35 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         for i in range(engine.slots):
             if slots[i] is not None or not waiting:
                 continue
-            shared = False
-            if paged:
-                # peek-then-pop: a denied admission must leave the
-                # request at the queue head, not shed it
-                req = waiting[0]
-                shared = is_shared(req)
-                if not alloc.can_ever_admit(req.prompt_len, shared):
-                    # structurally unservable at this pool size: even
-                    # an empty pool could not hold the prompt. Reject
-                    # (exact-partition bucket) instead of wedging the
-                    # queue head forever
-                    waiting.popleft()
-                    led.rejected += 1
-                    event(req.rid, res_lib.REJECTED,
-                          reason="kv_pages_exhausted")
-                    continue
-                pt = engine.spec.page_tokens
-                need = -(-req.prompt_len // pt)
-                reused = min(need, len(alloc.shared_pages)) \
-                    if shared else 0
-                if not alloc.admit(i, req.prompt_len, shared=shared):
-                    # pool full RIGHT NOW: backpressure, not shedding —
-                    # running slots will finish and free pages
-                    tracer.instant("kv_backpressure", cat="serve",
-                                   rid=req.rid, slot=i, pages=need)
-                    break
-                tracer.instant("kv_admit", cat="serve", rid=req.rid,
-                               slot=i, pages=need,
-                               pages_granted=need - reused,
-                               shared_pages_reused=reused)
-            req = waiting.popleft()
+            # peek-then-pop: a denied admission must leave the
+            # request at the queue head, not shed it
+            req = waiting[0]
+            shared = is_shared(req)
+            if not alloc.can_ever_admit(req.prompt_len, shared):
+                # structurally unservable at this pool size: even
+                # an empty pool could not hold the prompt. Reject
+                # (exact-partition bucket) instead of wedging the
+                # queue head forever
+                waiting.popleft()
+                led.rejected += 1
+                event(req.rid, res_lib.REJECTED,
+                      reason="kv_pages_exhausted")
+                continue
+            pt = engine.spec.page_tokens
+            need = -(-req.prompt_len // pt)
+            reused = min(need, len(alloc.shared_pages)) \
+                if shared else 0
+            if not alloc.admit(i, req.prompt_len, shared=shared):
+                # pool full RIGHT NOW: backpressure, not shedding —
+                # running slots will finish and free pages
+                tracer.instant("kv_backpressure", cat="serve",
+                               rid=req.rid, slot=i, pages=need)
+                break
+            tracer.instant("kv_admit", cat="serve", rid=req.rid,
+                           slot=i, pages=need,
+                           pages_granted=need - reused,
+                           shared_pages_reused=reused)
+            waiting.popleft()
             budget = req.max_new
             if cur_level > 0 and res.max_new_cap:
                 budget = min(budget, res.max_new_cap)
@@ -471,15 +468,10 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
             with tracer.span("prefill", cat="serve", rid=req.rid,
                              slot=i, prompt_len=req.prompt_len) as sp:
                 with tracer.span("prefill_enqueue", cat="serve"):
-                    if paged:
-                        state, first = engine.prefill(
-                            params, state, req.tokens[None, :],
-                            req.prompt_len, i, budget,
-                            shared_len=alloc.admit_shared_len(shared))
-                    else:
-                        state, first = engine.prefill(
-                            params, state, req.tokens[None, :],
-                            req.prompt_len, i, budget)
+                    state, first = engine.prefill(
+                        params, state, req.tokens[None, :],
+                        req.prompt_len, i, budget,
+                        shared_len=alloc.admit_shared_len(shared))
                 with tracer.span("prefill_fence", cat="serve"):
                     first = int(first)       # fence: the token exists NOW
                 if counts_on and tracer.enabled:
@@ -552,22 +544,21 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         # speculation only at full service: the degradation ladder's
         # rungs are plain decode programs, and a downshifted pod wants
         # its smallest dispatch, not a wider verify window
-        spec_on = paged and spec_k >= 2 and cur_level == 0
-        if paged:
-            # grow each live slot's mapping to cover every position
-            # this dispatch can write; a slot the pool cannot grow for
-            # is evicted (truncated output, pages fund the others)
-            width = spec_k if spec_on else cur_k
-            for i in occupied:
-                s = slots[i]
-                last = min(s.req.prompt_len + s.generated + width - 2,
-                           engine.max_seq - 1)
-                if not alloc.ensure(i, last):
-                    finish(i, "evicted")
-            occupied = [i for i in range(engine.slots)
-                        if slots[i] is not None]
-            if not occupied:
-                continue
+        spec_on = spec_k >= 2 and cur_level == 0
+        # grow each live slot's mapping to cover every position
+        # this dispatch can write; a slot the pool cannot grow for
+        # is evicted (truncated output, pages fund the others)
+        width = spec_k if spec_on else cur_k
+        for i in occupied:
+            s = slots[i]
+            last = min(s.req.prompt_len + s.generated + width - 2,
+                       engine.max_seq - 1)
+            if not alloc.ensure(i, last):
+                finish(i, "evicted")
+        occupied = [i for i in range(engine.slots)
+                    if slots[i] is not None]
+        if not occupied:
+            continue
         # the chaos serve surface: serve_kill dies HERE (a dispatch
         # boundary — the compiled program is never torn mid-flight),
         # serve_slow returns the stall it injected so virtual time can
@@ -580,8 +571,8 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                 metrics.flush()
             stall_s = float(chaos.on_serve_dispatch(dispatches) or 0.0)
         t_dispatch = clock()
+        occ_mask = np.array([s is not None for s in slots])
         if spec_on:
-            occ_mask = np.array([s is not None for s in slots])
             draft = np.zeros((engine.slots, spec_k - 1), np.int32)
             for i in occupied:
                 s = slots[i]
@@ -594,8 +585,7 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                     state, toks, valid, _emitted = engine.verify(
                         params, state, draft, dispatch_active=occ_mask)
                 toks, valid = fenced(toks, valid)
-        elif paged:
-            occ_mask = np.array([s is not None for s in slots])
+        else:
             with tracer.span("decode_step", cat="serve",
                              active=len(occupied), decode_k=cur_k) as sp:
                 with tracer.span("decode_enqueue", cat="serve"):
@@ -610,13 +600,6 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                         sp.note(kv_full_pages=alloc.pages_used(),
                                 kv_window_tokens=alloc.window_tokens_used(),
                                 **counted)
-        else:
-            with tracer.span("decode_step", cat="serve",
-                             active=len(occupied), decode_k=cur_k):
-                with tracer.span("decode_enqueue", cat="serve"):
-                    state, toks, valid = engine.decode(params, state,
-                                                       cur_k)
-                toks, valid = fenced(toks, valid)
         if virtual is not None:
             dt = virtual.decode_s + stall_s
             virtual.clock.advance(dt)
@@ -625,20 +608,19 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         emit = tracer.begin("emit", cat="serve")
         dispatches += 1
         active_peak = max(active_peak, len(occupied))
-        if paged:
-            pages_peak = max(pages_peak, alloc.pages_used())
-            window_peak = max(window_peak, alloc.window_tokens_used())
-            if tracer.enabled:
-                # KV-pool occupancy sample, one per dispatch: becomes
-                # the ph="C" counter track in pod_trace.json so cache
-                # pressure sits on the same timeline as the request
-                # spans causing it. Guarded: the refcount walk (and
-                # the clock read inside instant) must cost nothing
-                # when tracing is off
-                tracer.instant("kv_pages", cat="serve_counter",
-                               used=alloc.pages_used(),
-                               total=engine.spec.pages,
-                               shared_refs=shared_refs())
+        pages_peak = max(pages_peak, alloc.pages_used())
+        window_peak = max(window_peak, alloc.window_tokens_used())
+        if tracer.enabled:
+            # KV-pool occupancy sample, one per dispatch: becomes
+            # the ph="C" counter track in pod_trace.json so cache
+            # pressure sits on the same timeline as the request
+            # spans causing it. Guarded: the refcount walk (and
+            # the clock read inside instant) must cost nothing
+            # when tracing is off
+            tracer.instant("kv_pages", cat="serve_counter",
+                           used=alloc.pages_used(),
+                           total=engine.spec.pages,
+                           shared_refs=shared_refs())
         if spec_on:
             # a verify dispatch emits a VARIABLE token count per slot:
             # ITL attributes the dispatch wall over each slot's own
@@ -719,17 +701,6 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                     metrics.flush()
         if metrics is not None:
             wall = now()
-            extra: Dict[str, Any] = {}
-            if paged:
-                # the PAGED footprint — what is actually allocated
-                # (pool + table), not the dense slots×max_seq formula
-                extra = {"kv_pages_used": alloc.pages_used(),
-                         "kv_pages_total": engine.spec.pages,
-                         "kv_cache_bytes": engine.spec.bytes,
-                         "kv_shared_refs": shared_refs(),
-                         "spec_accept_rate": (
-                             round(accepted / drafted, 4)
-                             if drafted else None)}
             metrics.log(kind="serve_tick", t_s=round(wall, 4),
                         queue_depth=len(waiting),
                         active_slots=sum(s is not None for s in slots),
@@ -750,7 +721,14 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
                         # raw samples never leave the serving host
                         ttft_hist=stats.ttft_hist(),
                         itl_hist=stats.itl_hist(),
-                        **extra)
+                        # the pool's footprint: what is actually
+                        # allocated (pool + table + rings)
+                        kv_pages_used=alloc.pages_used(),
+                        kv_pages_total=engine.spec.pages,
+                        kv_cache_bytes=engine.spec.bytes,
+                        kv_shared_refs=shared_refs(),
+                        spec_accept_rate=(round(accepted / drafted, 4)
+                                          if drafted else None))
         tracer.end(tick)
 
     wall_s = now()
@@ -769,7 +747,6 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         "generated_tokens": generated, "truncated": truncated,
         "wall_s": round(wall_s, 4), "dispatches": dispatches,
         "slots": engine.slots, "decode_k": engine.decode_k,
-        "kv_layout": engine.layout,
         "tokens_per_sec": round(tps, 3) if tps is not None else None,
         "tokens_per_sec_per_chip": (round(tps_chip, 3)
                                     if tps_chip is not None else None),
@@ -799,16 +776,15 @@ def run_serve(engine: ServeEngine, params, requests: List[Request], *,
         "alert_events": alerts.events,
         "prefill_compiles": engine.compile_counts()[0],
         "decode_compiles": engine.compile_counts()[1],
-        "verify_compiles": len(getattr(engine, "verify_traces", [])),
+        "verify_compiles": len(engine.verify_traces),
         "active_slots_peak": active_peak,
-        "kv_page_tokens": (engine.spec.page_tokens if paged else 0),
-        "kv_pages_total": (engine.spec.pages if paged else 0),
+        "kv_page_tokens": engine.spec.page_tokens,
+        "kv_pages_total": engine.spec.pages,
         "kv_pages_used_peak": pages_peak,
         # the other kind of state (a model with window layers): tokens
         # held in the per-slot rings, of slots x ring_tokens
         "kv_window_tokens_total": (engine.spec.slots
-                                   * engine.spec.ring_tokens
-                                   if paged else 0),
+                                   * engine.spec.ring_tokens),
         "kv_window_tokens_peak": window_peak,
         # means over the decode dispatches (None: the model counts none)
         **{name + "_mean": (round(v / dispatches, 4)

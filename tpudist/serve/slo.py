@@ -165,11 +165,9 @@ def serve_status(ttft_p99_s: Optional[float], itl_p99_s: Optional[float],
 
 
 def slo_block(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The BENCH_SERVE.json ``slo`` block from a ``run_serve`` summary —
-    ONE producer shared by the serve CLI and ``bench.py --serve-sweep``
-    so the two artifact writers cannot drift (same reason
-    ``write_collectives_artifact`` exists). Thresholds resolve through
-    the rules table at call time, like every other gate."""
+    """The ``slo`` block of the serve CLI's ``--bench-out`` document,
+    from a ``run_serve`` summary. Thresholds resolve through the rules
+    table at call time, like every other gate."""
     return {
         "status": summary["status"],
         **{f"{rule}_status": summary[f"{rule}_status"]

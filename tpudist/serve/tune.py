@@ -1,9 +1,9 @@
 """Measured-probe autotuner for the serving engine's knobs.
 
 The PR-4 train tuner generalised: the decode superstep length
-(``decode_k`` — tokens per dispatch per slot) and the KV cache's
-physical storage layout (``st`` | ``hs``, :mod:`tpudist.serve.kvcache`)
-both move decode throughput, and the right answer depends on the model
+(``decode_k`` — tokens per dispatch per slot), the KV pool's page
+length (:mod:`tpudist.serve.kvcache`) and the speculation window all
+move decode throughput, and the right answer depends on the model
 shape, mesh and device kind — exactly the situation the train tuner
 replaced static heuristics with measurement for. This module reuses that
 machinery wholesale: the same persisted fingerprint-keyed JSON cache
@@ -29,7 +29,6 @@ import time
 from typing import Any, Dict, List, Optional
 
 from tpudist import verdict as verdict_lib
-from tpudist.parallel.sharding import KV_CACHE_LAYOUTS
 from tpudist.tune import cache as cache_mod
 from tpudist.tune import search as search_mod
 
@@ -37,10 +36,8 @@ from tpudist.tune import search as search_mod
 # knee is what matters, not every integer. Capped where per-dispatch
 # latency starts to dominate ITL attribution (slo: ITL = wall / k).
 DECODE_K_LADDER = (1, 2, 4, 8, 16, 32)
-# Paged-axis ladders (serve mode only): page sizes worth probing (0 —
-# the dense arena — is always the walk's committed fallback) and verify
-# window widths (window includes the pending last token, so 2 is the
-# smallest real speculation).
+# Page sizes worth probing and verify window widths (window includes the
+# pending last token, so 2 is the smallest real speculation).
 KV_PAGE_TOKENS_LADDER = (8, 16, 32)
 SPECULATE_K_LADDER = (2, 4, 8)
 
@@ -51,14 +48,12 @@ DEFAULT_TRIALS = 8
 
 @dataclasses.dataclass(frozen=True)
 class ServeCandidate:
-    """One point in the serve knob space. ``kv_page_tokens = 0`` is the
-    dense arena; > 0 selects the paged engine at that page size.
-    ``speculate_k = 0`` is plain decode; >= 2 is the draft+verify
-    window (meaningful only with paging — the walk gates it so)."""
+    """One point in the serve knob space. ``kv_page_tokens`` is the
+    pool's page length (the engine's default); ``speculate_k = 0`` is
+    plain decode, >= 2 the draft+verify window."""
 
     decode_k: int = 8
-    layout: str = "st"
-    kv_page_tokens: int = 0
+    kv_page_tokens: int = 8
     speculate_k: int = 0
 
     def replace(self, **kw) -> "ServeCandidate":
@@ -70,19 +65,15 @@ class ServeCandidate:
 
 def validate_serve_tuned(tuned: Dict[str, Any]) -> bool:
     """Knob sanity for a cached serve record (the ``validate`` hook of
-    :func:`tpudist.tune.cache.load`): an insane decode_k, unknown
-    layout, or a pre-paging record missing the paged knobs is a cache
-    MISS (re-probe), never a crash in the engine."""
-    if "kv_page_tokens" not in tuned or "speculate_k" not in tuned:
-        return False              # pre-paging schema: re-probe
+    :func:`tpudist.tune.cache.load`): an insane decode_k, or a record
+    of another knob schema (no paged knobs yet, or a storage ``layout``
+    still) is a cache MISS (re-probe), never a crash in the engine."""
+    if set(tuned) != {"decode_k", "kv_page_tokens", "speculate_k"}:
+        return False              # another schema: re-probe
     if int(tuned["decode_k"]) < 1:
         return False
     pt, sk = int(tuned["kv_page_tokens"]), int(tuned["speculate_k"])
-    if pt < 0 or sk < 0 or sk == 1:
-        return False
-    if sk >= 2 and pt == 0:
-        return False              # speculation needs the paged engine
-    return tuned["layout"] in KV_CACHE_LAYOUTS
+    return pt >= 1 and (sk == 0 or sk >= 2)
 
 
 def fingerprint(model_cfg, mesh, *, slots: int, max_seq: int,
@@ -147,31 +138,25 @@ def probe_candidate(model_cfg, mesh, params, cand: ServeCandidate, *,
     """Measure one candidate: build its engine, prefill every slot, time
     ``repeats`` runs of ``n_dispatches`` decode supersteps at full
     occupancy. Estimator over repeats is the MIN elapsed (one-sided host
-    noise, same reasoning as tune.probe). A paged candidate probes the
-    paged engine (default full-capacity pool: the probe measures the
-    program, not an artificial page famine); a speculative one times
+    noise, same reasoning as tune.probe). The pool is the default
+    full-capacity one (the probe measures the program, not an
+    artificial page famine); a speculative candidate times
     draft+verify dispatches and counts the tokens the verifies actually
     emitted — fenced ``lengths`` deltas, not ``k × dispatches``, since
     acceptance is workload-dependent and crediting rejected drafts
     would let speculation look free. Never raises — any failure (OOM,
-    bad layout lowering) is a pruned ``feasible=False`` result."""
+    a page longer than the sequence) is a pruned ``feasible=False``
+    result."""
     import jax
     import numpy as np
 
-    from tpudist.serve.engine import PagedServeEngine, ServeEngine
+    from tpudist.serve.engine import PagedServeEngine
     try:
-        paged = cand.kv_page_tokens > 0
-        spec_k = cand.speculate_k if paged else 0
-        if paged:
-            engine = PagedServeEngine(
-                model_cfg, mesh, slots=slots, max_seq=max_seq,
-                prompt_pad=prompt_pad, decode_k=cand.decode_k,
-                page_tokens=cand.kv_page_tokens, speculate_k=spec_k)
-        else:
-            engine = ServeEngine(model_cfg, mesh, slots=slots,
-                                 max_seq=max_seq, prompt_pad=prompt_pad,
-                                 decode_k=cand.decode_k,
-                                 layout=cand.layout)
+        spec_k = cand.speculate_k
+        engine = PagedServeEngine(
+            model_cfg, mesh, slots=slots, max_seq=max_seq,
+            prompt_pad=prompt_pad, decode_k=cand.decode_k,
+            page_tokens=cand.kv_page_tokens, speculate_k=spec_k)
         # per-slot decode budget must cover every timed dispatch so the
         # whole probe runs at full occupancy (an emptying batch would
         # flatter small decode_k); shrink the dispatch count if the
@@ -185,22 +170,19 @@ def probe_candidate(model_cfg, mesh, params, cand: ServeCandidate, *,
 
         def fill() -> Any:
             state = engine.init_state()
-            if paged:
-                engine.new_allocator()
+            engine.new_allocator()
             outs = []
             for s in range(slots):
-                if paged:
-                    engine.alloc.admit(s, prompt_pad)  # full-capacity
-                    # pool: cannot fail at probe shapes
+                engine.alloc.admit(s, prompt_pad)  # full-capacity
+                # pool: cannot fail at probe shapes
                 state, first = engine.prefill(
                     params, state, prompt[None, :], prompt_pad, s,
                     budget)
                 outs.append([int(x) for x in prompt] + [int(first)])
-            if paged:
-                # map every page up front: the probe times dispatch
-                # compute, not incremental host allocation
-                for s in range(slots):
-                    engine.alloc.ensure(s, max_seq - 1)
+            # map every page up front: the probe times dispatch
+            # compute, not incremental host allocation
+            for s in range(slots):
+                engine.alloc.ensure(s, max_seq - 1)
             return state, outs
 
         def dispatch(state, outs):
@@ -272,13 +254,12 @@ def _search(measure, start: ServeCandidate, *, max_decode_k: int,
     """Deterministic axis walk sharing the train search's discipline:
     decode_k first (ordered ascent, regress early-stop,
     plateau-prefers-smallest within PLATEAU_TOL — shorter supersteps
-    mean honester ITL at indistinguishable throughput), then layout at
-    the committed decode_k (best wins; ties keep the start's layout),
-    then the paged axes: ``kv_page_tokens`` (a real win over the
-    committed point switches storage discipline; a tie keeps it — the
-    dense arena is the simpler program) and, only at a committed page
-    size, ``speculate_k`` (same real-win bar: acceptance-rate-dependent
-    speedups must MEASURE, never be assumed). The committed point NEVER
+    mean honester ITL at indistinguishable throughput), then the paged
+    axes at the committed decode_k: ``kv_page_tokens`` (a real win over
+    the committed point switches page size; a tie keeps it) and, at the
+    committed page size, ``speculate_k`` (same real-win bar:
+    acceptance-rate-dependent speedups must MEASURE, never be
+    assumed). The committed point NEVER
     measures slower than the start."""
     memo: Dict[ServeCandidate, ServeProbeResult] = {}
     out = {"best": start, "best_tps": 0.0, "baseline_tps": 0.0,
@@ -330,17 +311,6 @@ def _search(measure, start: ServeCandidate, *, max_decode_k: int,
                 out["best_tps"] = tps
                 break
 
-    for layout in KV_CACHE_LAYOUTS:
-        if layout == out["best"].layout:
-            continue
-        res = run(out["best"].replace(layout=layout))
-        if res is None or not res.feasible:
-            continue
-        if res.tokens_per_sec > out["best_tps"] * (
-                1 + search_mod.PLATEAU_TOL):
-            out["best"] = out["best"].replace(layout=layout)
-            out["best_tps"] = res.tokens_per_sec
-
     # ---- paged axes (serve-mode coordinates, PR 16) ----
     if max_page_tokens > 0:
         for pt in KV_PAGE_TOKENS_LADDER:
@@ -357,17 +327,16 @@ def _search(measure, start: ServeCandidate, *, max_decode_k: int,
                 out["best"] = out["best"].replace(kv_page_tokens=pt,
                                                   speculate_k=0)
                 out["best_tps"] = res.tokens_per_sec
-        if out["best"].kv_page_tokens > 0:
-            for sk in SPECULATE_K_LADDER:
-                if sk == out["best"].speculate_k:
-                    continue
-                res = run(out["best"].replace(speculate_k=sk))
-                if res is None or not res.feasible:
-                    continue
-                if res.tokens_per_sec > out["best_tps"] * (
-                        1 + search_mod.PLATEAU_TOL):
-                    out["best"] = out["best"].replace(speculate_k=sk)
-                    out["best_tps"] = res.tokens_per_sec
+        for sk in SPECULATE_K_LADDER:
+            if sk == out["best"].speculate_k:
+                continue
+            res = run(out["best"].replace(speculate_k=sk))
+            if res is None or not res.feasible:
+                continue
+            if res.tokens_per_sec > out["best_tps"] * (
+                    1 + search_mod.PLATEAU_TOL):
+                out["best"] = out["best"].replace(speculate_k=sk)
+                out["best_tps"] = res.tokens_per_sec
 
     # the hard floor: never commit a point slower than the measured start
     if out["best"] != start and out["best_tps"] < out["baseline_tps"]:
@@ -403,7 +372,6 @@ def autotune_serve(model_cfg, mesh, params, *, slots: int, max_seq: int,
     if rec is not None:
         t = rec["tuned"]
         tuned = ServeCandidate(decode_k=int(t["decode_k"]),
-                               layout=t["layout"],
                                kv_page_tokens=int(t["kv_page_tokens"]),
                                speculate_k=int(t["speculate_k"]))
         if tuned.decode_k <= max_seq - prompt_pad:
@@ -466,7 +434,7 @@ def _log(out: ServeTuneOutcome, metrics: Any) -> ServeTuneOutcome:
         metrics.log(kind="serve_tune", status=out.status,
                     source=out.source, trials=out.trials,
                     pruned=out.pruned, fingerprint=out.fingerprint,
-                    decode_k=out.tuned.decode_k, layout=out.tuned.layout,
+                    decode_k=out.tuned.decode_k,
                     kv_page_tokens=out.tuned.kv_page_tokens,
                     speculate_k=out.tuned.speculate_k,
                     tokens_per_sec=out.tokens_per_sec,

@@ -1,11 +1,12 @@
 """tpudist.tune — the measured-probe autotuner.
 
 `config.resolve_steps_per_dispatch` and `resolve_staging_budget_bytes`
-GUESS the dispatch/staging operating point by static heuristic, and
-BENCH_DISPATCH.json shows an order-of-magnitude steps/s spread (~9-12x
-across rounds) between the best and worst guess on the same hardware. This package replaces the guess with a
-measurement: short on-device trials of the *real* compiled superstep
-(:mod:`probe`) over a bounded knob space — superstep length ``k``,
+GUESS the dispatch/staging operating point by static heuristic, and a
+CPU sweep of the superstep length on a toy preset once showed an
+order-of-magnitude steps/s spread between the best and worst guess (on
+the chip the spread is not measured: ROADMAP C3). This package replaces
+the guess with a measurement: short on-device trials of the *real*
+compiled superstep (:mod:`probe`) over a bounded knob space — superstep length ``k``,
 staging budget, ``remat``, ``grad_accum_steps`` — walked by a
 deterministic coordinate search (:mod:`search`) and persisted in a
 fingerprint-keyed JSON cache (:mod:`cache`) so the SECOND run of the

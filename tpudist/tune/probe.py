@@ -9,10 +9,8 @@ completes with a number or reports ``feasible=False`` (OOM, a staging
 budget that cannot double-buffer, watermark past the device limit) — an
 infeasible point is a *result* the search prunes, never a crash.
 
-:class:`EpochRunner` is the compile-once/run-many harness itself, shared
-with ``bench.py``'s sweeps (``--dispatch-sweep``/``--staging-sweep``
-previously hand-rolled the same compile/warmup/time-n-steps loop twice);
-the streaming path mirrors ``train._superstep_epoch`` — double-buffered
+:class:`EpochRunner` is the compile-once/run-many harness itself; the
+streaming path mirrors ``train._superstep_epoch`` — double-buffered
 slabs, slab-boundary fences, one compiled superstep for the whole epoch,
 padded tail included.
 """

@@ -36,9 +36,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from tpudist.config import SUPERSTEP_CAP, TrainConfig
 
-# Axis walk order: the k axis carries the order-of-magnitude spread
-# (BENCH_DISPATCH), so it is searched first and every later axis rides
-# the committed k. The overlap-plane knobs (grad bucket bytes, pipeline
+# Axis walk order: the k axis carried the widest spread in the CPU
+# sweeps this walk was written against, so it is searched first and
+# every later axis rides the committed k. The overlap-plane knobs (grad bucket bytes, pipeline
 # virtual stages) sit between the dispatch knobs and the math knobs:
 # both are pure SCHEDULE coordinates — bitwise-identical loss at every
 # value (parallel.overlap / parallel.pipeline pin this) — so they never
