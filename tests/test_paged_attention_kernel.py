@@ -1,0 +1,212 @@
+"""The page-table-driven decode kernel (``ops/pallas/paged_attention.py``)
+under the Pallas TPU interpreter, held to the masked read it stands in for
+on the TPU (``transformer._masked_pool_read``, the CPU path): same inputs,
+same answers, at tiny sizes. The interpreter raises on a copy out of
+bounds and hands the kernel NaN for memory it never wrote, so "reads only
+what the slot owns" is checked, not assumed."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+
+from tpudist.models import transformer as T
+from tpudist.ops.pallas import paged_attention as pa
+
+L, PAGES, PT, HD, MAXP = 3, 20, 4, 8, 5
+TRASH = PAGES
+
+
+def _pool(kv, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    shape = (L, kv, PAGES + 1, PT, HD)
+    return (jnp.asarray(rng.normal(size=shape), dtype),
+            jnp.asarray(rng.normal(size=shape), dtype))
+
+
+def _table(first_pos, w, perm=None):
+    """A row a slot: pages for positions ``0 .. first + w - 1`` (the
+    window's writes have landed), ``None`` a slot with nothing mapped."""
+    perm = list(range(PAGES)) if perm is None else list(perm)
+    table = np.full((len(first_pos), MAXP), -1, np.int32)
+    pos = np.zeros((len(first_pos), w), np.int32)
+    for s, p in enumerate(first_pos):
+        if p is None:
+            continue
+        pos[s] = p + np.arange(w)
+        n = -(-(p + w) // PT)
+        table[s, :n] = [perm.pop() for _ in range(n)]
+    return table, pos
+
+
+def _both(q, pool_k, pool_v, layer, table, pos, block_pages=None):
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    layer = jnp.int32(layer)
+    ref = T._masked_pool_read(q, pool_k, pool_v, layer, table, pos, PT)
+    out = pa.paged_attention(
+        q, pool_k, pool_v, layer, pa.walk(table, pos, PT, PAGES + 1),
+        block_pages=block_pages, interpret=pltpu.InterpretParams())
+    return np.asarray(out, np.float32), np.asarray(ref, np.float32)
+
+
+def _q(slots, w, h, dtype, seed=1):
+    return jnp.asarray(
+        np.random.default_rng(seed).normal(size=(slots, w, h, HD)), dtype)
+
+
+# (id, window, heads, kv heads, first query position a slot (None:
+# nothing mapped), layer, pages a block (None: the kernel's choice, here a
+# whole row), dtype)
+CASES = [
+    ("w1-group1", 1, 2, 2, [6, 13, 0, 17], 1, None, jnp.float32),
+    ("w1-group2", 1, 4, 2, [6, 13, 0, 17], 1, None, jnp.float32),
+    ("w1-group4", 1, 4, 1, [6, 13, 0, 17], 1, None, jnp.float32),
+    ("w3-group1", 3, 2, 2, [6, 11, 0, 15], 1, None, jnp.float32),
+    ("w3-group2", 3, 4, 2, [6, 11, 0, 15], 1, None, jnp.float32),
+    ("w3-group4", 3, 4, 1, [6, 11, 0, 15], 1, None, jnp.float32),
+    # the query sits on a page's last row, on the next page's first, on
+    # its second: k * page_tokens - 1, k * page_tokens, k * page_tokens + 1
+    ("page-edges", 1, 4, 2, [PT - 1, PT, PT + 1, 3 * PT - 1, 3 * PT,
+                             3 * PT + 1], 1, None, jnp.float32),
+    ("page-edges-w3", 3, 4, 2, [PT - 1, PT, PT + 1, 2 * PT - 3], 1, None,
+     jnp.float32),
+    # several blocks a slot: the online softmax across blocks, a block
+    # cut short by the row's end, the next slot's first block started
+    # behind a slot's last
+    ("blocks-of-2", 1, 4, 2, [6, 13, 0, 19], 1, 2, jnp.float32),
+    ("blocks-of-1-w3", 3, 4, 2, [6, 11, 0, 17], 1, 1, jnp.float32),
+    ("blocks-of-3", 1, 4, 2, [19, None, 12, 1], 1, 3, jnp.float32),
+    ("layer-first", 1, 4, 2, [6, 13, 0, 17], 0, 2, jnp.float32),
+    ("layer-last", 1, 4, 2, [6, 13, 0, 17], L - 1, 2, jnp.float32),
+    ("bfloat16", 1, 4, 2, [6, 13, 0, 17], 1, 2, jnp.bfloat16),
+    ("bfloat16-w3", 3, 4, 2, [6, 11, 0, 15], 2, None, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_kernel_matches_masked_read(case):
+    _, w, h, kv, first_pos, layer, block_pages, dtype = case
+    pool_k, pool_v = _pool(kv, dtype)
+    perm = np.random.default_rng(2).permutation(PAGES)
+    table, pos = _table(first_pos, w, perm)
+    out, ref = _both(_q(len(first_pos), w, h, dtype), pool_k, pool_v, layer,
+                     table, pos, block_pages)
+    live = [s for s, p in enumerate(first_pos) if p is not None]
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    assert np.isfinite(out).all()
+    for s, p in enumerate(first_pos):
+        if p is None:       # nothing mapped: skipped, reads 0
+            assert not out[s].any()
+
+
+@pytest.mark.parametrize("row", ["unmapped", "stale-beyond-a-gap",
+                                 "position-past-the-row"])
+def test_discarded_slots_are_finite_and_in_bounds(row):
+    """Slots the engine discards (inactive, outside the dispatch) keep
+    whatever position and row they had: the kernel must stay in bounds
+    (the interpreter raises otherwise) and finite, and the live slots
+    beside them must not notice."""
+    pool_k, pool_v = _pool(2, jnp.float32)
+    table, pos = _table([6, 9, 13], 1)
+    if row == "unmapped":
+        table[1] = -1
+    elif row == "stale-beyond-a-gap":
+        table[1] = [table[1, 0], -1, 3, 3, -1]
+        pos[1] = 18
+    else:
+        pos[1] = 10_000
+    q = _q(3, 1, 4, jnp.float32)
+    out, ref = _both(q, pool_k, pool_v, 1, table, pos, 2)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[[0, 2]], ref[[0, 2]], atol=1e-5)
+    if row == "unmapped":
+        assert not out[1].any()
+
+
+def test_shared_prefix_page_named_by_two_rows():
+    pool_k, pool_v = _pool(2, jnp.float32)
+    table, pos = _table([9, 10, 5], 1)
+    table[1, :2] = table[0, :2]             # two pages of shared prefix
+    out, ref = _both(_q(3, 1, 4, jnp.float32), pool_k, pool_v, 1, table,
+                     pos, 2)
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("read", ["kernel", "masked-read"])
+def test_pages_no_live_row_names_never_reach_the_answer(read):
+    """The page-bounded property: every page that no live row names up to
+    its position, the trash page included, poisoned in every layer. The
+    kernel never copies them, so NaN keys AND NaN values leave its answer
+    the clean pool's bit for bit. The masked read scores them all and
+    masks: a NaN key is dropped by the select, but a value meets a
+    probability of exactly 0 and 0 x NaN is NaN, so its values are
+    poisoned with the largest finite number instead."""
+    pool_k, pool_v = _pool(2, jnp.float32)
+    table, pos = _table([6, None, 13, 2], 3,
+                        np.random.default_rng(3).permutation(PAGES))
+    q = _q(4, 3, 4, jnp.float32)
+    named = {int(p) for p in table.ravel() if p >= 0}
+    dead = np.array([p for p in range(PAGES + 1) if p not in named])
+    assert TRASH in dead and len(dead) > 3
+    bad_v = jnp.nan if read == "kernel" else jnp.finfo(jnp.float32).max
+    bad_k = pool_k.at[:, :, dead].set(jnp.nan)
+    bad_v = pool_v.at[:, :, dead].set(bad_v)
+    clean_out, clean_ref = _both(q, pool_k, pool_v, 1, table, pos, 2)
+    out, ref = _both(q, bad_k, bad_v, 1, table, pos, 2)
+    if read == "kernel":
+        np.testing.assert_array_equal(out, clean_out)
+    else:
+        live = [0, 2, 3]    # a slot with nothing owned averages the pool
+        np.testing.assert_array_equal(ref[live], clean_ref[live])
+        np.testing.assert_array_equal(out, clean_out)
+
+
+def test_walk_counts_pages_and_links_live_slots():
+    table = np.array([[4, 2, -1, -1], [-1, -1, -1, -1], [1, -1, 3, -1],
+                      [0, 5, 6, 7]], np.int32)
+    pos = np.array([[5, 6], [0, 1], [9, 10], [14, 15]], np.int32)
+    n_pages, nxt, flat, flat_pos = pa.walk(jnp.asarray(table),
+                                           jnp.asarray(pos), PT, 9)
+    # slot 0: positions up to 6 -> 2 pages; slot 1: nothing mapped;
+    # slot 2: wants 3, mapped without a gap 1; slot 3: all four
+    assert n_pages.tolist() == [2, 0, 1, 4]
+    assert nxt.tolist() == [0, 2, 2, 3, 4]
+    assert flat.shape == (16,) and int(flat.min()) == 0
+    assert flat_pos.tolist() == pos.ravel().tolist()
+
+
+def test_supports_is_by_shape():
+    pool = (24, 8, 257, 64, 128)
+    assert pa.supports((16, 1, 16, 128), pool, jnp.bfloat16, 64)
+    assert pa.supports((16, 5, 16, 128), pool, jnp.bfloat16, 64)
+    assert pa.supports((16, 1, 16, 128), pool, jnp.float32, 8)
+    # lanes not full; a page that is no whole tile of the dtype; query
+    # rows that are no whole tile
+    assert not pa.supports((16, 1, 16, 64), (24, 8, 257, 64, 64),
+                           jnp.bfloat16, 64)
+    assert not pa.supports((16, 1, 16, 128), pool, jnp.bfloat16, 8)
+    assert not pa.supports((16, 1, 4, 128), (24, 2, 257, 64, 128),
+                           jnp.bfloat16, 64)
+    assert pa.pages_per_block(pool, jnp.bfloat16, 20) == 8
+    assert pa.pages_per_block(pool, jnp.bfloat16, 3) == 3
+
+
+def test_off_the_tpu_paged_attention_takes_the_masked_read():
+    """Routing is by backend and shape: here on the CPU the decode
+    programs hold no Mosaic call, at the very shapes the kernel supports,
+    so the serve tests' programs are the ones they were."""
+    s, w, h, kv, hd, pt = 2, 1, 8, 2, 128, 16
+    assert pa.supports((s, w, h, hd), (2, kv, 5, pt, hd), jnp.bfloat16, pt)
+    assert not T._use_paged_kernel((s, w, h, hd), (2, kv, 5, pt, hd),
+                                   jnp.bfloat16, pt)
+    sds = jax.ShapeDtypeStruct
+    pool = sds((2, kv, 5, pt, hd), jnp.bfloat16)
+    text = jax.jit(T._paged_attention, static_argnums=(9,)).lower(
+        sds((s, w, h, hd), jnp.bfloat16), sds((s, w, kv, hd), jnp.bfloat16),
+        sds((s, w, kv, hd), jnp.bfloat16), pool, pool, sds((), jnp.int32),
+        sds((s, 3), jnp.int32), sds((s, w), jnp.int32), sds((s, w), bool),
+        pt).as_text()
+    assert "tpu_custom_call" not in text and "custom_call" not in text
+    assert "dynamic_slice" in text      # the layer's whole page set

@@ -710,3 +710,63 @@ def test_paged_serve_cli_e2e_4dev_mesh(tmp_path):
     assert len(serves) == 1
     assert serves[0]["verify_compiles"] == 1
     assert serves[0]["kv_pages_total"] > 0
+
+
+# ------------------------------------------------------------------ #
+# what a page-bounded read spares: pages mapped a dispatch (PR 31)    #
+# ------------------------------------------------------------------ #
+
+@pytest.mark.parametrize("model_name,spec_k", [("transformer", 0),
+                                               ("moe", 0),
+                                               ("transformer", 3)])
+def test_every_dispatch_span_carries_kv_full_pages(devices8, model_name,
+                                                   spec_k):
+    """Every ``decode_step`` (and ``verify_step``) span, for every model,
+    notes the pages the slots' rows map; the summary carries their mean
+    over the dispatches beside the peak."""
+    from tpudist.obs import trace as trace_lib
+    cfg = CFGS[model_name]
+    mesh = build_mesh(ParallelConfig(), devices=devices8[:1])
+    params = init_params(cfg, mesh, seed=0)
+    engine = PagedServeEngine(cfg, mesh, slots=2, max_seq=32, prompt_pad=8,
+                              decode_k=4, page_tokens=4, pages=12,
+                              speculate_k=spec_k)
+    engine.warmup(params)
+    reqs = sched.make_requests(4, prompt_pad=8, vocab_size=cfg.vocab_size,
+                               max_new=9, rate=0.0, seed=3)
+    tracer = trace_lib.configure(enabled=True)
+    try:
+        summary = sched.run_serve(engine, params, reqs)
+        spans = tracer.events()
+    finally:
+        trace_lib.configure(enabled=False)
+    name = "verify_step" if spec_k else "decode_step"
+    steps = [s["args"] for s in spans if s["name"] == name]
+    assert steps and len(steps) == summary["dispatches"]
+    pages = [a["kv_full_pages"] for a in steps]
+    # a live slot holds at least its prompt's pages and never the pool
+    assert all(1 <= p <= 12 for p in pages), pages
+    assert summary["kv_pages_used_peak"] == max(pages)
+    assert summary["kv_pages_used_mean"] == round(
+        sum(pages) / len(pages), 2)
+    assert summary["kv_pages_total"] == 12
+
+
+def test_report_prints_pages_read_over_pool_pages():
+    from tpudist.obs import report
+    recs = [dict(kind="serve", requests=1, completed=1,
+                 generated_tokens=8, wall_s=0.05,
+                 tokens_per_sec_per_chip=40.0, status="success",
+                 kv_pages_used_peak=61, kv_pages_used_mean=43.25,
+                 kv_pages_total=256, kv_page_tokens=64,
+                 ttft_p50_s=0.005, ttft_p99_s=0.005,
+                 itl_p50_s=0.005, itl_p99_s=0.005)]
+    rep = report.build_report(recs, {})
+    assert rep["serving"]["kv_pages_used_mean"] == 43.25
+    text = report.to_markdown(rep)
+    assert "- kv pages read a dispatch: 43.25 of 256 pool pages" in text
+    assert "peak 61" in text
+    # a run record from before the counter: no line, no error
+    del recs[0]["kv_pages_used_mean"]
+    assert "kv pages read a dispatch" not in report.to_markdown(
+        report.build_report(recs, {}))

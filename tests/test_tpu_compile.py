@@ -1,0 +1,78 @@
+"""Kernels of the serve path compiled at their real widths by the chip's
+own compiler, for a DESCRIBED v5e and no chip time: what the Pallas
+interpreter cannot refuse (a slice off the tiling, too much fast memory, a
+copy of the pool in front of the call) is refused here. Compiled, not run:
+nothing in this file is a time or a result.
+
+The topology is described inside a fixture, never at import (one process
+at a time may load the TPU's library; a worker that cannot skips these
+tests, it does not lose the others), and all such tests live in this one
+file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+# the `internlm2` serve cells' decode shapes: 16 slots, 16 heads over 8 kv
+# heads x 128, a 24-layer pool of 256 + 1 pages of 64, rows of 20 pages
+@pytest.mark.parametrize("window,dtype", [(1, jnp.bfloat16),
+                                          (3, jnp.bfloat16),
+                                          (1, jnp.float32)])
+def test_paged_attention_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, window, dtype):
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, kv, hd, layers, n_pool, pt, maxp = 16, 16, 8, 128, 24, 257, 64, 20
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    pool = sds((layers, kv, n_pool, pt, hd), dtype)
+    assert pa.supports((slots, window, h, hd), pool.shape, dtype, pt)
+
+    def read(q, pool_k, pool_v, layer, table, pos):
+        return pa.paged_attention(q, pool_k, pool_v, layer,
+                                  pa.walk(table, pos, pt, n_pool))
+
+    # an ambient "highest" (a test file of the same worker sets it; a user
+    # may) must not reach the bfloat16 matmuls, which Mosaic would refuse
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(read).lower(
+            sds((slots, window, h, hd), dtype), pool, pool,
+            sds((), jnp.int32),
+            sds((slots, maxp), jnp.int32), sds((slots, window), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attn_decode" in text
+    # the pool goes to the kernel where it lies: no staged layer, no copy
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

@@ -317,3 +317,103 @@ def test_golden_bf16_flagship_two_step_losses_on_chip():
         losses.append(float(loss))
     GOLDEN = (10.9293, 7.9324)
     np.testing.assert_allclose(losses, GOLDEN, rtol=5e-3)
+
+
+# ------------------------------------------------------------------ #
+# the serve path's decode kernel (ops/pallas/paged_attention.py)      #
+# ------------------------------------------------------------------ #
+
+def _serve_cell_case(window, seed=0):
+    """The `internlm2` serve cells' own shapes: 16 slots, 256 + 1 pages of
+    64, 16 heads over 8 kv heads x 128, a 24-layer bfloat16 pool; rows
+    ragged from one token to the whole 1280, one slot unmapped, one with
+    a stale entry behind its pages. ``first`` is a slot's first query
+    position (its length before the window)."""
+    slots, h, kv, hd, layers, pages, pt, maxp = 16, 16, 8, 128, 24, 256, 64, 20
+    rng = np.random.default_rng(seed)
+    kk, kq = jax.random.split(jax.random.PRNGKey(seed))
+    shape = (layers, kv, pages + 1, pt, hd)
+    pool_k = jax.random.normal(kk, shape, jnp.bfloat16)
+    pool_v = jax.random.normal(jax.random.fold_in(kk, 1), shape,
+                               jnp.bfloat16)
+    q = jax.random.normal(kq, (slots, window, h, hd), jnp.bfloat16)
+    first = [0, 1, 63, 64, 65, 127, 128, 300, 511, 512, 513, 700,
+             1280 - window, None, 200, 320]
+    free = list(rng.permutation(pages))
+    table = np.full((slots, maxp), -1, np.int32)
+    pos = np.zeros((slots, window), np.int32)
+    for s, n in enumerate(first):
+        if n is None:
+            pos[s] = 900            # a finished slot: stale length, no row
+            continue
+        pos[s] = n + np.arange(window)
+        for j in range((n + window - 1) // pt + 1):
+            table[s, j] = free.pop()
+    table[14, 6] = free.pop()       # a stale entry past the slot's pages
+    return q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(pos), first
+
+
+@pytest.mark.parametrize("layer,window", [(0, 1), (23, 1), (23, 3)])
+def test_paged_attention_kernel_matches_masked_read_on_chip(layer, window):
+    """Mosaic-compiled, at the cell's shapes, against the XLA masked read
+    it replaces on the TPU (bfloat16 tolerance: the kernel keeps its
+    scores in float32, the read rounds them to bfloat16). Window 1 is the
+    decode step, window 3 a verify forward's."""
+    from tpudist.models import transformer as T
+    from tpudist.ops.pallas import paged_attention as pa
+    q, pool_k, pool_v, table, pos, first = _serve_cell_case(window)
+    pt = pool_k.shape[3]
+    assert T._use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt)
+
+    @jax.jit
+    def kernel(q, pk, pv, layer, table, pos):
+        return pa.paged_attention(q, pk, pv, layer,
+                                  pa.walk(table, pos, pt, pk.shape[2]))
+
+    ref = jax.jit(T._masked_pool_read, static_argnums=(6,))(
+        q, pool_k, pool_v, jnp.int32(layer), table, pos, pt)
+    out = kernel(q, pool_k, pool_v, jnp.int32(layer), table, pos)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    assert np.isfinite(out).all()
+    worst = {s: float(np.abs(out[s] - ref[s]).max())
+             for s, n in enumerate(first) if n is not None}
+    assert max(worst.values()) < 4e-2, worst
+    assert not out[13].any()        # nothing mapped: skipped
+
+
+def test_engine_greedy_tokens_match_masked_read_on_chip(monkeypatch):
+    """The whole serve lane over 64 decode steps a request, the kernel's
+    tokens against the masked read's (the parent's path), in float32 at
+    full matmul precision so that no near-tie decides: token for token."""
+    from tpudist.config import ModelConfig, ParallelConfig
+    from tpudist.models import transformer as T
+    from tpudist.parallel.mesh import build_mesh
+    from tpudist.serve import scheduler as sched
+    from tpudist.serve.engine import PagedServeEngine, init_params
+
+    cfg = ModelConfig(name="transformer", vocab_size=2048, n_layers=4,
+                      d_model=1024, n_heads=8, n_kv_heads=4, d_ff=2048,
+                      max_seq_len=512)
+    mesh = build_mesh(ParallelConfig(), devices=jax.devices()[:1])
+    params = init_params(cfg, mesh, seed=0)
+    reqs = sched.make_requests(6, prompt_pad=256, vocab_size=cfg.vocab_size,
+                               max_new=64, rate=0.0, seed=3, prompt_min=40)
+    outs, mosaic = {}, {}
+    with jax.default_matmul_precision("highest"):
+        for path in ("kernel", "masked_read"):
+            if path == "masked_read":
+                monkeypatch.setattr(T, "_use_paged_kernel",
+                                    lambda *a, **kw: False)
+            engine = PagedServeEngine(cfg, mesh, slots=4, max_seq=512,
+                                      prompt_pad=256, decode_k=8,
+                                      page_tokens=64, dtype=jnp.float32)
+            engine.warmup(params)
+            summary = sched.run_serve(engine, params, reqs)
+            assert summary["completed"] == len(reqs), summary["partition"]
+            outs[path] = {rid: r["tokens"]
+                          for rid, r in summary["results"].items()}
+            jitted, args = engine._programs["decode_k8"][:2]
+            mosaic[path] = "tpu_custom_call" in jitted.lower(*args).as_text()
+    assert mosaic == {"kernel": True, "masked_read": False}, mosaic
+    assert all(len(t) == 64 for t in outs["kernel"].values())
+    assert outs["kernel"] == outs["masked_read"]

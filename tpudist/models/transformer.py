@@ -366,65 +366,39 @@ def prefill_kv_hidden_states(params: Params, tokens: jax.Array,
     return rmsnorm(x, params["final_norm"]), {"k": ck, "v": cv}
 
 
-def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
-                     positions, write_ok, page_tokens: int):
-    """Windowed incremental attention against a PAGED KV pool,
-    gather-free on the read path.
+def _use_paged_kernel(q_shape, pool_shape, dtype, page_tokens: int) -> bool:
+    """Route the paged READ through the Pallas kernel? By what the code
+    can observe, after :func:`_use_flash`: on the TPU (the interpreter
+    would crawl on the CPU, where the masked read stays the path and the
+    kernel's reference), at shapes the kernel supports, and where the
+    ambient mesh leaves nothing for GSPMD to partition (a Mosaic call
+    cannot be; the pool of a multi-chip serve mesh keeps the masked
+    read)."""
+    if jax.default_backend() != "tpu":
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size > 1 and any(t == jax.sharding.AxisType.Auto
+                             for t in mesh.axis_types):
+        return False
+    from tpudist.ops.pallas import paged_attention as pa
+    return pa.supports(q_shape, pool_shape, dtype, page_tokens)
 
-    q: (slots, window, heads, head_dim); k_new/v_new: (slots, window,
-    kv, head_dim), ALREADY rotated at ``positions`` (slots, window);
-    pool_k/pool_v: (n_layers, kv, pages+1, page_tokens, head_dim) — the
-    WHOLE pool, last page of every layer the TRASH page; layer: int32
-    scalar, the layer this call writes and reads. page_table: (slots,
-    max_pages) int32, -1 = unmapped; write_ok: (slots, window) bool —
-    False routes the write to the trash page (inactive slots, positions
-    past capacity, shared-prefix positions another slot's registration
-    already wrote).
 
-    The pool is the layer loop's CARRY: written in place and handed
-    back whole, never sliced out per layer and restacked (which cost a
-    read and a write of the layer's page set, twice, every layer of
-    every token step). Its kv-head axis sits OUTSIDE the pages so that
-    one layer's slice is already the ``(kv, keys, head_dim)`` operand
-    the two attention matmuls batch over: with the heads inside a page
-    the compiler staged every layer's K and V through a second copy to
-    move them out.
-
-    WRITE: the only dynamic indexing is a tiny ``take_along_axis`` on
-    the int32 page table (slots × window entries) plus the scatter of
-    the new k/v, one ``head_dim`` row per token and kv head at
-    ``(layer, head, page, offset)``. READ: the layer's page set is one
-    dynamic index on the layer axis, and no gathers — ownership
-    is a one-hot compare of the page table against the pool's page ids
-    (the trash page id appears in no table, so it is masked out by
-    construction), each owned page's LOGICAL position comes from the
-    same one-hot, and attention runs over the layer's whole flattened
-    page set with ``owned & (key_pos <= query_pos)`` masking — stale
-    pages, other slots' pages and the trash page all mask to
-    exp(-inf) = 0 exactly. Write-then-attend with the position
-    mask also gives intra-window causality for free: a window query at
-    position p never sees the window's own later writes (their
-    positions exceed p). Same f32-softmax discipline as
-    :func:`_attention`."""
+def _masked_pool_read(q, pool_k, pool_v, layer, page_table, positions,
+                      page_tokens: int):
+    """The paged read in plain XLA, gather-free: the CPU path and the
+    reference the Pallas kernel is held to. The layer's page set is one
+    dynamic index on the layer axis; ownership is a one-hot compare of
+    the page table against the pool's page ids (the trash page id appears
+    in no table, so it is masked out by construction), each owned page's
+    LOGICAL position comes from the same one-hot, and attention runs over
+    the layer's whole flattened page set with ``owned & (key_pos <=
+    query_pos)`` masking: stale pages, other slots' pages and the trash
+    page all mask to exp(-inf) = 0 exactly. It reads and scores every
+    page of the layer whatever the slots own."""
     s, w, h, hd = q.shape
-    n_pool, pt = pool_k.shape[2], page_tokens
-    kv = k_new.shape[2]
+    kv, n_pool, pt = pool_k.shape[1], pool_k.shape[2], page_tokens
     maxp = page_table.shape[1]
-    trash = n_pool - 1
-
-    # ---- write: new k/v land at their pages (or the trash page) ----
-    with scope("attn/kv_write"):
-        j = positions // pt                                   # (s, w)
-        off = positions % pt
-        pg = jnp.take_along_axis(page_table, j, axis=1)       # (s, w)
-        pg = jnp.where(write_ok & (pg >= 0), pg, trash)
-        at = (layer, jnp.arange(kv)[None, None, :], pg[:, :, None],
-              off[:, :, None])                                # (s, w, kv)
-        pool_k = pool_k.at[at].set(k_new.astype(pool_k.dtype))
-        pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
-
-    # ---- read: ownership + position masks from one one-hot, and the
-    # layer's page set flattened in the compute dtype ----
     with scope("attn/kv_gather"):
         onehot = page_table[:, :, None] \
             == jnp.arange(n_pool)[None, None, :]
@@ -448,7 +422,77 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
                            jnp.asarray(-1e30, scores.dtype))
         probs = jax.nn.softmax(scores.astype(jnp.float32),
                                axis=-1).astype(q.dtype)
-        o = jnp.einsum("swkgn,knd->swkgd", probs, vf).reshape(s, w, h, hd)
+        return jnp.einsum("swkgn,knd->swkgd", probs, vf).reshape(s, w, h, hd)
+
+
+def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
+                     positions, write_ok, page_tokens: int):
+    """Windowed incremental attention against a PAGED KV pool: the new
+    k/v are written at their pages, then every query attends to its
+    slot's pages.
+
+    q: (slots, window, heads, head_dim); k_new/v_new: (slots, window,
+    kv, head_dim), ALREADY rotated at ``positions`` (slots, window);
+    pool_k/pool_v: (n_layers, kv, pages+1, page_tokens, head_dim) — the
+    WHOLE pool, last page of every layer the TRASH page; layer: int32
+    scalar, the layer this call writes and reads. page_table: (slots,
+    max_pages) int32, -1 = unmapped; write_ok: (slots, window) bool —
+    False routes the write to the trash page (inactive slots, positions
+    past capacity, shared-prefix positions another slot's registration
+    already wrote).
+
+    The pool is the layer loop's CARRY: written in place and handed
+    back whole, never sliced out per layer and restacked (which cost a
+    read and a write of the layer's page set, twice, every layer of
+    every token step). Its kv-head axis sits OUTSIDE the pages, so one
+    page of all kv heads is one strided ``(kv, page_tokens, head_dim)``
+    copy and one layer's slice is already the ``(kv, keys, head_dim)``
+    operand the attention matmuls batch over.
+
+    WRITE: the only dynamic indexing is a tiny ``take_along_axis`` on
+    the int32 page table (slots × window entries) plus the scatter of
+    the new k/v, one ``head_dim`` row per token and kv head at
+    ``(layer, head, page, offset)``.
+
+    READ, by backend and shape (:func:`_use_paged_kernel`; no flag):
+    on the TPU the Pallas kernel ``ops/pallas/paged_attention.py``
+    (``paged_attn_decode``) takes the whole pool in place and copies,
+    for each slot, only the pages its own row of the table maps up to
+    its query position: no slice of the layer, no ownership mask, no
+    score against a page the slot does not own. Everywhere else (the
+    CPU, a multi-chip mesh, shapes the kernel does not take)
+    :func:`_masked_pool_read` stages the layer's whole page set and
+    masks. Either way a key's position is its logical page times
+    ``page_tokens`` plus its offset and the mask is ``key_pos <=
+    query_pos``: write-then-attend with the position mask gives
+    intra-window causality for free (a window query at position p never
+    sees the window's own later writes), and the softmax is float32 as
+    in :func:`_attention`."""
+    kv, n_pool, pt = k_new.shape[2], pool_k.shape[2], page_tokens
+    trash = n_pool - 1
+
+    # ---- write: new k/v land at their pages (or the trash page) ----
+    with scope("attn/kv_write"):
+        j = positions // pt                                   # (s, w)
+        off = positions % pt
+        pg = jnp.take_along_axis(page_table, j, axis=1)       # (s, w)
+        pg = jnp.where(write_ok & (pg >= 0), pg, trash)
+        at = (layer, jnp.arange(kv)[None, None, :], pg[:, :, None],
+              off[:, :, None])                                # (s, w, kv)
+        pool_k = pool_k.at[at].set(k_new.astype(pool_k.dtype))
+        pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
+
+    # ---- read ----
+    if _use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt):
+        from tpudist.ops.pallas import paged_attention as pa
+        with scope("attn/kv_gather"):
+            # the table arithmetic the kernel's scalars come from
+            walked = pa.walk(page_table, positions, pt, n_pool)
+        with scope("attn/core"):
+            o = pa.paged_attention(q, pool_k, pool_v, layer, walked)
+    else:
+        o = _masked_pool_read(q, pool_k, pool_v, layer, page_table,
+                              positions, pt)
     return o, pool_k, pool_v
 
 

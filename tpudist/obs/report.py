@@ -712,6 +712,7 @@ def serving_section(metrics: List[Dict[str, Any]],
         "kv_page_tokens": s.get("kv_page_tokens"),
         "kv_pages_total": s.get("kv_pages_total"),
         "kv_pages_used_peak": s.get("kv_pages_used_peak"),
+        "kv_pages_used_mean": s.get("kv_pages_used_mean"),
         # two kinds of cache state and the routed experts' load (a model
         # with window layers and experts held here; None elsewhere)
         "kv_window_tokens_total": s.get("kv_window_tokens_total"),
@@ -1225,6 +1226,14 @@ def to_markdown(report: Dict[str, Any]) -> str:
                   f"queue depth max {sv['queue_depth_max']}, compiles "
                   f"{sv['prefill_compiles']} prefill / "
                   f"{sv['decode_compiles']} decode", ""]
+        if sv.get("kv_pages_used_mean") is not None:
+            lines += [f"- kv pages read a dispatch: "
+                      f"{sv['kv_pages_used_mean']} of "
+                      f"{sv['kv_pages_total']} pool pages mapped by the "
+                      f"slots' rows (mean over decode dispatches, peak "
+                      f"{sv['kv_pages_used_peak']}): a page-bounded "
+                      f"decode read copies these a layer a token step, "
+                      f"the masked read the whole pool", ""]
         if sv.get("moe_pairs_per_expert_mean") is not None:
             lines += [f"- experts held here: "
                       f"{sv['moe_pairs_per_expert_mean']} pair(s) an "

@@ -41,7 +41,7 @@ SCOPES: Tuple[str, ...] = (
     "cast",             # stored weight -> compute dtype where they
                         # differ: nothing on the serve path once the
                         # engine holds the weights in its dtype
-                        # (``ServeEngine._resident``); in training, the
+                        # (``PagedServeEngine._resident``); in training, the
                         # embedding table's (the others fuse into their
                         # matmuls)
     "loss",             # the whole loss function (forward and backward)

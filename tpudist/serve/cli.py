@@ -589,7 +589,8 @@ def _write_bench(path: str, args: argparse.Namespace,
             "shed_fraction", "queue_cap", "ttft_deadline_s",
             "adapt_level", "decode_k_ladder", "requeue_attempt",
             "kv_page_tokens", "kv_pages_total", "kv_pages_used_peak",
-            "active_slots_peak", "spec_accept_rate", "speculate_k",
+            "kv_pages_used_mean", "active_slots_peak", "spec_accept_rate",
+            "speculate_k",
             "shared_prefix_len", "kv_window_tokens_total",
             "kv_window_tokens_peak", "moe_pairs_per_expert_mean",
             "moe_experts_hit_mean")},

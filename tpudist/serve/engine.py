@@ -24,7 +24,18 @@ the CI lane):
   superstep bitwise) — so one compiled program serves every batch
   occupancy from full to empty. Each step is the model's
   ``paged_hidden_states`` over the pool, which rides the layer loop as
-  its carry and is written in place.
+  its carry and is written in place. Which READ of the pool runs is the
+  model's routing by backend and shape
+  (``transformer._use_paged_kernel``), never a flag of this engine: on
+  one TPU chip at kernel-sized shapes (``head_dim`` a multiple of 128,
+  pages a whole tile) each slot's queries go through the Pallas kernel
+  ``ops/pallas/paged_attention.py``, which copies only the pages the
+  slot's own row of the page table maps up to its position; on the
+  CPU, on a multi-chip mesh and at any other shape the XLA masked read
+  (``transformer._masked_pool_read``) stages the layer's whole page set
+  and masks what a slot does not own. ``cohere2moe`` has a read of its
+  own for its two kinds of cache. The verify program runs the same
+  read at window ``speculate_k``.
 
 With graceful degradation on (``adapt_ladder``), the contract
 generalises to one decode program PER LADDER RUNG, all compiled at
@@ -32,7 +43,9 @@ warmup: a pressure downshift switches programs, it never traces one.
 
 Greedy decoding is a pure function of (params, state), so runs are
 bitwise reproducible; decode-over-pages logits are pinned ULP-close to
-the full forward (tests/test_serve.py).
+the full forward (tests/test_serve.py), and the kernel is held to the
+masked read (tests/test_paged_attention_kernel.py under the Pallas
+interpreter; tests_tpu/test_tpu_lane.py on the chip).
 """
 
 from __future__ import annotations

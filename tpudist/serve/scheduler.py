@@ -310,7 +310,7 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
     results: Dict[int, Dict[str, Any]] = {}
     generated = truncated = dispatches = 0
     drafted = accepted = 0          # speculative-draft acceptance
-    active_peak = pages_peak = 0
+    active_peak = pages_peak = pages_sum = 0
     queue_depths: List[int] = []
     recent_tok: deque = deque(maxlen=max(res.window, 1))
     t0 = clock()
@@ -572,6 +572,10 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
             stall_s = float(chaos.on_serve_dispatch(dispatches) or 0.0)
         t_dispatch = clock()
         occ_mask = np.array([s is not None for s in slots])
+        # the pages the slots' rows map: what a page-bounded decode read
+        # copies a layer a token step (the masked read stages the layer's
+        # whole pool whatever they hold)
+        pages_now = alloc.pages_used()
         if spec_on:
             draft = np.zeros((engine.slots, spec_k - 1), np.int32)
             for i in occupied:
@@ -580,14 +584,16 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                     list(s.req.tokens[:s.req.prompt_len]) + s.output,
                     spec_k - 1)
             with tracer.span("verify_step", cat="serve",
-                             active=len(occupied), window=spec_k):
+                             active=len(occupied), window=spec_k,
+                             kv_full_pages=pages_now):
                 with tracer.span("decode_enqueue", cat="serve"):
                     state, toks, valid, _emitted = engine.verify(
                         params, state, draft, dispatch_active=occ_mask)
                 toks, valid = fenced(toks, valid)
         else:
             with tracer.span("decode_step", cat="serve",
-                             active=len(occupied), decode_k=cur_k) as sp:
+                             active=len(occupied), decode_k=cur_k,
+                             kv_full_pages=pages_now) as sp:
                 with tracer.span("decode_enqueue", cat="serve"):
                     state, toks, valid = engine.decode(
                         params, state, cur_k, dispatch_active=occ_mask)
@@ -597,8 +603,7 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                     for name in moe_sum:
                         moe_sum[name] += counted[name]
                     if tracer.enabled:
-                        sp.note(kv_full_pages=alloc.pages_used(),
-                                kv_window_tokens=alloc.window_tokens_used(),
+                        sp.note(kv_window_tokens=alloc.window_tokens_used(),
                                 **counted)
         if virtual is not None:
             dt = virtual.decode_s + stall_s
@@ -608,7 +613,8 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         emit = tracer.begin("emit", cat="serve")
         dispatches += 1
         active_peak = max(active_peak, len(occupied))
-        pages_peak = max(pages_peak, alloc.pages_used())
+        pages_peak = max(pages_peak, pages_now)
+        pages_sum += pages_now
         window_peak = max(window_peak, alloc.window_tokens_used())
         if tracer.enabled:
             # KV-pool occupancy sample, one per dispatch: becomes
@@ -781,6 +787,10 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         "kv_page_tokens": engine.spec.page_tokens,
         "kv_pages_total": engine.spec.pages,
         "kv_pages_used_peak": pages_peak,
+        # mapped pages a dispatch, mean: what the decode read owes a layer
+        # a token step, of kv_pages_total
+        "kv_pages_used_mean": (round(pages_sum / dispatches, 2)
+                               if dispatches else None),
         # the other kind of state (a model with window layers): tokens
         # held in the per-slot rings, of slots x ring_tokens
         "kv_window_tokens_total": (engine.spec.slots
