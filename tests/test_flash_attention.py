@@ -330,6 +330,59 @@ def test_window_is_refused_where_it_means_nothing():
         fa.flash_attention(q, k, v, window=0, interpret=True)
 
 
+def _block_ref(q, k, v, block):
+    """Dense attention under the block-causal mask j // block <= i //
+    block, float32."""
+    from tpudist.ops.gqa import expand_gqa
+    k, v = expand_gqa(q, k, v)
+    s = q.shape[1]
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    sc = jnp.where(j // block <= i // block, sc, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), v)
+
+
+# a block-diffusion model's 4, the smallest, a whole q block; beside the
+# windows above: the same kernel, another mask parameter
+@pytest.mark.parametrize("block,bq,bk", [(4, 128, 128), (2, 128, 128),
+                                         (128, 128, 128), (4, 256, 128),
+                                         (4, 128, 512)])
+def test_block_forward_matches_a_dense_block_mask(block, bq, bk):
+    q, k, v = _data(s=512, h=4, kv=2, seed=5)
+    got = fa.flash_attention(q, k, v, block=block, block_q=bq, block_k=bk,
+                             interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_block_ref(q, k, v, block)),
+                               atol=2e-5, rtol=1e-4)
+    causal = fa.flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                interpret=True)
+    # a block's first row sees the keys ahead of it; its last row is causal
+    assert np.abs(np.asarray(got[:, 0]) - np.asarray(causal[:, 0])).max() \
+        > 1e-3
+    np.testing.assert_allclose(np.asarray(got[:, block - 1]),
+                               np.asarray(causal[:, block - 1]), atol=2e-5)
+
+
+def test_block_is_refused_where_it_is_not_built():
+    q, k, v = _data()
+    for kw in ({"causal": False}, {"window": 64}, {"block": 6},
+               {"block": 256}):
+        with pytest.raises(ValueError, match="block"):
+            fa.flash_attention(q, k, v, interpret=True,
+                               **{"block": 4, **kw})
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: fa.flash_attention(
+            q, k, v, block=4, interpret=True).sum())(q)
+
+
+def test_the_dense_path_takes_the_block_mask_off_the_tpu():
+    from tpudist.models import transformer as T
+    q, k, v = _data(s=128, h=4, kv=2, seed=6)
+    np.testing.assert_allclose(
+        np.asarray(T._attention(q, k, v, block=4)),
+        np.asarray(_block_ref(q, k, v, 4)), atol=2e-5, rtol=1e-4)
+
+
 def test_an_unset_window_leaves_the_causal_kernels_as_they_were():
     """The train cell's program must not move: with no window nothing of
     the band is traced (no extra compare in the mask, the kv index map
@@ -341,5 +394,5 @@ def test_an_unset_window_leaves_the_causal_kernels_as_they_were():
             return fa.flash_attention(q, k, v, block_q=128, block_k=128,
                                       interpret=True, **kw).sum()
         return jax.jit(jax.grad(loss)).lower(q, k, v).as_text()
-    assert lowered() == lowered(window=None)
+    assert lowered() == lowered(window=None) == lowered(block=0)
     assert lowered(window=128) != lowered()

@@ -41,6 +41,9 @@ def _table(first_pos, w, perm=None):
 
 
 def _both(q, pool_k, pool_v, layer, table, pos, block_pages=None):
+    """``pos``: the last key position each query row may read (its own
+    position for a decode or verify window, its block's last for a block
+    of a block-diffusion model: both reads take the bound as data)."""
     table, pos = jnp.asarray(table), jnp.asarray(pos)
     layer = jnp.int32(layer)
     ref = T._masked_pool_read(q, pool_k, pool_v, layer, table, pos, PT)
@@ -81,6 +84,13 @@ CASES = [
     ("layer-last", 1, 4, 2, [6, 13, 0, 17], L - 1, 2, jnp.float32),
     ("bfloat16", 1, 4, 2, [6, 13, 0, 17], 1, 2, jnp.bfloat16),
     ("bfloat16-w3", 3, 4, 2, [6, 11, 0, 15], 2, None, jnp.bfloat16),
+    # a block of 4 positions a slot, every row bounded by the block's LAST
+    # position (``block4-``: the bound is given apart from the positions):
+    # blocks that start a page, end one, and lie inside one
+    ("block4-group2", 4, 4, 2, [4, 12, 0, 16], 1, None, jnp.float32),
+    ("block4-group4-blocks-of-2", 4, 4, 1, [8, 0, None, 16], 1, 2,
+     jnp.float32),
+    ("block4-bfloat16", 4, 4, 2, [4, 12, 0, 16], 2, 2, jnp.bfloat16),
 ]
 
 
@@ -90,8 +100,19 @@ def test_kernel_matches_masked_read(case):
     pool_k, pool_v = _pool(kv, dtype)
     perm = np.random.default_rng(2).permutation(PAGES)
     table, pos = _table(first_pos, w, perm)
+    if case[0].startswith("block4-"):
+        own, pos = pos, np.broadcast_to(pos[:, -1:], pos.shape)
     out, ref = _both(_q(len(first_pos), w, h, dtype), pool_k, pool_v, layer,
                      table, pos, block_pages)
+    if case[0].startswith("block4-"):
+        # the keys ahead inside the block count: bounded by its own
+        # position, a block's first row reads another answer
+        causal, _ = _both(_q(len(first_pos), w, h, dtype), pool_k, pool_v,
+                          layer, table, own, block_pages)
+        assert np.abs(causal[0, 0] - out[0, 0]).max() > 1e-2
+        np.testing.assert_allclose(
+            causal[0, -1], out[0, -1],
+            atol=1e-5 if dtype == jnp.float32 else 3e-2)
     live = [s for s, p in enumerate(first_pos) if p is not None]
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
@@ -161,6 +182,31 @@ def test_pages_no_live_row_names_never_reach_the_answer(read):
         live = [0, 2, 3]    # a slot with nothing owned averages the pool
         np.testing.assert_array_equal(ref[live], clean_ref[live])
         np.testing.assert_array_equal(out, clean_out)
+
+
+def test_paged_attention_reads_up_to_see_and_writes_at_positions():
+    """``transformer._paged_attention`` with ``see``: the new k/v land at
+    ``positions``, every row reads up to ``see``. A block's first row then
+    reads what a query at the block's last position reads."""
+    pool_k, pool_v = _pool(2, jnp.float32)
+    table, pos = _table([8, 4], 4)
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    q = _q(2, 4, 4, jnp.float32)
+    new = _q(2, 4, 2, jnp.float32, seed=5)
+    see = jnp.broadcast_to(pos[:, -1:], pos.shape)
+    ok = jnp.ones(pos.shape, bool)
+    out, pk, pv = T._paged_attention(q, new, new, pool_k, pool_v, 1, table,
+                                     pos, ok, PT, see=see)
+    plain, pk2, _ = T._paged_attention(q, new, new, pool_k, pool_v, 1,
+                                       table, pos, ok, PT)
+    assert np.array_equal(np.asarray(pk), np.asarray(pk2))   # same write
+    want = T._masked_pool_read(q, pk, pv, jnp.int32(1), table, see, PT)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
+    # the last row's bound is its own position either way
+    np.testing.assert_allclose(np.asarray(out[:, -1]),
+                               np.asarray(plain[:, -1]), atol=1e-6)
+    assert np.abs(np.asarray(out[:, 0]) - np.asarray(plain[:, 0])).max() \
+        > 1e-2
 
 
 def test_walk_counts_pages_and_links_live_slots():

@@ -353,15 +353,21 @@ def _serve_cell_case(window, seed=0):
     return q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(pos), first
 
 
-@pytest.mark.parametrize("layer,window", [(0, 1), (23, 1), (23, 3)])
-def test_paged_attention_kernel_matches_masked_read_on_chip(layer, window):
+@pytest.mark.parametrize("layer,window,bound", [
+    (0, 1, "own"), (23, 1, "own"), (23, 3, "own"), (23, 4, "block")])
+def test_paged_attention_kernel_matches_masked_read_on_chip(layer, window,
+                                                            bound):
     """Mosaic-compiled, at the cell's shapes, against the XLA masked read
     it replaces on the TPU (bfloat16 tolerance: the kernel keeps its
     scores in float32, the read rounds them to bfloat16). Window 1 is the
-    decode step, window 3 a verify forward's."""
+    decode step, window 3 a verify forward's, window 4 under the bound
+    ``block`` a block-diffusion model's block: every row reads up to the
+    window's last position, given to both reads in the positions' place."""
     from tpudist.models import transformer as T
     from tpudist.ops.pallas import paged_attention as pa
     q, pool_k, pool_v, table, pos, first = _serve_cell_case(window)
+    if bound == "block":
+        pos = jnp.broadcast_to(pos[:, -1:], pos.shape)
     pt = pool_k.shape[3]
     assert T._use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt)
 

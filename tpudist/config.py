@@ -73,6 +73,14 @@ class ModelConfig:
     n_shared_experts: int = 0     # averaged beside the routed sum
     norm_eps: float = 1e-5
     logit_scale: float = 1.0
+    # sdarmoe-only fields (model name "sdarmoe": generation by diffusion
+    # over blocks). It reads n_experts, expert_top_k, d_ff (one expert's
+    # width), head_dim and norm_eps too
+    block_length: int = 0         # positions a dispatch denoises and
+    # commits together; 0: the model generates a token a step
+    denoise_steps: int = 0        # denoising forwards a block (the commit
+    # forward comes on top); 0: block_length, one position a step
+    mask_token_id: int = 0        # what a position still masked holds
 
     @property
     def head_size(self) -> int:

@@ -8,10 +8,10 @@ plus a ``param_specs(cfg, axes)`` function mapping the params pytree to
 classes — pytrees compose directly with ``jit``/``shard_map``/optax.
 """
 
-from tpudist.models import cohere2moe, mlp, moe, transformer
+from tpudist.models import cohere2moe, mlp, moe, sdarmoe, transformer
 
 _REGISTRY = {"mlp": mlp, "transformer": transformer, "moe": moe,
-             "cohere2moe": cohere2moe}
+             "cohere2moe": cohere2moe, "sdarmoe": sdarmoe}
 
 
 def get_model(name: str):

@@ -20,13 +20,8 @@ attention and the scopes are theirs):
   ``n_shared_experts`` shared experts of the same form;
 * the head is the embedding, times ``logit_scale``, over the rows held.
 
-Dropless with static shapes: the pairs of held experts are sorted by
-expert and cut into blocks of ``block`` rows that belong to one expert
-each; a loop of as many trips as there ARE blocks (a ``while`` on the
-device: the worst case, every pair local, is correct and slow, the
-expected case costs what it routes) gathers a block's tokens, runs the
-three products against that expert's weights and scatter-adds the
-weighted result back.
+Dropless with static shapes: ``models/dropless.py``, the routine this
+model shares with ``sdarmoe``.
 
 Two kinds of cache state (``tpudist/serve/kvcache.py``): a full layer
 writes and reads the paged pool through the page table, gathering the
@@ -49,6 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tpudist.config import ModelConfig
+from tpudist.models import dropless
 from tpudist.models import transformer as T
 from tpudist.scopes import cast, scope
 
@@ -58,13 +54,9 @@ Params = Dict
 # float32 whole)
 LEAFWISE_INIT = True
 
-# rows of one expert's block in the grouped product: enough rows to keep a
-# product of a long prompt on the MXU's side of its roofline; a decode
-# step's few pairs take the smallest block that holds them
-_BLOCK_ROWS = 512
 _LEAVES = ("wq", "wk", "wv", "wo", "w_router", "e_gate", "e_up", "e_down",
            "s_gate", "s_up", "s_down")
-N_STATS = 2     # pairs on held experts, held experts hit
+N_STATS = dropless.N_STATS
 
 
 def layer_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -192,49 +184,12 @@ def _route(y: jax.Array, lp: Params, cfg: ModelConfig):
 
 def _routed(y: jax.Array, top_e, top_w, lp: Params, cfg: ModelConfig,
             real=None):
-    """The held experts' part of the routed sum, dropless. y: (tokens, d);
-    ``real`` (tokens,) bool: tokens that are none (a prompt's padding, an
-    empty slot) route nowhere. -> ((tokens, d) float32, stats)."""
-    n, d = y.shape
-    k, E = top_e.shape[1], held(cfg)
-    block = min(_BLOCK_ROWS, -(-n // 16) * 16)
-    dt = y.dtype
-    with scope("moe/dispatch"):
-        e = top_e.reshape(-1) - cfg.expert_first
-        local = (e >= 0) & (e < E)
-        if real is not None:
-            local &= jnp.repeat(real, k)
-        key = jnp.where(local, e, E)
-        order = jnp.argsort(key, stable=True)      # held experts first
-        sizes = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
-        start = jnp.cumsum(sizes) - sizes          # of a group, in order
-        nblk = (sizes + block - 1) // block
-        blk_end = jnp.cumsum(nblk)
-        flat_w = top_w.reshape(-1)
-
-    def expert(i, xb):
-        g = xb @ cast(lp["e_gate"][i], dt)
-        u = xb @ cast(lp["e_up"][i], dt)
-        return (jax.nn.silu(g) * u) @ cast(lp["e_down"][i], dt)
-
-    def one_block(b, out):
-        with scope("moe/dispatch"):
-            ex = jnp.searchsorted(blk_end, b, side="right")
-            rows = (b - (blk_end[ex] - nblk[ex])) * block + jnp.arange(block)
-            ok = rows < sizes[ex]
-            pair = order[jnp.clip(start[ex] + rows, 0, n * k - 1)]
-            tok = pair // k
-            xb = y[tok]
-            wb = jnp.where(ok, flat_w[pair], 0.0)
-        with scope("moe/experts"):
-            hb = lax.switch(ex, [functools.partial(expert, i)
-                                 for i in range(E)], xb)
-        with scope("moe/dispatch"):
-            return out.at[tok].add(hb.astype(jnp.float32) * wb[:, None])
-
-    out = lax.fori_loop(0, blk_end[-1], one_block,
-                        jnp.zeros((n, d), jnp.float32))
-    return out, jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
+    """The held experts' part of the routed sum: the shared dropless
+    routine over this layer's experts. -> ((tokens, d) float32, stats)."""
+    return dropless.routed(
+        y, top_e, top_w, (lp["e_gate"], lp["e_up"], lp["e_down"]),
+        first=cfg.expert_first, held=held(cfg), n_routed=cfg.n_experts,
+        real=real)
 
 
 def _shared(y: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:

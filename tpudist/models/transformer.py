@@ -161,7 +161,8 @@ def _use_flash(q_shape, k_shape, causal: bool = True) -> bool:
     return fa.supports(q_shape, k_shape, causal=causal)
 
 
-def _flash_per_shard(q, k, v, cos, sin, causal: bool, window=None):
+def _flash_per_shard(q, k, v, cos, sin, causal: bool, window=None,
+                     block: int = 0):
     """The flash kernel under whatever mesh the program is traced in.
 
     GSPMD cannot partition a Mosaic call ("Mosaic kernels cannot be
@@ -184,6 +185,8 @@ def _flash_per_shard(q, k, v, cos, sin, causal: bool, window=None):
     # ``window`` is passed only where it is set: the causal call stays the
     # call it was
     kw = {} if window is None else {"window": window}
+    if block:
+        kw["block"] = block
     if not auto or mesh.size == 1:
         return flash_attention(q, k, v, cos=cos, sin=sin, causal=causal,
                                **kw)
@@ -209,7 +212,7 @@ def _flash_per_shard(q, k, v, cos, sin, causal: bool, window=None):
 
 
 def _attention(q, k, v, *, causal: bool = True, cos=None, sin=None,
-               window=None):
+               window=None, block: int = 0):
     """Local attention. q: (batch, seq, heads, head_dim); k/v may carry
     fewer (grouped-query) kv heads and are expanded here. On TPU, aligned
     shapes run the pallas flash kernel (scores never in HBM — measured
@@ -226,13 +229,20 @@ def _attention(q, k, v, *, causal: bool = True, cos=None, sin=None,
 
     ``window`` (causal only): query i sees keys j with ``i - window < j
     <= i``. In the flash kernel on TPU; off it, a band in the dense mask
-    (the blockwise path knows no band and is not taken)."""
-    if _use_flash(q.shape, k.shape, causal):
-        return _flash_per_shard(q, k, v, cos, sin, causal, window)
+    (the blockwise path knows no band and is not taken).
+
+    ``block`` (causal only): the mask is causal over blocks of that many
+    positions, query i sees keys j with ``j // block <= i // block``. In
+    the flash kernel on TPU where ``block`` is a power of two; off it, in
+    the dense mask."""
+    if _use_flash(q.shape, k.shape, causal) \
+            and not block & max(block - 1, 0):
+        return _flash_per_shard(q, k, v, cos, sin, causal, window, block)
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    if causal and window is None and q.shape[1] >= _BLOCKWISE_MIN_SEQ \
+    if causal and window is None and not block \
+            and q.shape[1] >= _BLOCKWISE_MIN_SEQ \
             and q.shape[1] == k.shape[1] \
             and q.shape[1] % _BLOCKWISE_CHUNK == 0:
         from tpudist.ops.blockwise_attention import blockwise_causal_attention
@@ -245,6 +255,9 @@ def _attention(q, k, v, *, causal: bool = True, cos=None, sin=None,
     if causal:
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool))
+        if block:
+            mask = (jnp.arange(s_k)[None, :] // block
+                    <= jnp.arange(s_q)[:, None] // block)
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool), -window)
         scores = jnp.where(mask, scores, jnp.asarray(-1e30, scores.dtype))
@@ -426,7 +439,7 @@ def _masked_pool_read(q, pool_k, pool_v, layer, page_table, positions,
 
 
 def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
-                     positions, write_ok, page_tokens: int):
+                     positions, write_ok, page_tokens: int, see=None):
     """Windowed incremental attention against a PAGED KV pool: the new
     k/v are written at their pages, then every query attends to its
     slot's pages.
@@ -439,7 +452,12 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
     max_pages) int32, -1 = unmapped; write_ok: (slots, window) bool —
     False routes the write to the trash page (inactive slots, positions
     past capacity, shared-prefix positions another slot's registration
-    already wrote).
+    already wrote). ``see`` (slots, window) int32: the last key position
+    each query row may read, where that is not its own position (a block
+    of a block-diffusion model: every row sees the block's last key, the
+    keys ahead of it too); ``positions`` stays what the write and the
+    rotation use. Both reads take the bound as data, the kernel as the
+    scalars :func:`paged_attention.walk` makes: neither changes inside.
 
     The pool is the layer loop's CARRY: written in place and handed
     back whole, never sliced out per layer and restacked (which cost a
@@ -483,16 +501,17 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,
         pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
 
     # ---- read ----
+    bound = positions if see is None else see
     if _use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt):
         from tpudist.ops.pallas import paged_attention as pa
         with scope("attn/kv_gather"):
             # the table arithmetic the kernel's scalars come from
-            walked = pa.walk(page_table, positions, pt, n_pool)
+            walked = pa.walk(page_table, bound, pt, n_pool)
         with scope("attn/core"):
             o = pa.paged_attention(q, pool_k, pool_v, layer, walked)
     else:
         o = _masked_pool_read(q, pool_k, pool_v, layer, page_table,
-                              positions, pt)
+                              bound, pt)
     return o, pool_k, pool_v
 
 

@@ -719,6 +719,10 @@ def serving_section(metrics: List[Dict[str, Any]],
         "kv_window_tokens_peak": s.get("kv_window_tokens_peak"),
         "moe_pairs_per_expert_mean": s.get("moe_pairs_per_expert_mean"),
         "moe_experts_hit_mean": s.get("moe_experts_hit_mean"),
+        # a model that generates by diffusion over blocks (None elsewhere)
+        "block_length": s.get("block_length"),
+        "forwards_per_token": s.get("forwards_per_token"),
+        "tokens_per_dispatch": s.get("tokens_per_dispatch"),
         # one span a params tree the engine had to convert (none where
         # the tree rests in the engine's dtype already)
         "weights_resident": ({
@@ -1234,6 +1238,13 @@ def to_markdown(report: Dict[str, Any]) -> str:
                       f"{sv['kv_pages_used_peak']}): a page-bounded "
                       f"decode read copies these a layer a token step, "
                       f"the masked read the whole pool", ""]
+        if sv.get("block_length"):
+            lines += [f"- generation by blocks of {sv['block_length']}: "
+                      f"{sv['forwards_per_token']} forward(s) a token "
+                      f"emitted (a block takes its denoising steps and "
+                      f"one commit forward), "
+                      f"{sv['tokens_per_dispatch']} token(s) a dispatch "
+                      f"over all slots", ""]
         if sv.get("moe_pairs_per_expert_mean") is not None:
             lines += [f"- experts held here: "
                       f"{sv['moe_pairs_per_expert_mean']} pair(s) an "
@@ -1241,10 +1252,11 @@ def to_markdown(report: Dict[str, Any]) -> str:
                       f"{sv['moe_experts_hit_mean']} expert(s) hit a "
                       f"layer a step (means over decode dispatches); "
                       f"kv state at peak: {sv['kv_pages_used_peak']}/"
-                      f"{sv['kv_pages_total']} full-layer pages, "
-                      f"{sv['kv_window_tokens_peak']}/"
-                      f"{sv['kv_window_tokens_total']} window tokens a "
-                      f"window layer", ""]
+                      f"{sv['kv_pages_total']} full-layer pages"
+                      + (f", {sv['kv_window_tokens_peak']}/"
+                         f"{sv['kv_window_tokens_total']} window tokens a "
+                         f"window layer"
+                         if sv.get("kv_window_tokens_total") else ""), ""]
         if sv.get("weights_resident"):
             wr = sv["weights_resident"]
             lines += [f"- weights at rest: {wr['leaves_cast']} of "

@@ -129,9 +129,11 @@ def _rot_t(x, cos_ref, sin_ref):
 
 
 def _block_scores(q, k, scale, i, j, block_q, block_k, causal,
-                  window=None):
+                  window=None, block=0):
     """(nb, block_q, block_k) f32 scaled scores, causally masked; with a
-    ``window`` row r sees the columns c with r - window < c <= r.
+    ``window`` row r sees the columns c with r - window < c <= r; with a
+    ``block`` (a power of two) row r sees every column up to the last of
+    its own block of ``block`` positions, ``c <= r | (block - 1)``.
 
     The mask is applied UNCONDITIONALLY even though only diagonal-
     straddling blocks need it: a scalar ``lax.cond`` skipping it on
@@ -146,7 +148,7 @@ def _block_scores(q, k, scale, i, j, block_q, block_k, causal,
             + i * block_q
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) \
             + j * block_k
-        keep = cols <= rows
+        keep = cols <= (rows | (block - 1) if block else rows)
         if window is not None:
             keep &= cols > rows - window
         s = jnp.where(keep, s, NEG)
@@ -158,7 +160,7 @@ def _block_scores(q, k, scale, i, j, block_q, block_k, causal,
 
 def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int,
                 causal: bool, rope: bool, single: bool, rep: int,
-                window: int | None = None):
+                window: int | None = None, block: int = 0):
     if rope:
         (q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref,
          o_ref, lse_ref, *scratch) = refs
@@ -179,7 +181,7 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int,
             q = _rot(q, cq_ref, sq_ref)
             k = _rot(k, ck_ref, sk_ref)
         s = _block_scores(q, k, scale, i, j, block_q, block_k, causal,
-                          window)
+                          window, block)
         m = jnp.max(s, axis=2, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=2, keepdims=True)
@@ -206,7 +208,7 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int,
             q = _rot(q, cq_ref, sq_ref)
             k = _rot(k, ck_ref, sk_ref)
         s = _block_scores(q, k, scale, i, j, block_q, block_k, causal,
-                          window)
+                          window, block)
         m_prev = m_ref[:]                              # (nb, block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)                         # masked cells → 0
@@ -244,7 +246,7 @@ def _rope_specs(d: int, block_q: int, block_k: int, transposed: bool):
 
 
 def _fwd(q, k, v, cos, sin, *, scale, block_b, block_q, block_k, causal,
-         interpret, window=None) -> Tuple[jax.Array, jax.Array]:
+         interpret, window=None, block=0) -> Tuple[jax.Array, jax.Array]:
     bh, s, d = q.shape
     sk = k.shape[1]
     rep = bh // k.shape[0]          # grouped-query factor (1 = MHA)
@@ -273,7 +275,8 @@ def _fwd(q, k, v, cos, sin, *, scale, block_b, block_q, block_k, causal,
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal, rope=rope,
-                          single=single, rep=rep, window=window),
+                          single=single, rep=rep, window=window,
+                          block=block),
         name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
@@ -482,8 +485,12 @@ def _dqkv_kernel(*refs, scale: float, block_q: int, block_k: int,
     dk_ref[:] = dk.astype(dk_ref.dtype)
 
 
-def _bwd(scale, block_b, block_q, block_k, causal, interpret, window, res,
-         ct):
+def _bwd(scale, block_b, block_q, block_k, causal, interpret, window, block,
+         res, ct):
+    if block:
+        raise NotImplementedError(
+            "flash_attention's block-causal mask is forward only (the "
+            "serve path's prefill); the backward kernels mask causally")
     q, k, v, o, lse, cos, sin = res
     do, dlse = ct
     rope = cos is not None
@@ -609,22 +616,22 @@ def _bwd(scale, block_b, block_q, block_k, causal, interpret, window, res,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, cos, sin, scale, block_b, block_q, block_k, causal,
-           interpret, window=None):
+           interpret, window=None, block=0):
     """Returns (o, lse): BOTH differentiable outputs — lse's cotangent
     folds into the backward's delta constant (see _bwd). Callers that
     ignore lse get a zero dlse from autodiff, which subtracts away."""
     return _fwd(q, k, v, cos, sin, scale=scale, block_b=block_b,
                 block_q=block_q, block_k=block_k, causal=causal,
-                interpret=interpret, window=window)
+                interpret=interpret, window=window, block=block)
 
 
 def _flash_fwd(q, k, v, cos, sin, scale, block_b, block_q, block_k,
-               causal, interpret, window=None):
+               causal, interpret, window=None, block=0):
     o, lse = _fwd(q, k, v, cos, sin, scale=scale, block_b=block_b,
                   block_q=block_q, block_k=block_k, causal=causal,
-                  interpret=interpret, window=window)
+                  interpret=interpret, window=window, block=block)
     return (o, lse), (q, k, v, o, lse, cos, sin)
 
 
@@ -708,7 +715,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_b: int = 8,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool | None = None,
-                    window: int | None = None) -> jax.Array:
+                    window: int | None = None,
+                    block: int = 0) -> jax.Array:
     """Attention without the (b, h, s, s) score tensor in HBM.
 
     q: (batch, seq, heads, head_dim); k/v: (batch, seq_k, kv_heads,
@@ -728,6 +736,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sees keys j with ``i - window < j <= i`` (itself included), forward
     and backward; kv blocks wholly behind the band are neither computed
     nor fetched. Unset, the kernels are the causal ones to the letter.
+    ``block`` (a power of two that divides ``block_q``; forward only):
+    the mask is causal over BLOCKS of that many positions, query i sees
+    keys j with ``j // block <= i // block``, the keys ahead of it inside
+    its own block too (a block-diffusion model's prompt). Which kv blocks
+    a q block needs does not move: its last row closes a block.
     """
     b, s, h, hd = q.shape
     sk = k.shape[1]
@@ -735,6 +748,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(
             f"window={window} needs causal=True and at least 1 key (the "
             f"query's own)")
+    if block and (not causal or window is not None or block & (block - 1)
+                  or 128 % block):
+        raise ValueError(
+            f"block={block} needs causal=True, no window and a power of "
+            f"two that divides every q block")
     if cos is not None and (s != sk or cos.shape != (s, hd // 2)
                             or sin.shape != cos.shape):
         raise ValueError(
@@ -747,7 +765,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     cosf = None if cos is None else cos.astype(jnp.float32)
     sinf = None if sin is None else sin.astype(jnp.float32)
     o, _ = _flash(q3, k3, v3, cosf, sinf, 1.0 / (hd ** 0.5),
-                  nb, bq, bk, causal, interpret, window)
+                  nb, bq, bk, causal, interpret, window, block)
     return o.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 

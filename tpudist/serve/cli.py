@@ -51,12 +51,17 @@ def parse_args(argv: Optional[Sequence[str]] = None
         prog="python -m tpudist.serve",
         description="tpudist serving acceptance lane: continuous "
                     "batching + sharded KV cache + latency-SLO verdict")
-    p.add_argument("--model", choices=("transformer", "moe", "cohere2moe"),
+    p.add_argument("--model", choices=("transformer", "moe", "cohere2moe",
+                                       "sdarmoe"),
                    default="transformer",
                    help="cohere2moe: parallel block, window and NoPE-full "
                         "attention by layer, sigmoid-routed experts of "
                         "which --n-experts-held live here, averaged "
-                        "shared experts; weights at rest in bfloat16")
+                        "shared experts; weights at rest in bfloat16. "
+                        "sdarmoe: generation by diffusion over blocks of "
+                        "4 (the mask token is the vocabulary's last id), "
+                        "softmax-routed experts all held here, q/k norm, "
+                        "untied head")
     p.add_argument("--vocab-size", type=int, default=256)
     p.add_argument("--n-layers", type=int, default=2)
     p.add_argument("--d-model", type=int, default=64)
@@ -290,7 +295,12 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
         n_experts=args.n_experts, expert_top_k=args.expert_top_k,
         head_dim=args.head_dim, sliding_window=args.sliding_window,
         n_experts_held=args.n_experts_held,
-        n_shared_experts=args.n_shared_experts)
+        n_shared_experts=args.n_shared_experts,
+        # the family's released settings: blocks of 4 (the one length the
+        # engine has built), the mask token the vocabulary's last id
+        **({"block_length": 4, "mask_token_id": args.vocab_size - 1,
+            "rope_theta": 1e6, "norm_eps": 1e-6}
+           if args.model == "sdarmoe" else {}))
     mesh = build_mesh(ParallelConfig())
     # same resolver as the train lane (flag > $TPUDIST_TRACE > on for
     # the switch; --trace-dir > $TPUDIST_TRACE_DIR > --save-dir for the

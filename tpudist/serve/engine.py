@@ -1,5 +1,9 @@
 """The prefill/decode-split serving engine: exactly TWO compiled programs
-(three with speculation on), over a paged KV pool.
+(three with speculation on), over a paged KV pool. A model that generates
+by diffusion over blocks (``ModelConfig.block_length``) gets the same two:
+its prefill writes the prompt's whole blocks and yields no token, and its
+dispatch is the block-denoising program (``_paged_denoise_body``), chosen
+here by what the model declares as ``windowed`` chooses the ring programs.
 
 The pjit/TPUv4 discipline that keeps the training loop honest (one
 compiled program per run, traced scalars for everything that varies)
@@ -66,6 +70,9 @@ from tpudist.scopes import cast, scope, scoped
 from tpudist.serve import kvcache
 from tpudist.utils import compat
 
+# the one block length the block-denoising program is built and held at
+BLOCK_BUILT = 4
+
 
 class PagedServeState(NamedTuple):
     """Device-resident serving state — the scan carry of the decode
@@ -86,6 +93,15 @@ class PagedServeState(NamedTuple):
     ring_k: tuple = ()
     ring_v: tuple = ()
     stats: Optional[jax.Array] = None
+    # a model that generates by diffusion over blocks only (else None):
+    # the slot's CURRENT block, (slots, block_length) each: its tokens
+    # (the prompt's remainder as given, the mask token elsewhere), which
+    # of its positions are still masked, and for the block the LAST
+    # dispatch finished the denoising step at which each position was
+    # unmasked (-1: it was given)
+    block_tok: Optional[jax.Array] = None
+    block_open: Optional[jax.Array] = None
+    block_step: Optional[jax.Array] = None
 
 
 
@@ -178,6 +194,23 @@ class PagedServeEngine:
         self.model = get_model(model_cfg.name)
         self.mesh = mesh
         self.slots, self.max_seq = int(slots), int(max_seq)
+        # a model that declares a block length generates by diffusion over
+        # blocks: its dispatch is a program of its own below (a block
+        # through its denoising forwards and the commit forward), its
+        # prefill yields no token, and a dispatch's token steps are the
+        # block's positions
+        self.block = int(model_cfg.block_length)
+        if not self.block and getattr(self.model, "GENERATES_BY_BLOCKS",
+                                      False):
+            raise ValueError(
+                f"model {model_cfg.name!r} generates by diffusion over "
+                f"blocks and has no token-a-step decode: give its config "
+                f"a block_length")
+        # its denoising forwards (one position unmasked a step) + the commit
+        self.forwards_per_block = self.block + 1 if self.block else 0
+        if self.block:
+            self._check_block(model_cfg, speculate_k, adapt_ladder)
+            decode_k = self.block
         self.prompt_pad, self.decode_k = int(prompt_pad), int(decode_k)
         ladder = tuple(int(k) for k in (adapt_ladder or (decode_k,)))
         if not ladder or ladder[0] != self.decode_k:
@@ -199,6 +232,8 @@ class PagedServeEngine:
         # two kinds of cache state: the programs of such a model are
         # bodies of their own below, the others' are untouched
         self.windowed = self.spec.window_layers > 0
+        # a model whose programs count what they did (``read_stats``)
+        self.counted = hasattr(self.model, "N_STATS")
         if self.windowed and self.speculate_k:
             raise ValueError(
                 "--speculate-k over a model with window layers is not "
@@ -223,6 +258,36 @@ class PagedServeEngine:
                     donate_argnums=(1,)), mesh)
         self._verify = OnMesh(
             jax.jit(self._paged_verify_body, donate_argnums=(1,)), mesh)
+        self._denoise = OnMesh(
+            jax.jit(self._paged_denoise_body, donate_argnums=(1,)), mesh)
+
+    def _check_block(self, cfg: ModelConfig, speculate_k, adapt_ladder):
+        b = self.block
+        if speculate_k:
+            raise ValueError(
+                "--speculate-k with a model that generates by diffusion "
+                "over blocks is not built: a dispatch already yields a "
+                "block of tokens a slot, and there is no next-token draft "
+                "to verify")
+        if b != BLOCK_BUILT:
+            raise ValueError(
+                f"block_length {b} is not built: the flash mask, the "
+                f"denoising scan and the paged read are held by tests and "
+                f"by the chip at blocks of {BLOCK_BUILT} alone")
+        if cfg.denoise_steps not in (0, b):
+            raise ValueError(
+                f"denoise_steps {cfg.denoise_steps} over blocks of {b} is "
+                f"not built: the static schedule unmasks one position a "
+                f"step, so a block takes block_length steps")
+        if self.max_seq % b:
+            raise ValueError(
+                f"max_seq {self.max_seq} must be a whole number of blocks "
+                f"of {b}: blocks are aligned to absolute positions")
+        if adapt_ladder and tuple(adapt_ladder) != (b,):
+            raise ValueError(
+                f"adapt_ladder {tuple(adapt_ladder)} with a model that "
+                f"generates by blocks of {b}: a dispatch is one block, "
+                f"there is no shorter superstep to degrade to")
 
     # --------------------------------------------------------- weights
 
@@ -279,7 +344,11 @@ class PagedServeEngine:
             remaining=vec(jnp.zeros((s,), jnp.int32)),
             ring_k=rings["k"], ring_v=rings["v"],
             stats=vec(jnp.zeros((1 + self.model.N_STATS,), jnp.int32))
-            if self.windowed else None)
+            if self.counted else None,
+            **({"block_tok": vec(jnp.zeros((s, self.block), jnp.int32)),
+                "block_open": vec(jnp.zeros((s, self.block), bool)),
+                "block_step": vec(jnp.full((s, self.block), -1, jnp.int32))}
+               if self.block else {}))
 
     # --------------------------------------------------------- prefill
 
@@ -304,6 +373,9 @@ class PagedServeEngine:
                             ) -> Tuple[PagedServeState, jax.Array]:
         self.prefill_traces.append(1)   # trace-time compile marker
         spec = self.spec
+        if self.block:
+            return self._block_prefill(params, state, tokens, prompt_len,
+                                       slot, max_new, page_row, shared_len)
         # the model's full causal forward over the padded prompt, its
         # rotated K/V kept in a throwaway scratch row
         # (``prefill_kv_hidden_states``) — then copy the prompt's pages
@@ -418,6 +490,40 @@ class PagedServeEngine:
         return h, {"pool_k": pk, "pool_v": pv, "ring_k": rk, "ring_v": rv,
                    "stats": stats}
 
+    def _block_prefill(self, params, state: PagedServeState, tokens,
+                       prompt_len, slot, max_new, page_row, shared_len):
+        """The prefill of a model that generates by blocks: the prompt's
+        WHOLE blocks through the block-causal forward, their k/v into the
+        slot's pages; no token comes of it. The prompt's remainder
+        (``prompt_len mod block``) is not prefilled: it opens the slot's
+        current block, already unmasked, beside mask tokens. Hands back the
+        number of blocks written, to fence on."""
+        b = self.block
+        whole = prompt_len // b * b
+        _, ks, vs, counts = self.model.prefill_hidden_states(
+            params, tokens, self.model_cfg, dtype=self.dtype,
+            prompt_len=whole)
+        with scope("kv_scatter"):
+            pk, pv = self._scatter_pages(
+                state.pool_k, state.pool_v, jnp.stack(ks), jnp.stack(vs),
+                page_row, whole, shared_len)
+        at = whole + jnp.arange(b, dtype=jnp.int32)
+        given = lax.dynamic_slice(jnp.pad(tokens[0], (0, b)), (whole,), (b,))
+        tok = jnp.where(at < prompt_len, given,
+                        jnp.int32(self.model_cfg.mask_token_id))
+        active = (max_new > 0) & (whole < self.max_seq)
+        row = lambda a, v: lax.dynamic_update_slice(a, v[None], (slot, 0))
+        return state._replace(
+            pool_k=pk, pool_v=pv,
+            lengths=state.lengths.at[slot].set(whole),
+            active=state.active.at[slot].set(active),
+            remaining=state.remaining.at[slot].set(
+                jnp.where(active, max_new, 0)),
+            block_tok=row(state.block_tok, tok),
+            block_open=row(state.block_open, at >= prompt_len),
+            stats=jnp.concatenate([jnp.ones((1,), jnp.int32), counts])), \
+            whole // b
+
     def _note_program(self, name: str, jitted, args,
                       static_idx: Tuple[int, ...] = ()) -> None:
         """Remember how to ``.lower()`` one pinned program: shape/
@@ -462,7 +568,9 @@ class PagedServeEngine:
         ``slot``) and the shared-prefix watermark ``shared_len``
         (``alloc.admit_shared_len``). Returns the updated state and the
         request's FIRST generated token (a device scalar — ``int()`` it
-        to fence)."""
+        to fence); a model that generates by blocks yields no token here,
+        and the scalar is the number of whole blocks of the prompt
+        written to the cache."""
         params = self._resident(params)
         tokens = jnp.asarray(tokens, jnp.int32).reshape(1, self.prompt_pad)
         if page_row is None:
@@ -564,6 +672,84 @@ class PagedServeEngine:
         state, (toks, valid) = lax.scan(step, state, None, length=k)
         return state, toks, valid
 
+    @scoped("denoise")
+    def _paged_denoise_body(self, params, state: PagedServeState,
+                            page_table, dispatch_active
+                            ) -> Tuple[PagedServeState, jax.Array,
+                                       jax.Array]:
+        """One dispatch of a model that generates by blocks: every live
+        slot's current block through ``block`` denoising forwards and the
+        commit forward. A forward runs the block's tokens at their
+        positions: each layer writes their k and v into the slot's pages
+        (provisional until the commit) and reads the committed cache and
+        the block's own keys, all of them (``see``: the block's last
+        position). A denoising step takes, at each position still masked,
+        the argmax token and its probability (float32 softmax), and
+        unmasks the one of highest probability (ties: the lowest
+        position); a slot with nothing left masked (a request's first
+        block opens with the prompt's remainder) idles through the steps
+        that are left. The commit forward runs the block's final tokens
+        once more: what it writes is the K/V the cache keeps. A slot's
+        budget is honoured to the token: the block's positions past it
+        are computed and not emitted."""
+        self.decode_traces.append(self.block)   # trace-time compile marker
+        b, cfg = self.block, self.model_cfg
+        act = state.active & dispatch_active
+        start = state.lengths                   # the block's first position
+        offs = jnp.arange(b, dtype=jnp.int32)[None, :]
+        pos = jnp.minimum(start[:, None] + offs, self.max_seq - 1)
+        see = jnp.broadcast_to(pos[:, -1:], pos.shape)
+        write_ok = act[:, None] & (start[:, None] + offs < self.max_seq)
+        open0 = state.block_open & act[:, None]
+
+        def forward(tok, pk, pv):
+            return self.model.paged_hidden_states(
+                params, tok, cfg, dtype=self.dtype, pool_k=pk, pool_v=pv,
+                page_table=page_table, positions=pos, write_ok=write_ok,
+                see=see, page_tokens=self.spec.page_tokens)
+
+        def step(carry, s):
+            tok, still, at, pk, pv, counts = carry
+            h, pk, pv, st = forward(tok, pk, pv)
+            with scope("unmask"):
+                logits = self.model.head_logits(params, h, self.dtype)
+                best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                conf = jnp.exp(jnp.max(logits, axis=-1)
+                               - jax.nn.logsumexp(logits, axis=-1))
+                pick = jnp.argmax(jnp.where(still, conf, -1.0), axis=1)
+                hit = still & (offs == pick[:, None])
+                tok = jnp.where(hit, best, tok)
+                at = jnp.where(hit, s, at)
+            return (tok, still & ~hit, at, pk, pv, counts + st), None
+
+        (tok, _, at, pk, pv, counts), _ = lax.scan(
+            step, (state.block_tok, open0, jnp.full_like(state.block_tok, -1),
+                   state.pool_k, state.pool_v,
+                   jnp.zeros((self.model.N_STATS,), jnp.int32)),
+            jnp.arange(b, dtype=jnp.int32))
+        with scope("commit"):
+            _, pk, pv, st = forward(tok, pk, pv)
+        # the new positions in order, as far as the budget goes
+        emit = open0 & (jnp.cumsum(open0, axis=1) <= state.remaining[:, None])
+        new_len = jnp.where(act, start + b, start)
+        new_rem = jnp.where(act, state.remaining - emit.sum(axis=1),
+                            state.remaining)
+        new_active = jnp.where(
+            dispatch_active,
+            act & (new_rem > 0) & (new_len < self.max_seq), state.active)
+        fresh = act[:, None]
+        new_state = state._replace(
+            pool_k=pk, pool_v=pv, lengths=new_len, active=new_active,
+            remaining=new_rem,
+            block_tok=jnp.where(fresh, jnp.int32(cfg.mask_token_id),
+                                state.block_tok),
+            block_open=jnp.where(fresh, True, state.block_open),
+            block_step=jnp.where(fresh, at, -1),
+            stats=jnp.concatenate([
+                jnp.full((1,), self.forwards_per_block, jnp.int32),
+                counts + st]))
+        return new_state, jnp.where(fresh, tok, -1).T, emit.T
+
     def read_stats(self, state: PagedServeState) -> dict:
         """What the program that produced ``state`` counted, as span
         arguments (empty for a model that counts nothing). Call it after
@@ -571,12 +757,20 @@ class PagedServeEngine:
         if state.stats is None:
             return {}
         steps, pairs, hit = (int(v) for v in np.asarray(state.stats))
-        steps = max(steps, 1)
-        layers = self.model_cfg.n_layers
+        steps = max(steps, 1)       # token steps, or a block's forwards
+        cfg = self.model_cfg
+        held = cfg.n_experts_held or cfg.n_experts
         return {"moe_pairs_local": pairs,
                 "moe_pairs_per_expert": pairs / (
-                    self.model.held(self.model_cfg) * layers * steps),
-                "moe_experts_hit": hit / (layers * steps)}
+                    held * cfg.n_layers * steps),
+                "moe_experts_hit": hit / (cfg.n_layers * steps)}
+
+    def read_block(self, state: PagedServeState) -> np.ndarray:
+        """(block, slots): the denoising step at which each position of
+        the block the last dispatch finished was unmasked (-1: given, or
+        the slot was outside the dispatch), in ``decode``'s orientation.
+        Call it after the fence on that dispatch's tokens."""
+        return np.asarray(state.block_step).T
 
     def decode(self, params, state: PagedServeState,
                k: Optional[int] = None, dispatch_active=None
@@ -605,6 +799,13 @@ class PagedServeEngine:
             da = jnp.ones((self.slots,), bool)
         else:
             da = jnp.asarray(dispatch_active, bool).reshape(self.slots)
+        if self.block:
+            # tokens (block, slots): every position the dispatch computed
+            # (-1 outside it); valid: the ones emitted. ``state.block_step``
+            # says at which step each was unmasked
+            self._note_program(f"denoise_b{k}", self._denoise,
+                               (params, state, table, da))
+            return self._denoise(params, state, table, da)
         self._note_program(f"decode_k{k}", self._decode,
                            (params, state, k, table, da), static_idx=(2,))
         return self._decode(params, state, k, table, da)

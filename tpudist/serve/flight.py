@@ -26,7 +26,7 @@ trace document is supplied (and its ring dropped nothing) the ledger
 additionally pins the span view against the event view: one prefill
 span per admitted rid, and the per-rid sum of ``decode_emit`` tokens
 equal to the terminal event's ``generated`` count minus the prefill
-token.
+token (``prefill_tokens``: none where the model generates by blocks).
 
 Also home to the pod-trace presentation helpers: the per-slot track
 copies and the ph="C" KV-pool occupancy counter events the serve CLI
@@ -317,9 +317,12 @@ def _trace_problems(rid: int, f: Dict[str, Any],
         term = [e for e in f["events"] if e.get("event") == outcome]
         gen = term[-1].get("generated")
         got = f.get("decode_tokens", 0)
-        if gen is not None and got != int(gen) - 1:
+        # what the prefill itself produced: its one token, or none where
+        # the model generates by blocks (older artifacts do not say: 1)
+        pre = int(term[-1].get("prefill_tokens", 1))
+        if gen is not None and got != int(gen) - pre:
             out.append(f"rid {rid}: decode_emit tokens {got} != "
-                       f"generated-1 ({int(gen) - 1})")
+                       f"generated-{pre} ({int(gen) - pre})")
     return out
 
 

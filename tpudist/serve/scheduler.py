@@ -210,9 +210,14 @@ def make_garbage_requests(plan, event, *, rid_base: int, prompt_pad: int,
 class _Slot:
     req: Request
     generated: int
-    first_token_s: float
+    first_token_s: Optional[float]   # None: no token has come back yet
     output: List[int]
     budget: int               # max_new after any adapt-time truncation
+    # a model that generates by blocks: the denoising step at which each
+    # emitted token was unmasked, and the (position in block, token, step)
+    # of what the last block computed past the budget and did not emit
+    steps: List[int] = dataclasses.field(default_factory=list)
+    surplus: List[tuple] = dataclasses.field(default_factory=list)
 
 
 def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
@@ -265,7 +270,22 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
     verify forward accepts — greedy output stays bitwise identical to
     plain decode, only the tokens-per-dispatch changes. Speculation
     runs at adapt level 0 only (the degradation ladder's rungs are
-    plain decode programs)."""
+    plain decode programs).
+
+    A model that generates by diffusion over blocks (``engine.block``)
+    changes three things here. Its prefill yields NO token: the request is
+    admitted (``admitted`` carries the wait up to the fenced prefill, as
+    ever) and its time to first token is taken when its first block comes
+    back, arrival -> that dispatch's fence, logged as a
+    ``kind=serve_first_tokens`` record. A dispatch hands back up to
+    ``block`` tokens a slot, fewer for a request's first block (the
+    prompt's remainder opens it) and for its last (the budget is honoured
+    to the token: the surplus is computed and not emitted), so the
+    per-token latency of a dispatch is its wall over the slot's own
+    count, as under speculation. And each result carries, beside its
+    tokens, the denoising step at which each was unmasked
+    (``unmask_step``) and what its last block computed past the budget
+    (``surplus``): what a reference needs to replay the denoising."""
     import jax
     if n_chips is None:
         n_chips = max(jax.device_count(), 1)
@@ -300,11 +320,13 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         state = engine.register_prefix(params, state, prefix_arr,
                                        prefix_len)
     spec_k = engine.speculate_k
+    block = engine.block
     # a model whose programs count what they did (pairs routed to the
     # experts held here): read back after the fence the loop makes anyway
     # and carried as arguments of the ``prefill`` and ``decode_step``
     # spans, with how full each kind of cache state is
-    counts_on = engine.windowed
+    counts_on = engine.counted
+    forwards = 0                    # a block model's, summed over blocks
     window_peak = 0
     moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0}
     results: Dict[int, Dict[str, Any]] = {}
@@ -359,6 +381,9 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
             "generated": s.generated, "why": why,
             "adapt_truncated": s.budget < s.req.max_new,
             "e2e_s": t_done - s.req.arrival_s}
+        if block:
+            results[s.req.rid].update(unmask_step=list(s.steps),
+                                      surplus=list(s.surplus))
         stats.note_e2e(t_done - s.req.arrival_s)
         if why == "evicted":
             truncated += 1
@@ -368,7 +393,10 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         event(s.req.rid, res_lib.DONE if why == "done" else
               res_lib.EVICTED, slot=i, generated=s.generated,
               e2e_s=round(t_done - s.req.arrival_s, 6),
-              decode_s=round(t_done - s.first_token_s, 6))
+              decode_s=round(0.0 if s.first_token_s is None
+                             else t_done - s.first_token_s, 6),
+              # how many of ``generated`` came of the prefill itself
+              prefill_tokens=0 if block else 1)
         slots[i] = None
         # pages return to the pool (shared prefix pages drop one
         # refcount; the registry hold keeps them cached). Safe: the
@@ -476,6 +504,8 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                     first = int(first)       # fence: the token exists NOW
                 if counts_on and tracer.enabled:
                     sp.note(**engine.read_stats(state))
+                if block and tracer.enabled:
+                    sp.note(blocks_written=first)
             if virtual is not None:
                 virtual.clock.advance(virtual.prefill_s)
             t_first = now()
@@ -489,12 +519,19 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                   waited_s=round(t_first - req.arrival_s, 6),
                   queue_wait_s=round(t - req.arrival_s, 6),
                   prefill_s=round(t_first - t, 6))
-            stats.note_ttft(t_first - req.arrival_s)
-            generated += 1
-            slots[i] = _Slot(req=req, generated=1, first_token_s=t_first,
-                             output=[first], budget=budget)
-            if budget <= 1 or req.prompt_len >= engine.max_seq:
-                finish(i, "done" if budget <= 1 else "evicted")
+            if block:
+                # no token yet: the first come with the first block
+                slots[i] = _Slot(req=req, generated=0, first_token_s=None,
+                                 output=[], budget=budget)
+            else:
+                stats.note_ttft(t_first - req.arrival_s)
+                generated += 1
+                slots[i] = _Slot(req=req, generated=1,
+                                 first_token_s=t_first, output=[first],
+                                 budget=budget)
+            spent = budget <= 1 and not block   # the prefill's token was it
+            if spent or req.prompt_len >= engine.max_seq:
+                finish(i, "done" if spent else "evicted")
             t = now()
             pump(t)        # arrivals that landed during the prefill
 
@@ -551,8 +588,14 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         width = spec_k if spec_on else cur_k
         for i in occupied:
             s = slots[i]
-            last = min(s.req.prompt_len + s.generated + width - 2,
-                       engine.max_seq - 1)
+            if block:
+                # the whole block the dispatch writes, ahead of what the
+                # slot holds: blocks are aligned to absolute positions
+                last = (s.req.prompt_len + s.generated) // block * block \
+                    + block - 1
+            else:
+                last = s.req.prompt_len + s.generated + width - 2
+            last = min(last, engine.max_seq - 1)
             if not alloc.ensure(i, last):
                 finish(i, "evicted")
         occupied = [i for i in range(engine.slots)
@@ -603,8 +646,17 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                     for name in moe_sum:
                         moe_sum[name] += counted[name]
                     if tracer.enabled:
-                        sp.note(kv_window_tokens=alloc.window_tokens_used(),
-                                **counted)
+                        sp.note(**counted)
+                        if engine.windowed:
+                            sp.note(kv_window_tokens=alloc
+                                    .window_tokens_used())
+                if block:
+                    unmasked_at = engine.read_block(state)
+                    ran = engine.forwards_per_block * len(occupied)
+                    forwards += ran
+                    if tracer.enabled:
+                        sp.note(forwards=ran, blocks=len(occupied),
+                                tokens_emitted=int(valid.sum()))
         if virtual is not None:
             dt = virtual.decode_s + stall_s
             virtual.clock.advance(dt)
@@ -627,10 +679,13 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                            used=alloc.pages_used(),
                            total=engine.spec.pages,
                            shared_refs=shared_refs())
-        if spec_on:
+        varies = spec_on or bool(block)
+        t_back = now() if block else None    # ONE sample a dispatch
+        if varies:
             # a verify dispatch emits a VARIABLE token count per slot:
             # ITL attributes the dispatch wall over each slot's own
-            # accepted run (that is speculation's whole win)
+            # accepted run (that is speculation's whole win); so does a
+            # block dispatch (a request's first and last blocks are cut)
             tot_new = int(valid.sum())
             mean_new = tot_new / max(len(occupied), 1)
             recent_tok.append(dt / mean_new if mean_new > 0 else dt)
@@ -646,12 +701,29 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                     int(t) for t in toks[col_valid, i])
                 slots[i].generated += n_new
                 generated += n_new
-                stats.note_itl(dt / n_new if spec_on else per_tok,
-                               n_new)
+                stats.note_itl(dt / n_new if varies else per_tok, n_new)
                 if spec_on:
                     accepted += n_new - 1    # minus the bonus token
                     drafted += spec_k - 1
             s = slots[i]
+            if block:
+                at = unmasked_at[:, i]
+                s.steps.extend(int(a) for a in at[col_valid])
+                s.surplus = [(int(w), int(toks[w, i]), int(at[w]))
+                             for w in np.flatnonzero(~col_valid & (at >= 0))]
+                if n_new and s.first_token_s is None:
+                    # time to first token: arrival -> the return of the
+                    # request's first block
+                    s.first_token_s = t_back
+                    ttft = s.first_token_s - s.req.arrival_s
+                    stats.note_ttft(ttft)
+                    tracer.instant("first_tokens", cat="serve",
+                                   rid=s.req.rid, slot=i, tokens=n_new)
+                    if metrics is not None:
+                        metrics.log(kind="serve_first_tokens",
+                                    rid=s.req.rid, tokens=n_new,
+                                    t_s=round(s.first_token_s, 6),
+                                    ttft_s=round(ttft, 6))
             # per-slot decode attribution on the flight timeline: the
             # ledger sums these per rid and pins the total against the
             # terminal event's generated count (first token excluded)
@@ -803,6 +875,14 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         "spec_accept_rate": (round(accepted / drafted, 4)
                              if drafted else None),
         "speculate_k": spec_k,
+        # a model that generates by blocks (None elsewhere): forwards run
+        # a token emitted (block + 1 over block on full blocks) and tokens
+        # a dispatch handed back, over all slots
+        "block_length": block,
+        "forwards_per_token": (round(forwards / generated, 4)
+                               if block and generated else None),
+        "tokens_per_dispatch": (round(generated / dispatches, 2)
+                                if block and dispatches else None),
         "shared_prefix_len": prefix_len,
         "ttft_hist": stats.ttft_hist(),
         "itl_hist": stats.itl_hist(),
