@@ -182,9 +182,13 @@ def test_programs_count_the_pairs_of_the_held_experts(served):
         st = o["prefill_stats"]
         assert 0 < st["moe_pairs_local"] <= 4 * 4 * n
         assert st["moe_pairs_per_expert"] == st["moe_pairs_local"] / 16
+        # a block an expert hit at least, a layer a forward
+        assert 0 < st["moe_experts_hit"] <= st["moe_blocks"] <= 4 + n // 16
     assert 0 < last["moe_pairs_local"] <= 4 * 4 * 4 * 3
     assert last["moe_pairs_per_expert"] == last["moe_pairs_local"] / 64
     assert 0 < last["moe_experts_hit"] <= 4
+    # three tokens a step fit one block an expert: no second trip
+    assert last["moe_blocks"] == last["moe_experts_hit"]
     # both kinds of state, as the allocator accounts them
     assert alloc.pages_used() == sum(
         -(-(len(o["prompt"]) + 24) // PAGE) for o in out.values())
@@ -262,7 +266,15 @@ def test_no_pair_of_a_held_expert_is_dropped(case):
         hit = 4
     want = dense_routed(y, top_e, top_w, lp, 4)
     assert float(jnp.abs(got - want).max()) < TOL
-    assert [int(v) for v in stats] == [pairs, hit]
+    # the third count: blocks of rows run (1100 rows of one expert span
+    # three blocks of 512)
+    e = np.asarray(top_e)
+    ok = (e < 4) if real is None else (e < 4) & np.asarray(real)[:, None]
+    blocks = int((-(-np.bincount(e[ok], minlength=4) // 512)).sum())
+    assert [int(v) for v in stats] == [pairs, hit, blocks]
+    assert blocks == {"one_held_expert_takes_every_token": 3,
+                      "every_pair_is_local": 12,
+                      "every_pair_is_absent": 0}.get(case, blocks)
     if pairs:
         assert float(jnp.abs(want).max()) > 0.05
 
@@ -353,8 +365,10 @@ def test_run_serve_carries_the_counts_on_its_spans(mesh):
     eng = PagedServeEngine(cfg, mesh, slots=2, max_seq=44, prompt_pad=16,
                            decode_k=4, page_tokens=PAGE, pages=20,
                            dtype=jnp.float32, ring_margin=RING_MARGIN)
-    eng.warmup(params)
+    # the tracer first: the engine says its ``experts_path`` at the first
+    # dispatch a tracer sees, which is the warm-up's
     tracer = trace_lib.configure(enabled=True)
+    eng.warmup(params)
     reqs = []
     for i, n in enumerate((13, 5, 16, 9)):
         t = np.zeros(16, np.int32)
@@ -375,14 +389,19 @@ def test_run_serve_carries_the_counts_on_its_spans(mesh):
     assert len(fills) == 4 and len(steps) == summary["dispatches"]
     for a in steps:
         assert {"moe_pairs_local", "moe_pairs_per_expert",
-                "moe_experts_hit", "kv_full_pages", "kv_window_tokens",
-                "active"} <= set(a)
+                "moe_experts_hit", "moe_blocks", "kv_full_pages",
+                "kv_window_tokens", "active"} <= set(a)
+        assert a["moe_blocks"] >= a["moe_experts_hit"]
         assert 0 < a["kv_window_tokens"] <= 2 * (WINDOW + RING_MARGIN)
     for a in fills:
         assert a["moe_pairs_per_expert"] == a["moe_pairs_local"] / 16
     assert summary["kv_window_tokens_peak"] == 16
     assert summary["kv_window_tokens_total"] == 16
     assert 0 < summary["moe_pairs_per_expert_mean"] < 4
+    assert summary["moe_blocks_mean"] >= summary["moe_experts_hit_mean"] > 0
+    # said once, at the first traced dispatch: off the TPU the loop
+    assert [s["args"] for s in spans if s["name"] == "experts_path"] \
+        == [{"path": "loop", "prefill": "loop"}]
     assert 0 < summary["kv_pages_used_peak"] <= 20
 
 

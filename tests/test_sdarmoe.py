@@ -239,8 +239,10 @@ def served(mesh, params):
     reference with the control and every planted fault beside it."""
     _, file = configs()
     eng = engine_of(mesh, slots=3)
-    eng.warmup(params)
+    # the tracer first: the engine says its ``experts_path`` at the first
+    # dispatch a tracer sees, which is the warm-up's
     tracer = trace_lib.configure(enabled=True)
+    eng.warmup(params)
     lens = [(8, 9), (9, 12), (6, 7), (11, 5), (5, 10), (12, 8)]
     reqs = []
     for i, (pl, mn) in enumerate(lens):
@@ -317,6 +319,9 @@ def test_run_serve_carries_the_span_arguments(served):
         assert a["forwards"] == (B + 1) * a["blocks"] == (B + 1) * a["active"]
         assert 0 < a["tokens_emitted"] <= B * a["blocks"]
         assert a["moe_pairs_per_expert"] > 0 and a["moe_experts_hit"] > 0
+        # blocks of rows the expert routine ran, a layer a forward: one an
+        # expert hit at least
+        assert a["moe_blocks"] >= a["moe_experts_hit"]
         assert a["kv_full_pages"] > 0
     assert sum(s["args"]["tokens_emitted"] for s in steps) \
         == sum(r.max_new for r in reqs)
@@ -325,6 +330,40 @@ def test_run_serve_carries_the_span_arguments(served):
         == sorted(r.prompt_len // B for r in reqs)
     names = {s["name"] for s in spans}
     assert {"decode_enqueue", "decode_fence", "first_tokens"} <= names
+    # said once, at the first traced dispatch: off the TPU the loop
+    assert [s["args"] for s in spans if s["name"] == "experts_path"] \
+        == [{"path": "loop", "prefill": "loop"}]
+    summary = served[0]
+    assert summary["moe_blocks_mean"] >= summary["moe_experts_hit_mean"] > 0
+
+
+def test_the_experts_rest_as_one_stack_a_leaf_of_the_same_draws():
+    """``init`` lays a layer's experts as three stacks; member ``i`` is
+    the draw from ``fold_in(leaf_key, i)`` that the array-an-expert layout
+    made, so the benchmark's reference (which makes its own weights from
+    the seed) still agrees, and ``lp["e_gate"][i]`` reads as it did."""
+    cfg, _ = configs()
+    d, dff, E, L = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_layers
+    key = jax.random.PRNGKey(SEED)
+    params = M.init(key, cfg)
+    leaves = jax.tree_util.tree_leaves(params)
+    assert len(leaves) == 3 + 12 * L          # 3 x E x L arrays no more
+    for l in (0, L - 1):
+        lp = params["layers"][l]
+        ks = dict(zip(M._LEAVES, jax.random.split(
+            jax.random.fold_in(key, 1 + l), len(M._LEAVES))))
+        for name, shape, fan in (("e_gate", (d, dff), d),
+                                 ("e_up", (d, dff), d),
+                                 ("e_down", (dff, d), dff)):
+            assert lp[name].shape == (E,) + shape
+            assert lp[name].dtype == jnp.bfloat16
+            for i in (0, 1, E - 1):
+                one = (jax.random.normal(jax.random.fold_in(ks[name], i),
+                                         shape, jnp.float32)
+                       / jnp.sqrt(fan)).astype(jnp.bfloat16)
+                np.testing.assert_array_equal(
+                    np.asarray(lp[name][i], np.float32),
+                    np.asarray(one, np.float32))
 
 
 def test_the_engine_refuses_what_is_not_built(mesh):

@@ -627,6 +627,32 @@ def test_report_serving_section_and_verdict():
     assert rep2["serving"] == {"enabled": False}
 
 
+@pytest.mark.parametrize("path", ["grouped", "loop", None])
+def test_report_serving_prints_the_experts_path_and_blocks(path):
+    """The engine's ``experts_path`` instant (which way the dropless
+    routine was lowered) and the third count (blocks of rows run) reach
+    the "experts held here" line; a run without the instant prints the
+    counts alone, a model that counts nothing no line."""
+    metrics = _serve_metrics()
+    metrics[-1].update(moe_pairs_per_expert_mean=14.8,
+                       moe_experts_hit_mean=103.0, moe_blocks_mean=104.5,
+                       kv_pages_used_peak=7, kv_pages_total=16)
+    ev = {"ph": "X", "pid": 0, "tid": 1, "cat": "serve", "ts": 0.0,
+          "name": "experts_path", "dur": 0.0,
+          "args": {"path": path, "prefill": path}}
+    rep = report_lib.build_report(
+        metrics, {"traceEvents": [ev] if path else []})
+    assert rep["serving"]["experts_path"] == path
+    assert rep["serving"]["moe_blocks_mean"] == 104.5
+    line = next(ln for ln in report_lib.to_markdown(rep).splitlines()
+                if "experts held here" in ln)
+    assert "103.0 expert(s) hit a layer a step in 104.5 block(s)" in line
+    assert (f"experts_path {path}" in line) == bool(path)
+    plain = report_lib.build_report(_serve_metrics(), {})
+    assert plain["serving"]["experts_path"] is None
+    assert "experts held here" not in report_lib.to_markdown(plain)
+
+
 def test_report_serving_prints_what_the_engine_converted():
     """The ``weights_resident`` span (one a params tree the engine had to
     convert to its dtype) is the serving section's one line; a run whose

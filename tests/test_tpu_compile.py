@@ -76,3 +76,48 @@ def test_paged_attention_kernel_compiles_at_the_cells_shapes(
     assert "paged_attn_decode" in text
     # the pool goes to the kernel where it lies: no staged layer, no copy
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# the `sdar` cell's expert layer: 128 experts of 2048 x 768 as one stack a
+# leaf, 512 tokens a dispatch in blocks of 64, 1024 a prefill in blocks of
+# 128, top-8
+@pytest.mark.parametrize("n,block,dtype", [(512, 64, jnp.bfloat16),
+                                           (1024, 128, jnp.bfloat16),
+                                           (512, 64, jnp.float32)],
+                         ids=["dispatch", "prefill", "dispatch-float32"])
+def test_grouped_experts_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache, monkeypatch, n, block, dtype):
+    """The whole grouped path of ``dropless.routed`` (one gather, the
+    kernel, the combine) for the described chip: one Mosaic call, the
+    stacks handed to it where they lie, and no loop left."""
+    from tpudist.models import dropless
+    from tpudist.ops.pallas import grouped_experts as ge
+    E, d, dff, k = 128, 2048, 768, 8
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    ex = (sds((E, d, dff), dtype), sds((E, d, dff), dtype),
+          sds((E, dff, d), dtype))
+    assert dropless.block_rows(n, k, E) == block
+    assert ge.supports(ex, block, dtype)
+    # the routing asks the backend, which here is the CPU
+    monkeypatch.setattr(dropless, "_use_grouped_kernel", ge.supports)
+
+    def mix(y, top_e, top_w, real, *ex):
+        return dropless.routed(y, top_e, top_w, ex, first=0, held=E,
+                               n_routed=E, real=real)
+
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(mix).lower(
+            sds((n, d), dtype), sds((n, k), jnp.int32),
+            sds((n, k), jnp.float32), sds((n,), jnp.bool_), *ex)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "grouped_experts" in text
+    assert " while(" not in text and "conditional(" not in text
+    # the sorted rows, the kernel's rows and the pairs' rows gathered
+    # back, no copy of a stack: far under one stack's 402 MB
+    tiles = ge.tiles_max(n * k, E, block)
+    size = jnp.dtype(dtype).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * tiles * block * d * size + 8 * n * k * d

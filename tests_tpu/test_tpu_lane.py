@@ -423,3 +423,118 @@ def test_engine_greedy_tokens_match_masked_read_on_chip(monkeypatch):
     assert mosaic == {"kernel": True, "masked_read": False}, mosaic
     assert all(len(t) == 64 for t in outs["kernel"].values())
     assert outs["kernel"] == outs["masked_read"]
+
+
+@pytest.mark.parametrize("n,block,router", [
+    (512, 64, "random"), (1024, 128, "random"), (512, 64, "half_real"),
+    (512, 64, "one_expert")])
+def test_grouped_experts_kernel_matches_the_loop_on_chip(monkeypatch, n,
+                                                         block, router):
+    """Mosaic-compiled, at the `sdar` cell's shapes (128 experts of 2048 x
+    768 as one stack a leaf, top-8; 512 tokens a dispatch in blocks of 64,
+    1024 a prefill in blocks of 128), ``dropless.routed`` through the
+    grouped kernel against its own loop, the path it leaves (bfloat16
+    tolerance: the two differ in the order of the MXU's accumulation and
+    of a token's eight rows' sum, and XLA may keep ``silu(g) * u`` wider
+    than bfloat16). ``one_expert``: every token's first choice is expert
+    5, eight tiles of one block index; ``half_real``: every other token is
+    none."""
+    from tpudist.models import dropless
+    from tpudist.ops.pallas import grouped_experts as ge
+    E, d, dff, k = 128, 2048, 768, 8
+    ks = jax.random.split(jax.random.PRNGKey(n), 6)
+    y = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
+    ex = (jax.random.normal(ks[1], (E, d, dff), jnp.bfloat16) / d ** 0.5,
+          jax.random.normal(ks[2], (E, d, dff), jnp.bfloat16) / d ** 0.5,
+          jax.random.normal(ks[3], (E, dff, d), jnp.bfloat16) / dff ** 0.5)
+    logits = jax.random.normal(ks[4], (n, E), jnp.float32)
+    if router == "one_expert":
+        logits = logits.at[:, 5].set(10.0)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    top_w = top_p / top_p.sum(axis=-1, keepdims=True)
+    real = (jnp.arange(n) % 2 == 0) if router == "half_real" else None
+    assert dropless.block_rows(n, k, E) == block
+    assert dropless.path(ex, n, k, E, jnp.bfloat16) == "grouped"
+
+    def run():
+        f = jax.jit(lambda y, e, w, *ex: dropless.routed(
+            y, e, w, ex, first=0, held=E, n_routed=E, real=real))
+        text = f.lower(y, top_e, top_w, *ex).as_text()
+        out, stats = f(y, top_e, top_w, *ex)
+        return np.asarray(out), np.asarray(stats), "tpu_custom_call" in text
+
+    got, stats, mosaic = run()
+    monkeypatch.setattr(dropless, "_use_grouped_kernel", lambda *a: False)
+    want, want_stats, loop_mosaic = run()
+    assert mosaic and not loop_mosaic
+    np.testing.assert_array_equal(stats, want_stats)
+    pairs = n * k // (2 if real is not None else 1)
+    assert stats[0] == pairs and stats[2] >= stats[1] > 0
+    if router == "one_expert":
+        assert stats[2] >= stats[1] + n // block - 1
+    assert np.isfinite(got).all()
+    scale = float(np.abs(want).max())
+    assert float(np.abs(got - want).max()) < 2.0 ** -6 * scale, scale
+    if real is not None:
+        assert not got[1::2].any()
+
+
+def test_engine_serves_the_same_tokens_grouped_and_looped_on_chip(
+        monkeypatch):
+    """The whole serve lane of a block-diffusion expert model, the grouped
+    kernel's tokens against the loop's (the parent's path), in float32 at
+    full matmul precision so that no near-tie decides: token for token,
+    and the engine says which path each run's programs took."""
+    from tpudist.config import ModelConfig, ParallelConfig
+    from tpudist.models import dropless, sdarmoe
+    from tpudist.obs import trace as trace_lib
+    from tpudist.parallel.mesh import build_mesh
+    from tpudist.serve import scheduler as sched
+    from tpudist.serve.engine import PagedServeEngine
+
+    cfg = ModelConfig(name="sdarmoe", vocab_size=512, n_layers=2,
+                      d_model=256, n_heads=2, n_kv_heads=1, head_dim=128,
+                      d_ff=128, n_experts=16, expert_top_k=2,
+                      rope_theta=1e6, norm_eps=1e-6, block_length=4,
+                      denoise_steps=4, mask_token_id=511)
+    mesh = build_mesh(ParallelConfig(), devices=jax.devices()[:1])
+    params = sdarmoe.init(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    rng = np.random.default_rng(3)
+    reqs = []
+    for i, (pl, mn) in enumerate([(40, 33), (17, 64), (64, 21), (9, 40),
+                                  (33, 64), (50, 12)]):
+        t = np.zeros(64, np.int32)
+        t[:pl] = rng.integers(0, 511, (pl,))
+        reqs.append(sched.Request(rid=i, arrival_s=0.0, tokens=t,
+                                  prompt_len=pl, max_new=mn))
+    outs, said, mosaic = {}, {}, {}
+    with jax.default_matmul_precision("highest"):
+        for path in ("grouped", "loop"):
+            if path == "loop":
+                monkeypatch.setattr(dropless, "_use_grouped_kernel",
+                                    lambda *a: False)
+            tracer = trace_lib.configure(enabled=True)
+            try:
+                engine = PagedServeEngine(cfg, mesh, slots=4, max_seq=256,
+                                          prompt_pad=64, page_tokens=64,
+                                          dtype=jnp.float32)
+                engine.warmup(params)
+                summary = sched.run_serve(engine, params, reqs)
+                said[path] = [e["args"] for e in tracer.events()
+                              if e["name"] == "experts_path"]
+            finally:
+                trace_lib.configure(enabled=False)
+            assert summary["completed"] == len(reqs), summary["partition"]
+            assert summary["moe_blocks_mean"] \
+                >= summary["moe_experts_hit_mean"] > 0
+            outs[path] = {rid: (r["tokens"], r["unmask_step"])
+                          for rid, r in summary["results"].items()}
+            jitted, args = engine._programs["denoise_b4"][:2]
+            mosaic[path] = jitted.lower(*args).as_text().count(
+                "grouped_experts")
+    assert said == {"grouped": [{"path": "grouped", "prefill": "grouped"}],
+                    "loop": [{"path": "loop", "prefill": "loop"}]}, said
+    assert mosaic["grouped"] > 0 and mosaic["loop"] == 0, mosaic
+    assert [len(outs["grouped"][i][0]) for i in range(6)] \
+        == [33, 64, 21, 40, 64, 12]
+    assert outs["grouped"] == outs["loop"]

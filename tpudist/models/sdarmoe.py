@@ -20,7 +20,10 @@ dropless expert routine and the scopes are theirs):
   split pairs ``(i, i + head_dim / 2)``; ``head_dim`` is the model's own;
 * the mix: a SOFTMAX router over ``n_experts``, the top ``expert_top_k``
   with weights renormalised over the chosen, no shared expert, dropless
-  over all the experts (``models/dropless.py``);
+  over all the experts (``models/dropless.py``), whose weights rest as
+  one stack a leaf: on one TPU chip the routine runs a layer's experts as
+  one grouped Pallas kernel (``ops/pallas/grouped_experts.py``), elsewhere
+  as its loop of blocks;
 * an untied head over the whole vocabulary.
 
 Weights are created at rest in their serving dtype, leaf by leaf, on the
@@ -62,21 +65,21 @@ def init(key: jax.Array, cfg: ModelConfig, *, dtype=jnp.bfloat16,
     a program of its own where it will live. The embedding draws from
     ``fold_in(key, 0)``, the head from ``fold_in(key, 1 + n_layers)``,
     layer ``l``'s leaves from the 8 keys split from ``fold_in(key, 1 +
-    l)``, expert ``i`` from ``fold_in(leaf_key, i)``. Every gain is one."""
+    l)``, expert ``i``, member ``i`` of its leaf's one stack, from
+    ``fold_in(leaf_key, i)``. Every gain is one."""
     d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_size
     dff, E, L = cfg.d_ff, cfg.n_experts, cfg.n_layers
     make = functools.partial(_maker(sharding), dtype=jnp.dtype(dtype))
     ones = jax.jit(lambda n: jnp.ones((n,), jnp.float32),
                    out_shardings=sharding, static_argnums=0)
 
-    def w(k, *shape, fan_in):
-        return make(k, shape=shape, fan_in=fan_in, stacked=0)
+    def w(k, *shape, fan_in, stacked=0):
+        return make(k, shape=shape, fan_in=fan_in, stacked=stacked)
 
     def experts(k, *shape, fan_in):
-        # an array of its own per expert: ``dropless.routed`` picks an
-        # expert by branch and reads its weights where they lie
-        return tuple(w(jax.random.fold_in(k, i), *shape, fan_in=fan_in)
-                     for i in range(E))
+        # one stack a leaf, member ``i`` drawn from ``fold_in(k, i)``: the
+        # grouped kernel reads an expert's matrices out of it in place
+        return w(k, *shape, fan_in=fan_in, stacked=E)
 
     layers = []
     for l in range(L):

@@ -381,17 +381,13 @@ def prefill_kv_hidden_states(params: Params, tokens: jax.Array,
 
 def _use_paged_kernel(q_shape, pool_shape, dtype, page_tokens: int) -> bool:
     """Route the paged READ through the Pallas kernel? By what the code
-    can observe, after :func:`_use_flash`: on the TPU (the interpreter
-    would crawl on the CPU, where the masked read stays the path and the
-    kernel's reference), at shapes the kernel supports, and where the
-    ambient mesh leaves nothing for GSPMD to partition (a Mosaic call
-    cannot be; the pool of a multi-chip serve mesh keeps the masked
-    read)."""
-    if jax.default_backend() != "tpu":
-        return False
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.size > 1 and any(t == jax.sharding.AxisType.Auto
-                             for t in mesh.axis_types):
+    can observe, after :func:`_use_flash`: on one TPU chip
+    (``parallel.mesh.one_tpu_program``: on the CPU the masked read stays
+    the path and the kernel's reference, and the pool of a multi-chip
+    serve mesh keeps it, a Mosaic call being nothing GSPMD can partition)
+    at shapes the kernel supports."""
+    from tpudist.parallel.mesh import one_tpu_program
+    if not one_tpu_program():
         return False
     from tpudist.ops.pallas import paged_attention as pa
     return pa.supports(q_shape, pool_shape, dtype, page_tokens)

@@ -719,6 +719,13 @@ def serving_section(metrics: List[Dict[str, Any]],
         "kv_window_tokens_peak": s.get("kv_window_tokens_peak"),
         "moe_pairs_per_expert_mean": s.get("moe_pairs_per_expert_mean"),
         "moe_experts_hit_mean": s.get("moe_experts_hit_mean"),
+        # blocks of rows the expert routine ran (its loop's trips or its
+        # kernel's tiles), and from the engine's ``experts_path`` instant
+        # which of the two its dispatch program was lowered with
+        "moe_blocks_mean": s.get("moe_blocks_mean"),
+        "experts_path": next(
+            ((e.get("args") or {}).get("path") for e in events
+             if e.get("name") == "experts_path"), None),
         # a model that generates by diffusion over blocks (None elsewhere)
         "block_length": s.get("block_length"),
         "forwards_per_token": s.get("forwards_per_token"),
@@ -1250,7 +1257,12 @@ def to_markdown(report: Dict[str, Any]) -> str:
                       f"{sv['moe_pairs_per_expert_mean']} pair(s) an "
                       f"expert a layer a token step, "
                       f"{sv['moe_experts_hit_mean']} expert(s) hit a "
-                      f"layer a step (means over decode dispatches); "
+                      f"layer a step"
+                      + (f" in {sv['moe_blocks_mean']} block(s) of rows"
+                         if sv.get("moe_blocks_mean") is not None else "")
+                      + (f", experts_path {sv['experts_path']}"
+                         if sv.get("experts_path") else "")
+                      + f" (means over decode dispatches); "
                       f"kv state at peak: {sv['kv_pages_used_peak']}/"
                       f"{sv['kv_pages_total']} full-layer pages"
                       + (f", {sv['kv_window_tokens_peak']}/"

@@ -81,6 +81,19 @@ def build_mesh(cfg: Optional[ParallelConfig] = None,
     return Mesh(np.asarray(devices).reshape(sizes), AXIS_NAMES)
 
 
+def one_tpu_program() -> bool:
+    """Can a Mosaic kernel, which GSPMD cannot partition, be called here
+    as it is? On the TPU (the interpreter would crawl on the CPU, where
+    each kernel's XLA path stays the tests' and its reference) and where
+    the ambient mesh (``jax.set_mesh``) leaves nothing to partition: one
+    device, no mesh, or every axis manual."""
+    if jax.default_backend() != "tpu":
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    return not (mesh.size > 1 and any(
+        t == jax.sharding.AxisType.Auto for t in mesh.axis_types))
+
+
 # ------------------------------------------------- slice / fabric layout
 #
 # Multi-slice awareness: a TPU pod of several slices exposes

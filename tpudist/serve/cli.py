@@ -603,7 +603,7 @@ def _write_bench(path: str, args: argparse.Namespace,
             "speculate_k",
             "shared_prefix_len", "kv_window_tokens_total",
             "kv_window_tokens_peak", "moe_pairs_per_expert_mean",
-            "moe_experts_hit_mean")},
+            "moe_experts_hit_mean", "moe_blocks_mean")},
         "slo": slo_lib.slo_block(summary),
         "device": jax.devices()[0].device_kind,
     }

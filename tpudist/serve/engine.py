@@ -41,6 +41,13 @@ the CI lane):
   own for its two kinds of cache. The verify program runs the same
   read at window ``speculate_k``.
 
+An expert model's routed sum is ``models/dropless.routed`` in every
+program; whether it runs a layer's experts as one grouped Pallas kernel
+or as its loop of blocks is that routine's own routing by backend, mesh
+and shape, which the engine only reports (``experts_paths``; once a
+traced run as the ``experts_path`` instant, beside the blocks of rows
+each dispatch ran in ``read_stats``' ``moe_blocks``).
+
 With graceful degradation on (``adapt_ladder``), the contract
 generalises to one decode program PER LADDER RUNG, all compiled at
 warmup: a pressure downshift switches programs, it never traces one.
@@ -63,7 +70,7 @@ from jax import lax
 
 from tpudist.config import ModelConfig
 from tpudist.engine import OnMesh, _arg_specs
-from tpudist.models import get_model
+from tpudist.models import dropless, get_model
 from tpudist.obs import trace as trace_lib
 from tpudist.parallel import sharding as shd
 from tpudist.scopes import cast, scope, scoped
@@ -234,6 +241,7 @@ class PagedServeEngine:
         self.windowed = self.spec.window_layers > 0
         # a model whose programs count what they did (``read_stats``)
         self.counted = hasattr(self.model, "N_STATS")
+        self._path_said = False     # the ``experts_path`` instant is out
         if self.windowed and self.speculate_k:
             raise ValueError(
                 "--speculate-k over a model with window layers is not "
@@ -668,7 +676,8 @@ class PagedServeEngine:
 
         if self.windowed:
             # what THIS dispatch counts: token steps run, the model's own
-            state = state._replace(stats=jnp.zeros_like(state.stats))
+            state = state._replace(stats=jnp.zeros(
+                (1 + self.model.N_STATS,), jnp.int32))
         state, (toks, valid) = lax.scan(step, state, None, length=k)
         return state, toks, valid
 
@@ -756,14 +765,30 @@ class PagedServeEngine:
         the fence on that program's tokens: the counts are ready then."""
         if state.stats is None:
             return {}
-        steps, pairs, hit = (int(v) for v in np.asarray(state.stats))
+        steps, pairs, hit, blocks = (int(v) for v in
+                                     np.asarray(state.stats))
         steps = max(steps, 1)       # token steps, or a block's forwards
         cfg = self.model_cfg
         held = cfg.n_experts_held or cfg.n_experts
+        # blocks over experts hit: the share of second trips to an expert
         return {"moe_pairs_local": pairs,
                 "moe_pairs_per_expert": pairs / (
                     held * cfg.n_layers * steps),
-                "moe_experts_hit": hit / (cfg.n_layers * steps)}
+                "moe_experts_hit": hit / (cfg.n_layers * steps),
+                "moe_blocks": blocks / (cfg.n_layers * steps)}
+
+    def experts_paths(self, params) -> dict:
+        """Which way ``models/dropless.routed`` lowers this engine's
+        programs, by the routine's own rule inside this engine's mesh:
+        ``path`` the dispatch program's (a token step or a block a slot),
+        ``prefill`` the prefill's; each ``grouped`` or ``loop``."""
+        cfg, lp = self.model_cfg, params["layers"][0]
+        experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
+        with jax.set_mesh(self.mesh):
+            of = lambda n: dropless.path(experts, n, cfg.expert_top_k,
+                                         cfg.n_experts, self.dtype)
+            return {"path": of(self.slots * (self.block or 1)),
+                    "prefill": of(self.prompt_pad)}
 
     def read_block(self, state: PagedServeState) -> np.ndarray:
         """(block, slots): the denoising step at which each position of
@@ -799,6 +824,12 @@ class PagedServeEngine:
             da = jnp.ones((self.slots,), bool)
         else:
             da = jnp.asarray(dispatch_active, bool).reshape(self.slots)
+        if self.counted and not self._path_said \
+                and trace_lib.get().enabled:
+            # once a traced run: whether the grouped kernel engaged at all
+            self._path_said = True
+            trace_lib.get().instant("experts_path", cat="serve",
+                                    **self.experts_paths(params))
         if self.block:
             # tokens (block, slots): every position the dispatch computed
             # (-1 outside it); valid: the ones emitted. ``state.block_step``

@@ -328,7 +328,8 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
     counts_on = engine.counted
     forwards = 0                    # a block model's, summed over blocks
     window_peak = 0
-    moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0}
+    moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0,
+               "moe_blocks": 0.0}
     results: Dict[int, Dict[str, Any]] = {}
     generated = truncated = dispatches = 0
     drafted = accepted = 0          # speculative-draft acceptance
