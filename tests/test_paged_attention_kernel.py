@@ -122,6 +122,63 @@ def test_kernel_matches_masked_read(case):
             assert not out[s].any()
 
 
+# the LATENT call: one pool whose rows are keys and, in their first
+# ``v_width`` lanes, values; one kv "head" for all query heads; the scale an
+# argument; the grid over groups of slots. (id, heads, first position a
+# slot, pages a block, slots a grid step (None: all in one), dtype)
+LATENT = [
+    ("unequal-pages", 8, [6, 13, 0, 17], None, None, jnp.float32),
+    ("an-empty-slot", 8, [19, None, 12, 1], 3, None, jnp.float32),
+    ("groups-of-2", 8, [6, 13, 0, 17], 2, 2, jnp.float32),
+    ("groups-of-1", 8, [9, None, 2, 14], 2, 1, jnp.float32),
+    ("an-empty-group", 8, [None, None, 2, 14, 7, 3], 2, 2, jnp.float32),
+    ("a-last-group-empty", 8, [5, 11, None, None], 1, 2, jnp.float32),
+    ("bfloat16-groups", 16, [6, 13, 0, 17], 2, 2, jnp.bfloat16),
+]
+ROW, VW = 24, 16
+
+
+@pytest.mark.parametrize("case", LATENT, ids=[c[0] for c in LATENT])
+def test_latent_call_matches_masked_read(case):
+    _, h, first_pos, block_pages, group, dtype = case
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.normal(size=(L, 1, PAGES + 1, PT, ROW)), dtype)
+    table, pos = _table(first_pos, 1, rng.permutation(PAGES))
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    q = jnp.asarray(rng.normal(size=(len(first_pos), 1, h, ROW)), dtype)
+    scale = 0.3                     # not ROW ** -0.5
+    ref = T._masked_pool_read(q, pool, None, jnp.int32(1), table, pos, PT,
+                              scale=scale, v_width=VW)
+    out = pa.paged_attention(
+        q, pool, None, jnp.int32(1), pa.walk(table, pos, PT, PAGES + 1),
+        scale=scale, v_width=VW, block_pages=block_pages, slot_group=group,
+        interpret=pltpu.InterpretParams())
+    assert out.shape == ref.shape == (len(first_pos), 1, h, VW)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    live = [s for s, p in enumerate(first_pos) if p is not None]
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    assert np.isfinite(out).all()
+    for s, p in enumerate(first_pos):
+        if p is None:
+            assert not out[s].any()
+    # the scale is the argument's: the row's own width gives another answer
+    other = T._masked_pool_read(q, pool, None, jnp.int32(1), table, pos, PT,
+                                scale=ROW ** -0.5, v_width=VW)
+    assert np.abs(np.asarray(other, np.float32)[live] - ref[live]).max() \
+        > 1e-2
+
+
+def test_slot_groups_keep_one_step_where_the_rows_fit():
+    # every call before the latent one: one grid step
+    assert pa.slot_groups(16, 16, 128, 128, jnp.bfloat16) == 16
+    assert pa.slot_groups(128, 4 * 32, 128, 128, jnp.bfloat16) == 128
+    # 192 slots x 64 heads x (640 + 512) lanes: 28 MB, in steps of 32
+    assert pa.slot_groups(192, 64, 640, 512, jnp.bfloat16) == 32
+    assert pa.slot_groups(7, 64, 640, 512, jnp.float32) == 7
+    assert pa.slot_groups(191, 64, 640, 512, jnp.bfloat16) == 1
+
+
 @pytest.mark.parametrize("row", ["unmapped", "stale-beyond-a-gap",
                                  "position-past-the-row"])
 def test_discarded_slots_are_finite_and_in_bounds(row):
@@ -235,6 +292,13 @@ def test_supports_is_by_shape():
     assert not pa.supports((16, 1, 16, 128), pool, jnp.bfloat16, 8)
     assert not pa.supports((16, 1, 4, 128), (24, 2, 257, 64, 128),
                            jnp.bfloat16, 64)
+    # values out of the key rows: a whole number of their lanes
+    latent = (8, 1, 4097, 64, 640)
+    assert pa.supports((192, 1, 64, 640), latent, jnp.bfloat16, 64, 512)
+    assert not pa.supports((192, 1, 64, 640), latent, jnp.bfloat16, 64, 576)
+    assert not pa.supports((192, 1, 64, 576), (8, 1, 4097, 64, 576),
+                           jnp.bfloat16, 64, 512)
+    assert pa.pages_per_block(latent, jnp.bfloat16, 32) == 8
     assert pa.pages_per_block(pool, jnp.bfloat16, 20) == 8
     assert pa.pages_per_block(pool, jnp.bfloat16, 3) == 3
 

@@ -653,6 +653,25 @@ def test_report_serving_prints_the_experts_path_and_blocks(path):
     assert "experts held here" not in report_lib.to_markdown(plain)
 
 
+def test_report_serving_prints_the_identity_experts_share():
+    """A model with identity experts: their pairs beside all pairs routed,
+    one line; a model without them prints none."""
+    metrics = _serve_metrics()
+    metrics[-1].update(moe_pairs_per_expert_mean=1.79,
+                       moe_experts_hit_mean=10.9, moe_blocks_mean=10.9,
+                       moe_pairs_zero_mean=739.3, moe_pairs_all_mean=2221.4,
+                       moe_zero_share=0.3328,
+                       kv_pages_used_peak=7, kv_pages_total=16)
+    rep = report_lib.build_report(metrics, {})
+    assert rep["serving"]["moe_zero_share"] == 0.3328
+    line = next(ln for ln in report_lib.to_markdown(rep).splitlines()
+                if "identity experts" in ln)
+    assert "739.3 of 2221.4 pair(s) routed" in line and "33.3 %" in line
+    plain = report_lib.build_report(_serve_metrics(), {})
+    assert plain["serving"]["moe_zero_share"] is None
+    assert "identity experts" not in report_lib.to_markdown(plain)
+
+
 def test_report_serving_prints_what_the_engine_converted():
     """The ``weights_resident`` span (one a params tree the engine had to
     convert to its dtype) is the serving section's one line; a run whose

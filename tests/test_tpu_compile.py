@@ -78,6 +78,39 @@ def test_paged_attention_kernel_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# the `longcat` cell's decode read: 192 slots, 64 query heads as one group
+# over ONE pool of 8 sublayers x 4096 + 1 pages of 64 rows of 640 lanes
+# (576 values and 64 dead lanes), values the rows' first 512 lanes, the
+# scale the published 192 ** -0.5; 32 slots a grid step
+def test_paged_attention_kernel_compiles_at_the_latent_cells_shapes(
+        one_chip, no_compile_cache):
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, subs, n_pool, pt, maxp, vw = 192, 64, 8, 4097, 64, 32, 512
+    width = 640
+    dtype = jnp.bfloat16
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    pool = sds((subs, 1, n_pool, pt, width), dtype)
+    assert pa.supports((slots, 1, h, width), pool.shape, dtype, pt, vw)
+    assert pa.slot_groups(slots, h, width, vw, dtype) == 32
+
+    def read(q, pool, table, pos):
+        return pa.paged_attention(q, pool, None, 5,
+                                  pa.walk(table, pos, pt, n_pool),
+                                  scale=192 ** -0.5, v_width=vw)
+
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(read).lower(
+            sds((slots, 1, h, width), dtype), pool,
+            sds((slots, maxp), jnp.int32), sds((slots, 1), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attn_decode" in text
+    # one pool, handed over where it lies: no staged sublayer, no copy
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 # the `sdar` cell's expert layer: 128 experts of 2048 x 768 as one stack a
 # leaf, 512 tokens a dispatch in blocks of 64, 1024 a prefill in blocks of
 # 128, top-8

@@ -387,6 +387,58 @@ def test_paged_attention_kernel_matches_masked_read_on_chip(layer, window,
     assert not out[13].any()        # nothing mapped: skipped
 
 
+@pytest.mark.parametrize("sub", [0, 7])
+def test_latent_paged_attention_kernel_matches_masked_read_on_chip(sub):
+    """The kernel's LATENT call, Mosaic-compiled at the ``longcat`` cell's
+    widths (192 slots in grid steps of 32, 64 query heads as one group,
+    rows of 640 lanes whose first 512 are the values, the scale 192 **
+    -0.5) against the XLA masked read of the same one pool. The pool is a
+    tenth of the cell's: the masked read scores every slot against every
+    page. A whole grid step of slots with nothing mapped, slots of 1 to 6
+    pages, one with nothing."""
+    from tpudist.models import transformer as T
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, row, vw, subs, pages, pt, maxp = 192, 64, 640, 512, 8, 448, 64, 6
+    rng = np.random.default_rng(11)
+    pool = jnp.asarray(rng.normal(size=(subs, 1, pages + 1, pt, row)),
+                       jnp.bfloat16)
+    perm = list(rng.permutation(pages))
+    table = np.full((slots, maxp), -1, np.int32)
+    pos = np.zeros((slots, 1), np.int32)
+    first = [None if 64 <= s < 96 or s == 13 else int(rng.integers(
+        0, (1 + s % maxp) * pt)) for s in range(slots)]
+    for s, p in enumerate(first):
+        if p is not None:
+            pos[s] = p
+            n = p // pt + 1
+            table[s, :n] = [perm.pop() for _ in range(n)]
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    q = jnp.asarray(rng.normal(size=(slots, 1, h, row)) * 0.3, jnp.bfloat16)
+    assert T._use_paged_kernel(q.shape, pool.shape, pool.dtype, pt, vw)
+    assert pa.slot_groups(slots, h, row, vw, q.dtype) == 32
+    scale = 192 ** -0.5
+
+    @jax.jit
+    def kernel(q, pool, table, pos):
+        return pa.paged_attention(q, pool, None, sub,
+                                  pa.walk(table, pos, pt, pool.shape[2]),
+                                  scale=scale, v_width=vw)
+
+    ref = jax.jit(lambda q, pool, table, pos: T._masked_pool_read(
+        q, pool, None, sub, table, pos, pt, scale=scale, v_width=vw))(
+            q, pool, table, pos)
+    out = kernel(q, pool, table, pos)
+    assert out.shape == (slots, 1, h, vw)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    assert np.isfinite(out).all()
+    worst = max(float(np.abs(out[s] - ref[s]).max())
+                for s, p in enumerate(first) if p is not None)
+    assert worst < 4e-2, worst
+    for s, p in enumerate(first):
+        if p is None:
+            assert not out[s].any()
+
+
 def test_engine_greedy_tokens_match_masked_read_on_chip(monkeypatch):
     """The whole serve lane over 64 decode steps a request, the kernel's
     tokens against the masked read's (the parent's path), in float32 at

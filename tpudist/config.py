@@ -81,11 +81,38 @@ class ModelConfig:
     denoise_steps: int = 0        # denoising forwards a block (the commit
     # forward comes on top); 0: block_length, one position a step
     mask_token_id: int = 0        # what a position still masked holds
+    # longcatflash-only fields (model name "longcatflash": latent attention
+    # (MLA) over a latent paged cache, two attention sublayers and two
+    # dense FFNs a layer around one expert mix, identity experts). It
+    # reads n_experts (the router's real experts), n_experts_held,
+    # expert_first, expert_top_k, d_ff (one expert's width) and norm_eps
+    # too. The model module declares them its own (``CONFIG_FIELDS``):
+    # ``models.model_for`` refuses them set for a model that claims none
+    q_lora_rank: int = 0          # width of the query's latent
+    kv_lora_rank: int = 0         # width of the cached latent; > 0 makes
+    # the serve cache latent: one row a token an attention sublayer
+    qk_nope_head_dim: int = 0     # a head's query/key part without rope
+    qk_rope_head_dim: int = 0     # ... and with: one key shared by all heads
+    v_head_dim: int = 0           # a head's value
+    n_zero_experts: int = 0       # identity experts the router also scores
+    routed_scaling: float = 1.0   # times every chosen weight
+    d_ff_dense: int = 0           # width of the dense FFNs beside the mix
 
     @property
     def head_size(self) -> int:
         """Width of one attention head: the ONE place that says it."""
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def latent_row(self) -> int:
+        """Values of one cached row of a latent cache as it is STORED: the
+        latent and the shared rope key (``kv_lora_rank +
+        qk_rope_head_dim``: 576) up to whole lanes of 128 (640: the rest
+        are dead lanes, written 0 and scored against 0). 0 for a model
+        without latent attention."""
+        if not self.kv_lora_rank:
+            return 0
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
 @dataclass(frozen=True)

@@ -35,7 +35,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from tpudist.config import TrainConfig
-from tpudist.models import get_model
+from tpudist.models import model_for
 from tpudist.parallel import sharding as shd
 from tpudist.scopes import scope
 from tpudist.utils import compat
@@ -264,7 +264,7 @@ def make_loss_fn(cfg: TrainConfig, mesh: Mesh | None = None, *,
     ``constrain_logits`` is only legal (and only needed) under the
     jit+shardings train path — a NamedSharding constraint inside the
     fully-manual shard_map DP path is an error."""
-    model = get_model(cfg.model.name)
+    model = model_for(cfg.model)
     dt = _compute_dtype(cfg)
     if (mesh is not None and mesh.shape.get("expert", 1) > 1
             and cfg.model.name != "moe"):
@@ -342,7 +342,7 @@ def init_state(key: jax.Array, cfg: TrainConfig,
     """Init params + opt state, placed into their sharded layout if a mesh is
     given. Init is seeded → deterministic across process counts (the
     convergence oracle depends on this; SURVEY.md §7 "hard parts")."""
-    model = get_model(cfg.model.name)
+    model = model_for(cfg.model)
     params = model.init(key, cfg.model)
     tx = make_optimizer(cfg)
     opt_state = tx.init(params)
@@ -357,7 +357,7 @@ def state_shardings(cfg: TrainConfig, mesh: Mesh) -> TrainState:
     """NamedShardings for the full TrainState. Opt-state moments share the
     params' layout (ZeRO-style: optimizer state lives where the shard
     lives); scalar leaves are replicated."""
-    model = get_model(cfg.model.name)
+    model = model_for(cfg.model)
     params_shape = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), cfg.model))
     # drop axes that don't divide a dim (vocab 97 over fsdp=2 → replicated)

@@ -379,7 +379,8 @@ def prefill_kv_hidden_states(params: Params, tokens: jax.Array,
     return rmsnorm(x, params["final_norm"]), {"k": ck, "v": cv}
 
 
-def _use_paged_kernel(q_shape, pool_shape, dtype, page_tokens: int) -> bool:
+def _use_paged_kernel(q_shape, pool_shape, dtype, page_tokens: int,
+                      v_width: int = 0) -> bool:
     """Route the paged READ through the Pallas kernel? By what the code
     can observe, after :func:`_use_flash`: on one TPU chip
     (``parallel.mesh.one_tpu_program``: on the CPU the masked read stays
@@ -390,11 +391,11 @@ def _use_paged_kernel(q_shape, pool_shape, dtype, page_tokens: int) -> bool:
     if not one_tpu_program():
         return False
     from tpudist.ops.pallas import paged_attention as pa
-    return pa.supports(q_shape, pool_shape, dtype, page_tokens)
+    return pa.supports(q_shape, pool_shape, dtype, page_tokens, v_width)
 
 
 def _masked_pool_read(q, pool_k, pool_v, layer, page_table, positions,
-                      page_tokens: int):
+                      page_tokens: int, scale=None, v_width: int = 0):
     """The paged read in plain XLA, gather-free: the CPU path and the
     reference the Pallas kernel is held to. The layer's page set is one
     dynamic index on the layer axis; ownership is a one-hot compare of
@@ -404,7 +405,9 @@ def _masked_pool_read(q, pool_k, pool_v, layer, page_table, positions,
     the layer's whole flattened page set with ``owned & (key_pos <=
     query_pos)`` masking: stale pages, other slots' pages and the trash
     page all mask to exp(-inf) = 0 exactly. It reads and scores every
-    page of the layer whatever the slots own."""
+    page of the layer whatever the slots own. ``scale`` and ``v_width``
+    are the kernel's: a latent cache hands one pool (``pool_v`` None), its
+    values the key rows' first ``v_width`` lanes, and its own scale."""
     s, w, h, hd = q.shape
     kv, n_pool, pt = pool_k.shape[1], pool_k.shape[2], page_tokens
     maxp = page_table.shape[1]
@@ -419,19 +422,23 @@ def _masked_pool_read(q, pool_k, pool_v, layer, page_table, positions,
             & (kpos[:, None, :, :] <= positions[:, :, None, None])
         mask = mask.reshape(s, w, n_pool * pt)                # (s, w, keys)
         kf = lax.dynamic_index_in_dim(pool_k, layer, keepdims=False)
-        vf = lax.dynamic_index_in_dim(pool_v, layer, keepdims=False)
+        if not v_width:
+            vf = lax.dynamic_index_in_dim(pool_v, layer, keepdims=False)
         kf = kf.reshape(kv, n_pool * pt, hd).astype(q.dtype)
-        vf = vf.reshape(kv, n_pool * pt, hd).astype(q.dtype)
+        vf = kf[..., :v_width] if v_width else \
+            vf.reshape(kv, n_pool * pt, hd).astype(q.dtype)
 
     with scope("attn/core"):
         qg = q.reshape(s, w, kv, h // kv, hd)   # GQA: group per kv head
-        scores = jnp.einsum("swkgd,knd->swkgn", qg, kf) / jnp.sqrt(
-            jnp.asarray(hd, q.dtype))
+        scores = jnp.einsum("swkgd,knd->swkgn", qg, kf)
+        scores = scores / jnp.sqrt(jnp.asarray(hd, q.dtype)) \
+            if scale is None else scores * jnp.asarray(scale, q.dtype)
         scores = jnp.where(mask[:, :, None, None, :], scores,
                            jnp.asarray(-1e30, scores.dtype))
         probs = jax.nn.softmax(scores.astype(jnp.float32),
                                axis=-1).astype(q.dtype)
-        return jnp.einsum("swkgn,knd->swkgd", probs, vf).reshape(s, w, h, hd)
+        return jnp.einsum("swkgn,knd->swkgd", probs, vf).reshape(
+            s, w, h, v_width or hd)
 
 
 def _paged_attention(q, k_new, v_new, pool_k, pool_v, layer, page_table,

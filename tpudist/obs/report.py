@@ -723,6 +723,11 @@ def serving_section(metrics: List[Dict[str, Any]],
         # kernel's tiles), and from the engine's ``experts_path`` instant
         # which of the two its dispatch program was lowered with
         "moe_blocks_mean": s.get("moe_blocks_mean"),
+        # a model with identity experts (None elsewhere): their pairs and
+        # all pairs routed, a layer a token step, and the former's share
+        "moe_pairs_zero_mean": s.get("moe_pairs_zero_mean"),
+        "moe_pairs_all_mean": s.get("moe_pairs_all_mean"),
+        "moe_zero_share": s.get("moe_zero_share"),
         "experts_path": next(
             ((e.get("args") or {}).get("path") for e in events
              if e.get("name") == "experts_path"), None),
@@ -1269,6 +1274,13 @@ def to_markdown(report: Dict[str, Any]) -> str:
                          f"{sv['kv_window_tokens_total']} window tokens a "
                          f"window layer"
                          if sv.get("kv_window_tokens_total") else ""), ""]
+        if sv.get("moe_zero_share") is not None:
+            lines += [f"- identity experts: "
+                      f"{sv['moe_pairs_zero_mean']} of "
+                      f"{sv['moe_pairs_all_mean']} pair(s) routed a layer "
+                      f"a token step go to an expert that returns its "
+                      f"input ({100 * sv['moe_zero_share']:.1f} % of the "
+                      f"routed work, done without a matrix)", ""]
         if sv.get("weights_resident"):
             wr = sv["weights_resident"]
             lines += [f"- weights at rest: {wr['leaves_cast']} of "

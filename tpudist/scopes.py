@@ -83,17 +83,33 @@ BLOCK_SCOPES: Tuple[str, ...] = (
                         # tokens that writes the K/V the cache keeps
 )
 
-_ALL = SCOPES + MODEL_SCOPES + BLOCK_SCOPES
+# The scopes of latent attention and of identity experts (``longcatflash``),
+# in a third tuple for the same reason: accepted tests hold
+# ``MODEL_SCOPES``' words, and ``MODEL_SCOPES + BLOCK_SCOPES``' words, equal
+# to the word lists of the metric files that read them
+# (``perfbench/tests/test_cohere2moe_cell.py``, ``test_sdar_cell.py``); the
+# metrics that read these bring a reader name of their own.
+LATENT_SCOPES: Tuple[str, ...] = (
+    "attn/latent_q",    # query down, norm, up (and in decode the product
+                        # with W_uk that carries it into the latent space)
+    "attn/latent_kv",   # the row the cache keeps: down, norm, scale
+    "attn/latent_up",   # prefill: the latent expanded into every head's k
+                        # and v; decode: W_uv on the values read
+    "moe/zero",         # the identity experts' term: summed weights x input
+)
+
+_ALL = SCOPES + MODEL_SCOPES + BLOCK_SCOPES + LATENT_SCOPES
 _WORDS = frozenset(w for s in _ALL for w in s.split("/"))
 _WRAPPED = re.compile(r"^(?:[A-Za-z_]+\()*([A-Za-z0-9_.\-]+)\)*$")
 
 
 def scope(name: str):
     """``jax.named_scope(name)`` for a name of :data:`SCOPES`,
-    :data:`MODEL_SCOPES` or :data:`BLOCK_SCOPES` only."""
+    :data:`MODEL_SCOPES`, :data:`BLOCK_SCOPES` or :data:`LATENT_SCOPES`
+    only."""
     if name not in _ALL:
         raise ValueError(f"{name!r} is not one of tpudist.scopes.SCOPES, "
-                         f"MODEL_SCOPES or BLOCK_SCOPES")
+                         f"MODEL_SCOPES, BLOCK_SCOPES or LATENT_SCOPES")
     import jax
     return jax.named_scope(name)
 

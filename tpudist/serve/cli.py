@@ -52,7 +52,7 @@ def parse_args(argv: Optional[Sequence[str]] = None
         description="tpudist serving acceptance lane: continuous "
                     "batching + sharded KV cache + latency-SLO verdict")
     p.add_argument("--model", choices=("transformer", "moe", "cohere2moe",
-                                       "sdarmoe"),
+                                       "sdarmoe", "longcatflash"),
                    default="transformer",
                    help="cohere2moe: parallel block, window and NoPE-full "
                         "attention by layer, sigmoid-routed experts of "
@@ -61,7 +61,13 @@ def parse_args(argv: Optional[Sequence[str]] = None
                         "sdarmoe: generation by diffusion over blocks of "
                         "4 (the mask token is the vocabulary's last id), "
                         "softmax-routed experts all held here, q/k norm, "
-                        "untied head")
+                        "untied head. longcatflash: latent attention over "
+                        "a latent paged cache (--kv-lora-rank and the "
+                        "head widths below), two attention sublayers and "
+                        "two dense FFNs of --d-ff-dense a layer around "
+                        "one mix of --n-experts real experts of --d-ff "
+                        "(--n-experts-held here) and --n-zero-experts "
+                        "identity ones, untied head")
     p.add_argument("--vocab-size", type=int, default=256)
     p.add_argument("--n-layers", type=int, default=2)
     p.add_argument("--d-model", type=int, default=64)
@@ -81,6 +87,23 @@ def parse_args(argv: Optional[Sequence[str]] = None
                         "router stays --n-experts wide (0: all)")
     p.add_argument("--n-shared-experts", type=int, default=0,
                    help="cohere2moe: shared experts, averaged")
+    p.add_argument("--q-lora-rank", type=int, default=0,
+                   help="longcatflash: width of the query's latent")
+    p.add_argument("--kv-lora-rank", type=int, default=0,
+                   help="longcatflash: width of the cached latent")
+    p.add_argument("--qk-nope-head-dim", type=int, default=0,
+                   help="longcatflash: a head's query/key part without "
+                        "rope")
+    p.add_argument("--qk-rope-head-dim", type=int, default=0,
+                   help="longcatflash: ... and with (one key for all "
+                        "heads)")
+    p.add_argument("--v-head-dim", type=int, default=0,
+                   help="longcatflash: a head's value")
+    p.add_argument("--n-zero-experts", type=int, default=0,
+                   help="longcatflash: identity experts the router also "
+                        "scores")
+    p.add_argument("--d-ff-dense", type=int, default=0,
+                   help="longcatflash: width of the two dense FFNs a layer")
     p.add_argument("--slots", type=int, default=DEFAULT_SLOTS,
                    help="concurrent sequences (KV cache pages)")
     p.add_argument("--max-seq", type=int, default=DEFAULT_MAX_SEQ,
@@ -300,7 +323,17 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
         # engine has built), the mask token the vocabulary's last id
         **({"block_length": 4, "mask_token_id": args.vocab_size - 1,
             "rope_theta": 1e6, "norm_eps": 1e-6}
-           if args.model == "sdarmoe" else {}))
+           if args.model == "sdarmoe" else {}),
+        # the family's released settings beside the widths the flags give
+        **({"q_lora_rank": args.q_lora_rank,
+            "kv_lora_rank": args.kv_lora_rank,
+            "qk_nope_head_dim": args.qk_nope_head_dim,
+            "qk_rope_head_dim": args.qk_rope_head_dim,
+            "v_head_dim": args.v_head_dim,
+            "n_zero_experts": args.n_zero_experts,
+            "d_ff_dense": args.d_ff_dense, "routed_scaling": 6.0,
+            "rope_theta": 1e7, "norm_eps": 1e-5}
+           if args.model == "longcatflash" else {}))
     mesh = build_mesh(ParallelConfig())
     # same resolver as the train lane (flag > $TPUDIST_TRACE > on for
     # the switch; --trace-dir > $TPUDIST_TRACE_DIR > --save-dir for the
@@ -603,7 +636,9 @@ def _write_bench(path: str, args: argparse.Namespace,
             "speculate_k",
             "shared_prefix_len", "kv_window_tokens_total",
             "kv_window_tokens_peak", "moe_pairs_per_expert_mean",
-            "moe_experts_hit_mean", "moe_blocks_mean")},
+            "moe_experts_hit_mean", "moe_blocks_mean",
+            "moe_pairs_zero_mean", "moe_pairs_all_mean",
+            "moe_zero_share")},
         "slo": slo_lib.slo_block(summary),
         "device": jax.devices()[0].device_kind,
     }

@@ -4,6 +4,11 @@ by diffusion over blocks (``ModelConfig.block_length``) gets the same two:
 its prefill writes the prompt's whole blocks and yields no token, and its
 dispatch is the block-denoising program (``_paged_denoise_body``), chosen
 here by what the model declares as ``windowed`` chooses the ring programs.
+A model with latent attention (``ModelConfig.kv_lora_rank``) gets the same
+two over ONE pool of latent rows (``latent``: ``_latent_prefill`` seeds it
+from every attention sublayer's rows, the decode step carries it alone;
+``PagedServeState.pool_v`` is None), and samples through its own untied
+head.
 
 The pjit/TPUv4 discipline that keeps the training loop honest (one
 compiled program per run, traced scalars for everything that varies)
@@ -38,15 +43,22 @@ the CI lane):
   CPU, on a multi-chip mesh and at any other shape the XLA masked read
   (``transformer._masked_pool_read``) stages the layer's whole page set
   and masks what a slot does not own. ``cohere2moe`` has a read of its
-  own for its two kinds of cache. The verify program runs the same
-  read at window ``speculate_k``.
+  own for its two kinds of cache; ``longcatflash`` hands the kernel (and
+  off the chip the masked read) its one latent pool, the width of the
+  values inside a row and its own softmax scale
+  (``longcatflash.latent_paged_attention``). The verify program runs the
+  same read at window ``speculate_k``; over a latent cache it is refused
+  in words, as is a shared prefix.
 
 An expert model's routed sum is ``models/dropless.routed`` in every
 program; whether it runs a layer's experts as one grouped Pallas kernel
 or as its loop of blocks is that routine's own routing by backend, mesh
 and shape, which the engine only reports (``experts_paths``; once a
 traced run as the ``experts_path`` instant, beside the blocks of rows
-each dispatch ran in ``read_stats``' ``moe_blocks``).
+each dispatch ran in ``read_stats``' ``moe_blocks``). ``read_stats`` hands
+back the routine's three counts and, under the model's own names
+(``EXTRA_STATS``), whatever the model counts beyond them (``longcatflash``:
+pairs on identity experts and all pairs routed).
 
 With graceful degradation on (``adapt_ladder``), the contract
 generalises to one decode program PER LADDER RUNG, all compiled at
@@ -70,7 +82,7 @@ from jax import lax
 
 from tpudist.config import ModelConfig
 from tpudist.engine import OnMesh, _arg_specs
-from tpudist.models import dropless, get_model
+from tpudist.models import dropless, model_for
 from tpudist.obs import trace as trace_lib
 from tpudist.parallel import sharding as shd
 from tpudist.scopes import cast, scope, scoped
@@ -89,7 +101,8 @@ class PagedServeState(NamedTuple):
     dispatch as a small traced int32 array."""
 
     pool_k: jax.Array        # (L, kv, pages+1, page_tokens, head_dim)
-    pool_v: jax.Array
+    pool_v: Optional[jax.Array]     # None: a latent cache is ONE pool,
+    # (sublayers, 1, pages+1, page_tokens, latent_row), in ``pool_k``
     lengths: jax.Array       # (slots,) int32: tokens in cache per slot
     last_token: jax.Array    # (slots,) int32: newest token, not yet cached
     active: jax.Array        # (slots,) bool: slot holds a live sequence
@@ -119,7 +132,7 @@ def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
     model's own (``model.init``'s float32, or what a leafwise init makes
     in place): the ENGINE owns the dtype at rest and converts a tree it
     is handed once (``PagedServeEngine._resident``)."""
-    model = get_model(model_cfg.name)
+    model = model_for(model_cfg)
     if getattr(model, "LEAFWISE_INIT", False):
         # a model whose float32 whole does not fit the chip that serves
         # it: every leaf is made where it will live, in its dtype at rest
@@ -198,7 +211,7 @@ class PagedServeEngine:
                 f"--speculate-k must be 0 (off) or >= 2 (window of "
                 f"last_token + drafts), got {speculate_k}")
         self.model_cfg = model_cfg
-        self.model = get_model(model_cfg.name)
+        self.model = model_for(model_cfg)
         self.mesh = mesh
         self.slots, self.max_seq = int(slots), int(max_seq)
         # a model that declares a block length generates by diffusion over
@@ -239,6 +252,9 @@ class PagedServeEngine:
         # two kinds of cache state: the programs of such a model are
         # bodies of their own below, the others' are untouched
         self.windowed = self.spec.window_layers > 0
+        # a third kind: one latent row a token an attention sublayer, no V
+        # pool; its prefill seeds and its decode step reads that one pool
+        self.latent = self.spec.latent
         # a model whose programs count what they did (``read_stats``)
         self.counted = hasattr(self.model, "N_STATS")
         self._path_said = False     # the ``experts_path`` instant is out
@@ -247,6 +263,11 @@ class PagedServeEngine:
                 "--speculate-k over a model with window layers is not "
                 "built: a rejected draft would have to be unwound from "
                 "the ring")
+        if self.latent and self.speculate_k:
+            raise ValueError(
+                "--speculate-k over a latent cache is not built: the "
+                "verify forward has no absorbed form over a window of "
+                "drafts that tests or the chip hold")
         self.alloc = kvcache.PageAllocator(self.spec)
         self.prefill_traces: list = []
         self.decode_traces: list = []
@@ -369,8 +390,12 @@ class PagedServeEngine:
             return logits
 
     def _greedy(self, params, h):
-        """Greedy next token from final-normed hidden states."""
-        logits = self._tied_logits(params, h)
+        """Greedy next token from final-normed hidden states, through the
+        model's own head where it has one (untied), else the tied one."""
+        if hasattr(self.model, "head_logits"):
+            logits = self.model.head_logits(params, h, self.dtype)
+        else:
+            logits = self._tied_logits(params, h)
         with scope("sample"):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -390,11 +415,16 @@ class PagedServeEngine:
         # into the slot's pages, a whole page at a time
         # (``_scatter_pages``). A model with window layers hands back
         # every layer's k/v itself and seeds both kinds of state
-        # (``_windowed_prefill``).
+        # (``_windowed_prefill``); one with latent attention every
+        # sublayer's rows (``_latent_prefill``).
         if self.windowed:
             h, cache = self._windowed_prefill(params, state, tokens,
                                               prompt_len, slot, page_row,
                                               shared_len)
+        elif self.latent:
+            h, cache = self._latent_prefill(params, state, tokens,
+                                            prompt_len, page_row,
+                                            shared_len)
         else:
             scratch_shape = (spec.n_layers, 1, self.prompt_pad,
                              spec.n_kv_heads, spec.head_dim)
@@ -406,7 +436,7 @@ class PagedServeEngine:
         h_last = lax.dynamic_index_in_dim(h, prompt_len - 1, axis=1,
                                           keepdims=False)
         first = self._greedy(params, h_last)[0]
-        if not self.windowed:
+        if not (self.windowed or self.latent):
             with scope("kv_scatter"):
                 pk, pv = self._scatter_pages(
                     state.pool_k, state.pool_v, scratch["k"], scratch["v"],
@@ -424,7 +454,9 @@ class PagedServeEngine:
     def _scatter_pages(self, pool_k, pool_v, k, v, page_row, prompt_len,
                        shared_len):
         """A prompt's K/V, (L, 1, prompt_pad, kv, hd) of the pool's
-        layers, copied into the slot's pages. Pages wholly below
+        layers, copied into the slot's pages (a latent cache: its one
+        pool and its rows, ``pool_v`` and ``v`` None; a tuple of one comes
+        back). Pages wholly below
         ``shared_len`` are skipped (they are the shared prefix, already
         holding bitwise-identical content); those, the pages past the
         prompt and unmapped ones route to the trash page. The prompt's
@@ -448,7 +480,8 @@ class PagedServeEngine:
                           spec.head_dim)
             return c.transpose(1, 0, 3, 2, 4)[:, :, :, None]
 
-        blocks = (page_blocks(k), page_blocks(v))
+        pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
+        blocks = tuple(page_blocks(c) for c in (k, v)[:len(pools)])
 
         def put(j, pools):
             return tuple(
@@ -456,7 +489,23 @@ class PagedServeEngine:
                                          (0, 0, pg[j], 0, 0))
                 for pool, b in zip(pools, blocks))
 
-        return lax.fori_loop(0, n_pp, put, (pool_k, pool_v))
+        return lax.fori_loop(0, n_pp, put, pools)
+
+    def _latent_prefill(self, params, state: PagedServeState, tokens,
+                        prompt_len, page_row, shared_len):
+        """The forward of a model with latent attention (the expanded
+        form over the whole prompt), and the one pool seeded from the rows
+        it hands back, every attention sublayer's into the slot's pages."""
+        h, rows, counts = self.model.prefill_hidden_states(
+            params, tokens, self.model_cfg, dtype=self.dtype,
+            prompt_len=prompt_len)
+        with scope("kv_scatter"):
+            # (sublayers, 1, pad, row) -> the pool's one "kv head"
+            pk, = self._scatter_pages(
+                state.pool_k, None, jnp.stack(rows)[:, :, :, None, :], None,
+                page_row, prompt_len, shared_len)
+        return h, {"pool_k": pk, "stats": jnp.concatenate(
+            [jnp.ones((1,), jnp.int32), counts])}
 
     def _windowed_prefill(self, params, state: PagedServeState, tokens,
                           prompt_len, slot, page_row, shared_len):
@@ -605,6 +654,10 @@ class PagedServeEngine:
         page (``prefix_len % page_tokens`` positions) routes to trash
         here; admissions recompute it into their first private page —
         the copy-on-write fork, done eagerly by recomputation."""
+        if self.latent:
+            raise ValueError(
+                "a shared prefix over a latent cache is not built: no test "
+                "or chip run holds shared pages of latent rows yet")
         params = self._resident(params)
         pages = self.alloc.register_shared(prefix_len)
         if not pages:
@@ -634,22 +687,32 @@ class PagedServeEngine:
                 act = st.active & dispatch_active
                 pos = jnp.minimum(st.lengths, self.max_seq - 1)
                 step_args = dict(
-                    dtype=self.dtype, pool_k=st.pool_k, pool_v=st.pool_v,
+                    dtype=self.dtype,
                     page_table=page_table, positions=pos[:, None],
                     write_ok=(act & (st.lengths < self.max_seq))[:, None],
                     page_tokens=self.spec.page_tokens)
+                # K and V pools; a latent cache hands its one pool alone
+                pools = dict(pool_k=st.pool_k, pool_v=st.pool_v)
                 if self.windowed:
                     h, pk, pv, rk, rv, counts = \
                         self.model.paged_hidden_states(
                             params, st.last_token[:, None], self.model_cfg,
-                            ring_k=st.ring_k, ring_v=st.ring_v, **step_args)
+                            ring_k=st.ring_k, ring_v=st.ring_v, **pools,
+                            **step_args)
                     cache = {"ring_k": rk, "ring_v": rv,
                              "stats": st.stats + jnp.concatenate(
                                  [jnp.ones((1,), jnp.int32), counts])}
+                elif self.latent:
+                    h, pk, counts = self.model.paged_hidden_states(
+                        params, st.last_token[:, None], self.model_cfg,
+                        pool=st.pool_k, **step_args)
+                    pv = None
+                    cache = {"stats": st.stats + jnp.concatenate(
+                        [jnp.ones((1,), jnp.int32), counts])}
                 else:
                     h, pk, pv = self.model.paged_hidden_states(
                         params, st.last_token[:, None], self.model_cfg,
-                        **step_args)
+                        **pools, **step_args)
                     cache = {}
                 nxt = self._greedy(params, h[:, 0])
                 new_len = jnp.where(act, st.lengths + 1, st.lengths)
@@ -674,7 +737,7 @@ class PagedServeEngine:
                 (st.active & dispatch_active).any(), run, skip, st)
             return st, (tok, valid)
 
-        if self.windowed:
+        if self.windowed or self.latent:
             # what THIS dispatch counts: token steps run, the model's own
             state = state._replace(stats=jnp.zeros(
                 (1 + self.model.N_STATS,), jnp.int32))
@@ -765,17 +828,22 @@ class PagedServeEngine:
         the fence on that program's tokens: the counts are ready then."""
         if state.stats is None:
             return {}
-        steps, pairs, hit, blocks = (int(v) for v in
-                                     np.asarray(state.stats))
+        steps, pairs, hit, blocks, *more = (int(v) for v in
+                                            np.asarray(state.stats))
         steps = max(steps, 1)       # token steps, or a block's forwards
         cfg = self.model_cfg
         held = cfg.n_experts_held or cfg.n_experts
         # blocks over experts hit: the share of second trips to an expert
-        return {"moe_pairs_local": pairs,
-                "moe_pairs_per_expert": pairs / (
-                    held * cfg.n_layers * steps),
-                "moe_experts_hit": hit / (cfg.n_layers * steps),
-                "moe_blocks": blocks / (cfg.n_layers * steps)}
+        out = {"moe_pairs_local": pairs,
+               "moe_pairs_per_expert": pairs / (
+                   held * cfg.n_layers * steps),
+               "moe_experts_hit": hit / (cfg.n_layers * steps),
+               "moe_blocks": blocks / (cfg.n_layers * steps)}
+        # what the model counts beyond the routine's three, under its own
+        # names (``longcatflash``: pairs on identity experts, all pairs)
+        for name, v in zip(getattr(self.model, "EXTRA_STATS", ()), more):
+            out[name] = v / (cfg.n_layers * steps)
+        return out
 
     def experts_paths(self, params) -> dict:
         """Which way ``models/dropless.routed`` lowers this engine's
@@ -785,8 +853,9 @@ class PagedServeEngine:
         cfg, lp = self.model_cfg, params["layers"][0]
         experts = (lp["e_gate"], lp["e_up"], lp["e_down"])
         with jax.set_mesh(self.mesh):
-            of = lambda n: dropless.path(experts, n, cfg.expert_top_k,
-                                         cfg.n_experts, self.dtype)
+            of = lambda n: dropless.path(
+                experts, n, cfg.expert_top_k,
+                cfg.n_experts + cfg.n_zero_experts, self.dtype)
             return {"path": of(self.slots * (self.block or 1)),
                     "prefill": of(self.prompt_pad)}
 

@@ -27,6 +27,15 @@ query head count happens inside the attention math — an 8×-grouped
 model's cache is 8× smaller than a naive full-head cache, which is the
 difference between fitting long contexts in HBM or not.
 
+A model with LATENT attention (``ModelConfig.kv_lora_rank``) keeps a third
+kind of row: ONE row a token an attention sublayer, ``[latent | rope key]``
+up to whole lanes (``ModelConfig.latent_row``: 576 values stored at 640),
+with no kv-head axis to speak of and no V pool: the values are the row's
+own first lanes. The pool is then ``(sublayers, 1, pages+1, page_tokens,
+latent_row)`` and ``init_paged_cache`` makes ``"v": None``. 1,280 B a token
+a sublayer in bfloat16 where 64 heads of 128 as K and V rows are 32,768 B:
+what lets such a model serve hundreds of sequences a chip.
+
 Sharding rides the existing mesh machinery: ``parallel.sharding.
 paged_kv_cache_specs`` is the ``param_specs``-style single source for
 the PartitionSpec (pages over the batch axes, kv heads over tensor),
@@ -64,7 +73,12 @@ class PagedCacheSpec:
     window + ``ring_margin`` positions (the window a query reads plus
     what one dispatch may append before it reads), statically one per
     slot: a window layer's state never grows with the sequence and is
-    never what an admission waits for."""
+    never what an admission waits for.
+
+    A model with LATENT attention (``cfg.kv_lora_rank``) has ``latent``
+    set: ``n_layers`` counts its attention SUBLAYERS (two a layer),
+    ``n_kv_heads`` is 1, ``head_dim`` the stored row's width, and there is
+    one pool, not a K and a V."""
 
     n_layers: int
     slots: int
@@ -76,6 +90,7 @@ class PagedCacheSpec:
     dtype: Any = jnp.float32
     window_layers: int = 0
     ring_tokens: int = 0
+    latent: bool = False
 
     @classmethod
     def from_model(cls, cfg: ModelConfig, *, slots: int, max_seq: int,
@@ -91,6 +106,12 @@ class PagedCacheSpec:
             # sizing (admission can never be denied); operators shrink
             # it to trade capacity for sustained concurrency
             pages = slots * maxp
+        if cfg.kv_lora_rank:
+            return cls(n_layers=2 * cfg.n_layers, slots=slots,
+                       max_seq=max_seq, n_kv_heads=1,
+                       head_dim=cfg.latent_row,
+                       page_tokens=int(page_tokens), pages=int(pages),
+                       dtype=dtype, latent=True)
         n_window = 0
         if cfg.sliding_window:
             from tpudist.models import get_model
@@ -136,17 +157,22 @@ class PagedCacheSpec:
         return 2 * n * jnp.dtype(self.dtype).itemsize
 
     @property
+    def pools(self) -> int:
+        """Pools of ``pool_shape``: K and V, or a latent cache's one."""
+        return 1 if self.latent else 2
+
+    @property
     def bytes(self) -> int:
         """The PAGED footprint: pool pages (trash included — it is
-        real HBM) × page bytes for K and V, plus the page-table
-        overhead, plus the window layers' rings. This is the number
-        serve_tick reports: what is actually allocated, not ``slots ×
-        max_seq``."""
+        real HBM) × page bytes for K and V (a latent cache's one pool,
+        dead lanes included), plus the page-table overhead, plus the
+        window layers' rings. This is the number serve_tick reports: what
+        is actually allocated, not ``slots × max_seq``."""
         n = 1
         for d in self.pool_shape:
             n *= d
-        return 2 * n * jnp.dtype(self.dtype).itemsize + self.table_bytes \
-            + self.window_bytes
+        return self.pools * n * jnp.dtype(self.dtype).itemsize \
+            + self.table_bytes + self.window_bytes
 
 
 def paged_cache_shardings(spec: PagedCacheSpec, mesh) -> Any:
@@ -161,13 +187,14 @@ def paged_cache_shardings(spec: PagedCacheSpec, mesh) -> Any:
 def init_paged_cache(spec: PagedCacheSpec, mesh=None
                      ) -> Dict[str, jax.Array]:
     """Zero-initialised paged ``{"k", "v"}`` pool (trash page included),
-    placed to its mesh sharding when one is given."""
+    placed to its mesh sharding when one is given. A latent cache is ONE
+    pool: ``"v"`` is None."""
     k = jnp.zeros(spec.pool_shape, spec.dtype)
-    v = jnp.zeros(spec.pool_shape, spec.dtype)
+    v = None if spec.latent else jnp.zeros(spec.pool_shape, spec.dtype)
     if mesh is not None:
         sh = paged_cache_shardings(spec, mesh)
         k = jax.device_put(k, sh)
-        v = jax.device_put(v, sh)
+        v = None if v is None else jax.device_put(v, sh)
     return {"k": k, "v": v}
 
 
@@ -249,7 +276,8 @@ class PageAllocator:
         else:
             margin = STAGING_STATE_HEADROOM * float(params_bytes)
             self.bound_source = "heuristic"
-        page_bytes = 2 * self.spec.n_layers * self.spec.page_tokens \
+        page_bytes = self.spec.pools * self.spec.n_layers \
+            * self.spec.page_tokens \
             * self.spec.n_kv_heads * self.spec.head_dim \
             * jnp.dtype(self.spec.dtype).itemsize
         avail = float(hbm_bytes) - margin - self.spec.table_bytes \

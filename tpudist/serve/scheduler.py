@@ -330,6 +330,10 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
     window_peak = 0
     moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0,
                "moe_blocks": 0.0}
+    # a model with identity experts counts their pairs beside all pairs
+    # routed (``engine.read_stats``: the model's ``EXTRA_STATS``)
+    moe_sum.update(dict.fromkeys(
+        getattr(engine.model, "EXTRA_STATS", ()), 0.0))
     results: Dict[int, Dict[str, Any]] = {}
     generated = truncated = dispatches = 0
     drafted = accepted = 0          # speculative-draft acceptance
@@ -873,6 +877,11 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
         **{name + "_mean": (round(v / dispatches, 4)
                             if counts_on and dispatches else None)
            for name, v in moe_sum.items()},
+        # of all pairs routed, the share on identity experts: routed work
+        # done without a matrix (None: the model has no such experts)
+        "moe_zero_share": (round(moe_sum["moe_pairs_zero"]
+                                 / moe_sum["moe_pairs_all"], 4)
+                           if moe_sum.get("moe_pairs_all") else None),
         "spec_accept_rate": (round(accepted / drafted, 4)
                              if drafted else None),
         "speculate_k": spec_k,
