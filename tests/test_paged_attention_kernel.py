@@ -91,6 +91,13 @@ CASES = [
     ("block4-group4-blocks-of-2", 4, 4, 1, [8, 0, None, 16], 1, 2,
      jnp.float32),
     ("block4-bfloat16", 4, 4, 2, [4, 12, 0, 16], 2, 2, jnp.bfloat16),
+    # ``cohere2moe``'s full layer: a group of 16 query heads a kv head,
+    # rows that hold one or two of the row's pages beside a full one, a
+    # freed slot (row all -1), blocks of 2 pages
+    ("group16-kv2", 1, 32, 2, [0, 17, None, 5], 1, 2, jnp.float32),
+    ("group16-kv8", 1, 128, 8, [2, None, 19, 0, 7], 1, 2, jnp.float32),
+    ("group16-kv8-bfloat16", 1, 128, 8, [2, None, 19, 0, 7], 0, 2,
+     jnp.bfloat16),
 ]
 
 

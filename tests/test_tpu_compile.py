@@ -78,6 +78,36 @@ def test_paged_attention_kernel_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# the `cmdaplus` cell's full layer: 32 slots, 128 query heads over 8 kv
+# heads x 128 (a group of 16), ONE layer's pool of 3072 + 1 pages of 64,
+# rows of 144 pages (max_seq 9216)
+def test_paged_attention_kernel_compiles_at_the_full_layers_shapes(
+        one_chip, no_compile_cache):
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, kv, hd, n_pool, pt, maxp = 32, 128, 8, 128, 3073, 64, 144
+    dtype = jnp.bfloat16
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    pool = sds((1, kv, n_pool, pt, hd), dtype)
+    assert pa.supports((slots, 1, h, hd), pool.shape, dtype, pt)
+    assert pa.slot_groups(slots, h, hd, hd, dtype) == slots
+    assert pa.pages_per_block(pool.shape, dtype, maxp) == 8
+
+    def read(q, pool_k, pool_v, table, pos):
+        return pa.paged_attention(q, pool_k, pool_v, 0,
+                                  pa.walk(table, pos, pt, n_pool))
+
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(read).lower(
+            sds((slots, 1, h, hd), dtype), pool, pool,
+            sds((slots, maxp), jnp.int32), sds((slots, 1), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attn_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 # the `longcat` cell's decode read: 192 slots, 64 query heads as one group
 # over ONE pool of 8 sublayers x 4096 + 1 pages of 64 rows of 640 lanes
 # (576 values and 64 dead lanes), values the rows' first 512 lanes, the

@@ -387,6 +387,50 @@ def test_paged_attention_kernel_matches_masked_read_on_chip(layer, window,
     assert not out[13].any()        # nothing mapped: skipped
 
 
+def test_paged_attention_kernel_at_the_full_layers_geometry_on_chip():
+    """``cohere2moe``'s full layer as the `cmdaplus` cell reads it, Mosaic-
+    compiled: 32 slots, 128 query heads over 8 kv heads x 128 (a group of
+    16), 64-token pages, rows of 144 entries that map 16 to 144 pages, one
+    slot freed; against the XLA masked read. The pool is 400 pages, not the
+    cell's 3072 (the masked read scores every slot against every page), so
+    rows share pages, none twice in a row."""
+    from tpudist.models import transformer as T
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, kv, hd, pages, pt, maxp = 32, 128, 8, 128, 400, 64, 144
+    rng = np.random.default_rng(36)
+    kk, kq = jax.random.split(jax.random.PRNGKey(36))
+    shape = (1, kv, pages + 1, pt, hd)
+    pool_k = jax.random.normal(kk, shape, jnp.bfloat16)
+    pool_v = jax.random.normal(jax.random.fold_in(kk, 1), shape,
+                               jnp.bfloat16)
+    q = jax.random.normal(kq, (slots, 1, h, hd), jnp.bfloat16)
+    n_pages = [16, 144] + [int(n) for n in rng.integers(16, 145, slots - 2)]
+    n_pages[7] = 0                  # freed: its row cleared
+    table = np.full((slots, maxp), -1, np.int32)
+    pos = np.full((slots, 1), 500, np.int32)
+    for s, n in enumerate(n_pages):
+        if n:
+            table[s, :n] = rng.choice(pages, n, replace=False)
+            pos[s] = (n - 1) * pt + int(rng.integers(0, pt))
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    assert T._use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt)
+
+    @jax.jit
+    def kernel(q, pk, pv, table, pos):
+        return pa.paged_attention(q, pk, pv, 0,
+                                  pa.walk(table, pos, pt, pk.shape[2]))
+
+    ref = jax.jit(T._masked_pool_read, static_argnums=(6,))(
+        q, pool_k, pool_v, jnp.int32(0), table, pos, pt)
+    out = kernel(q, pool_k, pool_v, table, pos)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    assert np.isfinite(out).all()
+    worst = {s: float(np.abs(out[s] - ref[s]).max())
+             for s, n in enumerate(n_pages) if n}
+    assert max(worst.values()) < 4e-2, worst
+    assert not out[7].any()         # nothing mapped: skipped
+
+
 @pytest.mark.parametrize("sub", [0, 7])
 def test_latent_paged_attention_kernel_matches_masked_read_on_chip(sub):
     """The kernel's LATENT call, Mosaic-compiled at the ``longcat`` cell's

@@ -24,10 +24,13 @@ Dropless with static shapes: ``models/dropless.py``, the routine this
 model shares with ``sdarmoe``.
 
 Two kinds of cache state (``tpudist/serve/kvcache.py``): a full layer
-writes and reads the paged pool through the page table, gathering the
-pages a slot OWNS (never slots x whole pool); a window layer keeps, per
-slot, a ring of the last ``ring`` tokens, written at ``pos mod ring`` and
-masked by logical position.
+writes and reads the paged pool through ``transformer._paged_attention``,
+the step every paged model takes: on one TPU chip its read is the kernel
+``paged_attn_decode``, which copies only the pages a slot's own row maps;
+elsewhere (the CPU, a multi-chip mesh) the masked read, which stages and
+scores the layer's WHOLE pool and so is meant for the CPU's tiny sizes. A
+window layer keeps, per slot, a ring of the last ``ring`` tokens, written
+at ``pos mod ring`` and masked by logical position.
 
 Weights are created at rest in their serving dtype, leaf by leaf, on the
 device (``LEAFWISE_INIT``): the float32 whole of this model does not fit
@@ -286,10 +289,10 @@ def apply(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
 
 
 def _scores_to_values(q, kf, vf, mask):
-    """q: (slots, window, heads, hd); kf, vf: ``kv`` arrays of (slots,
-    keys, hd), one per kv head: a head's keys are scored where they lie,
-    as each kind of cache hands them over, and never restacked; mask:
-    (slots, window, keys). Grouped-query, float32 softmax."""
+    """A window layer's read of its rings. q: (slots, window, heads, hd);
+    kf, vf: ``kv`` arrays of (slots, keys, hd), one per kv head: a head's
+    ring is scored where it lies and never restacked; mask: (slots,
+    window, keys). Grouped-query, float32 softmax."""
     s, w, h, hd = q.shape
     kv = len(kf)
     with scope("attn/core"):
@@ -306,39 +309,6 @@ def _scores_to_values(q, kf, vf, mask):
             out.append(jnp.einsum("swgn,snd->swgd", probs,
                                   vf[i].astype(q.dtype)))
         return jnp.stack(out, axis=2).reshape(s, w, h, hd)
-
-
-def _owned_pages_attention(q, k_new, v_new, pool_k, pool_v, layer: int,
-                           page_table, positions, write_ok,
-                           page_tokens: int):
-    """A FULL layer's step against the paged pool (the pool's layout and
-    the write are ``transformer._paged_attention``'s). The read gathers,
-    per slot, the pages its row of the table names, in logical order, so
-    a key's position is its place in the gather: what is scored is slots x
-    max_pages pages, bounded by what a slot can own, never slots x the
-    whole pool. Unmapped entries gather the trash page; they lie past
-    every live position and are masked with it."""
-    s, w, _, hd = q.shape
-    kv, pt = k_new.shape[2], page_tokens
-    trash = pool_k.shape[2] - 1
-    maxp = page_table.shape[1]
-    with scope("attn/kv_write/full"):
-        pg = jnp.take_along_axis(page_table, positions // pt, axis=1)
-        pg = jnp.where(write_ok & (pg >= 0), pg, trash)
-        at = (layer, jnp.arange(kv)[None, None, :], pg[:, :, None],
-              (positions % pt)[:, :, None])
-        pool_k = pool_k.at[at].set(k_new.astype(pool_k.dtype))
-        pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
-    with scope("attn/kv_gather/full"):
-        rows = jnp.where(page_table >= 0, page_table, trash)  # (s, maxp)
-
-        def owned(pool):    # per kv head (pages+1, pt, hd) -> (s, keys, hd)
-            return [pool[layer, i].at[rows].get(
-                mode="promise_in_bounds").reshape(s, maxp * pt, hd)
-                for i in range(kv)]
-        kf, vf = owned(pool_k), owned(pool_v)
-        mask = jnp.arange(maxp * pt)[None, None, :] <= positions[:, :, None]
-    return _scores_to_values(q, kf, vf, mask), pool_k, pool_v
 
 
 def _ring_attention(q, k_new, v_new, ring_k, ring_v, positions, write_ok,
@@ -393,7 +363,7 @@ def paged_hidden_states(params: Params, tokens: jax.Array,
             n_ring += 1
         else:
             def attend(q, k, v, i=n_full):
-                o, *cache = _owned_pages_attention(
+                o, *cache = T._paged_attention(
                     q, k, v, pool_k, pool_v, i, page_table, positions,
                     write_ok, page_tokens)
                 return o, cache
