@@ -21,9 +21,13 @@ def test_compilation_cache_dir_rule(monkeypatch):
     calls = {}
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: calls.__setitem__(k, v))
-    listeners = []
+    listeners, timers = [], []
     monkeypatch.setattr(jax.monitoring, "register_event_listener",
                         listeners.append)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        timers.append)
+    monkeypatch.setattr(platform, "_listening", False)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
     platform.enable_compilation_cache()
     assert "jax_compilation_cache_dir" not in calls
@@ -43,13 +47,16 @@ def test_compilation_cache_dir_rule(monkeypatch):
         platform.enable_compilation_cache()
         seen.append(calls["jax_compilation_cache_dir"])
     assert seen == [os.path.join(REPO, ".jax_cache")] * 2
+    # one registration of each listener, however often it is asked for
+    assert listeners == [platform._count_cache_event]
+    assert timers == [platform._record_compile]
     # hits and misses are counted from jax's own events, nothing else
-    before = dict(platform.CACHE_EVENTS)
+    monkeypatch.setattr(platform, "CACHE_EVENTS",
+                        dict.fromkeys(platform.CACHE_EVENTS, 0))
     listeners[0]("/jax/compilation_cache/cache_hits")
     listeners[0]("/jax/some/other/event")
-    after = dict(platform.CACHE_EVENTS)
-    platform.CACHE_EVENTS.update(before)
-    assert sum(after.values()) == sum(before.values()) + 1
+    platform._asked.cache = None
+    assert sum(platform.CACHE_EVENTS.values()) == 1
 
 
 def test_chip_smoke_refuses_without_tpu_and_stays_off_jax():

@@ -35,6 +35,7 @@ merged trace plus ``metrics.jsonl`` into an offline run report.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -235,6 +236,17 @@ class Tracer:
         t = _now_ns()
         self._thread_buf().record(name, cat, t, t, args or None)
 
+    def record(self, name: str, cat: str, t0_ns: int, t1_ns: int,
+               **args: Any) -> None:
+        """A span known only once it is over (a phase someone else timed):
+        stored in the calling thread's ring as given, where it nests by
+        time under whatever span is open. Never mirrored into a profiler
+        session. Reads no clock."""
+        if not self.enabled:
+            return
+        self._thread_buf().record(name, cat, int(t0_ns), int(t1_ns),
+                                  args or None)
+
     # ------------------------------------------------------ inspection
     @property
     def span_count(self) -> int:
@@ -377,6 +389,18 @@ def span(name: str, cat: str = "misc", **args: Any):
 
 def instant(name: str, cat: str = "misc", **args: Any) -> None:
     get().instant(name, cat, **args)
+
+
+def spanned(name: str, cat: str = "misc"):
+    """Decorator: every call of the function is one span of the
+    process-wide tracer (looked up at call time)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*a, **kw):
+            with get().span(name, cat):
+                return fn(*a, **kw)
+        return inner
+    return wrap
 
 
 def enabled() -> bool:

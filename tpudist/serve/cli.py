@@ -436,8 +436,7 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
         prompt_pad=args.prompt_pad, decode_k=cand.decode_k,
         page_tokens=cand.kv_page_tokens, pages=max(args.kv_pages, 0),
         speculate_k=cand.speculate_k, adapt_ladder=ladder)
-    with trace_lib.span("serve_warmup", cat="serve"):
-        engine.warmup(params)
+    engine.warmup(params)
 
     # program memory (obs.memledger): warmup just compiled every pinned
     # program, so their memory_analysis is readable off the request

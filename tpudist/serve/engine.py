@@ -124,7 +124,7 @@ class PagedServeState(NamedTuple):
     block_step: Optional[jax.Array] = None
 
 
-
+@trace_lib.spanned("model_init", cat="init")
 def init_params(model_cfg: ModelConfig, mesh, seed: int = 0):
     """Seeded model params placed to their sanitised param_specs layout
     — the same init + sharding recipe the training engine uses, minus
@@ -191,6 +191,7 @@ class PagedServeEngine:
     nothing.
     """
 
+    @trace_lib.spanned("engine_init", cat="init")
     def __init__(self, model_cfg: ModelConfig, mesh, *, slots: int,
                  max_seq: int, prompt_pad: int, decode_k: int = 8,
                  page_tokens: int = 8, pages: int = 0,
@@ -903,12 +904,16 @@ class PagedServeEngine:
             # tokens (block, slots): every position the dispatch computed
             # (-1 outside it); valid: the ones emitted. ``state.block_step``
             # says at which step each was unmasked
-            self._note_program(f"denoise_b{k}", self._denoise,
+            self._note_program(self._step_program(k), self._denoise,
                                (params, state, table, da))
             return self._denoise(params, state, table, da)
-        self._note_program(f"decode_k{k}", self._decode,
+        self._note_program(self._step_program(k), self._decode,
                            (params, state, k, table, da), static_idx=(2,))
         return self._decode(params, state, k, table, da)
+
+    def _step_program(self, k: int) -> str:
+        """The name ``_programs`` gives the decode program of rung ``k``."""
+        return f"denoise_b{k}" if self.block else f"decode_k{k}"
 
     # ---------------------------------------------------------- verify
 
@@ -987,6 +992,7 @@ class PagedServeEngine:
 
     # ---------------------------------------------------------- warmup
 
+    @trace_lib.spanned("warmup", cat="serve")
     def warmup(self, params) -> None:
         """Compile every program OFF the request clock: a cold first
         admission would charge XLA compilation to that request's TTFT,
@@ -997,22 +1003,29 @@ class PagedServeEngine:
         table (compilation only sees shapes; the junk writes route to
         the trash page), fences, and leaves the jit caches warm — after
         this, a whole serve run (adapt transitions included) compiles
-        nothing (``assert_two_programs``)."""
+        nothing (``assert_two_programs``). Each program's run is a
+        ``warmup_program`` span named as ``_programs`` names it."""
         params = self._resident(params)
         state = self.init_state()
         dummy = jnp.zeros((1, self.prompt_pad), jnp.int32)
         row = np.full((self.spec.max_pages_per_slot,), -1, np.int32)
-        state, first = self.prefill(params, state, dummy, 1, 0, 2,
-                                    page_row=row)
-        jax.device_get(first)
+        tracer = trace_lib.get()
+        with tracer.span("warmup_program", cat="serve", program="prefill"):
+            state, first = self.prefill(params, state, dummy, 1, 0, 2,
+                                        page_row=row)
+            jax.device_get(first)
         for k in self.ladder:
-            state, toks, valid = self.decode(params, state, k)
-            jax.device_get((toks, valid))
+            with tracer.span("warmup_program", cat="serve",
+                             program=self._step_program(k)):
+                state, toks, valid = self.decode(params, state, k)
+                jax.device_get((toks, valid))
         if self.speculate_k >= 2:
             draft = np.zeros((self.slots, self.speculate_k - 1),
                              np.int32)
-            state, toks, valid, e = self.verify(params, state, draft)
-            jax.device_get((toks, valid, e))
+            with tracer.span("warmup_program", cat="serve",
+                             program="verify"):
+                state, toks, valid, e = self.verify(params, state, draft)
+                jax.device_get((toks, valid, e))
 
     def compile_counts(self) -> Tuple[int, int]:
         return len(self.prefill_traces), len(self.decode_traces)

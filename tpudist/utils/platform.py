@@ -1,10 +1,14 @@
 """Process-level JAX set-up shared by every CLI entry point: the
-persistent compilation cache and the libtpu tuning flags. The platform
+persistent compilation cache, the listener that records jax's compile
+phases as ring-tracer spans, and the libtpu tuning flags. The platform
 itself is JAX's own business (``JAX_PLATFORMS``)."""
 
 from __future__ import annotations
 
 import os
+import threading
+
+from tpudist.obs import trace as trace_lib
 
 # one fixed path inside the checkout: the directory is part of what a
 # later process must reproduce to hit the cache, so it never moves
@@ -19,10 +23,62 @@ _DEFAULT_CACHE_DIR = os.path.join(
 CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
                 "/jax/compilation_cache/cache_misses": 0}
 
+# jax's own compile phases (each reported once it is over, with the
+# program's ``fun_name``) -> the span the ring tracer records for it
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile_lower",
+    "/jax/core/compile/backend_compile_duration": "compile_backend"}
+
+# the persistent cache's answer to the compile under way in this thread:
+# its hit or miss event fires inside the backend compile, before the
+# compile's own duration event
+_asked = threading.local()
+_listening = False
+_listen_lock = threading.Lock()
+
 
 def _count_cache_event(event: str, **_) -> None:
     if event in CACHE_EVENTS:
         CACHE_EVENTS[event] += 1
+        _asked.cache = "hit" if event.endswith("hits") else "miss"
+
+
+def _record_compile(event, duration_secs=None, *_, **kw) -> None:
+    """One compile phase as a span ending now, in the calling thread's
+    ring. Called from inside jax's compile, so it never raises; a
+    disabled tracer costs it no clock read."""
+    try:
+        name = COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        cache = None
+        if name == "compile_backend":
+            cache, _asked.cache = getattr(_asked, "cache", None), None
+        tracer = trace_lib.get()
+        if not tracer.enabled or not duration_secs >= 0:
+            return
+        t1 = trace_lib._now_ns()
+        args = {"fun": str(kw.get("fun_name", ""))}
+        if cache:
+            args["cache"] = cache
+        tracer.record(name, "compile", t1 - int(duration_secs * 1e9), t1,
+                      **args)
+    except Exception:
+        pass
+
+
+def listen_to_compiles() -> None:
+    """Register the two listeners above with jax, once a process."""
+    global _listening
+    import jax
+    with _listen_lock:
+        if _listening:
+            return
+        jax.monitoring.register_event_listener(_count_cache_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _record_compile)
+        _listening = True
 
 
 def enable_compilation_cache() -> None:
@@ -55,7 +111,7 @@ def enable_compilation_cache() -> None:
     # a line, or a checkout under another path, is still a hit.
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_traceback_in_locations_limit", 0)
-    jax.monitoring.register_event_listener(_count_cache_event)
+    listen_to_compiles()
 
 
 def tune_tpu(scoped_vmem_kib: int | None = None) -> None:
