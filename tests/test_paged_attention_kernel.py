@@ -91,6 +91,14 @@ CASES = [
     ("block4-group4-blocks-of-2", 4, 4, 1, [8, 0, None, 16], 1, 2,
      jnp.float32),
     ("block4-bfloat16", 4, 4, 2, [4, 12, 0, 16], 2, 2, jnp.bfloat16),
+    # the first forward of a block that carries the previous block's
+    # commit: 8 rows a slot from the previous block's first position, rows
+    # 0-3 bounded by that block's last position, rows 4-7 by their own
+    # block's (``commit4-``): bounds on a page's edge and inside one
+    ("commit4-group2", 8, 4, 2, [4, 8, 0, 12], 1, None, jnp.float32),
+    ("commit4-group4-blocks-of-2", 8, 4, 1, [12, None, 0, 4], 1, 2,
+     jnp.float32),
+    ("commit4-bfloat16", 8, 4, 2, [4, 8, 0, 12], 2, 2, jnp.bfloat16),
     # ``cohere2moe``'s full layer: a group of 16 query heads a kv head,
     # rows that hold one or two of the row's pages beside a full one, a
     # freed slot (row all -1), blocks of 2 pages
@@ -109,8 +117,22 @@ def test_kernel_matches_masked_read(case):
     table, pos = _table(first_pos, w, perm)
     if case[0].startswith("block4-"):
         own, pos = pos, np.broadcast_to(pos[:, -1:], pos.shape)
+    if case[0].startswith("commit4-"):
+        # one bound a row: a block's end, the committed block's or its own
+        own = np.broadcast_to(pos[:, -1:], pos.shape)
+        pos = np.concatenate([np.broadcast_to(pos[:, 3:4], (len(pos), 4)),
+                              own[:, 4:]], axis=1)
     out, ref = _both(_q(len(first_pos), w, h, dtype), pool_k, pool_v, layer,
                      table, pos, block_pages)
+    if case[0].startswith("commit4-"):
+        # read up to the current block's end, a committed row reads another
+        # answer, and a current row the same
+        one, _ = _both(_q(len(first_pos), w, h, dtype), pool_k, pool_v,
+                       layer, table, own, block_pages)
+        assert np.abs(one[0, 0] - out[0, 0]).max() > 1e-2
+        np.testing.assert_allclose(
+            one[0, 4:], out[0, 4:],
+            atol=1e-5 if dtype == jnp.float32 else 3e-2)
     if case[0].startswith("block4-"):
         # the keys ahead inside the block count: bounded by its own
         # position, a block's first row reads another answer

@@ -103,19 +103,53 @@ def serve_one(eng, params, prompt, max_new, slot=1):
     return req, state, alloc.row(slot)
 
 
-def replay(params, cfg, state, row, block_tok, start, dtype=jnp.float32):
-    """The program's forward of ONE block with the tokens ``block_tok`` at
-    positions start.. against the pool as the run left it (everything
-    before the block committed, the block's own keys written by this
-    forward, nothing later visible). -> (logits (B, V), pool_k, pool_v)."""
+def block_forward(params, cfg, pool_k, pool_v, row, block_tok, start,
+                  dtype=jnp.float32):
+    """The model's forward of ONE block alone, the tokens ``block_tok`` at
+    positions start.. of the slot whose page row is ``row``: the block's
+    own keys written by this forward, nothing later visible.
+    -> (logits (B, V), pool_k, pool_v)."""
     pos = (start + np.arange(B))[None].astype(np.int32)
     h, pk, pv, _ = M.paged_hidden_states(
         params, jnp.asarray(block_tok, jnp.int32)[None], cfg, dtype=dtype,
-        pool_k=state.pool_k, pool_v=state.pool_v,
+        pool_k=jnp.asarray(pool_k), pool_v=jnp.asarray(pool_v),
         page_table=jnp.asarray(row)[None], positions=jnp.asarray(pos),
         write_ok=jnp.ones((1, B), bool),
         see=jnp.full((1, B), start + B - 1, jnp.int32), page_tokens=PAGE)
     return M.head_logits(params, h, dtype)[0], pk, pv
+
+
+def replay(params, cfg, state, row, block_tok, start, dtype=jnp.float32):
+    """``block_forward`` against the pool as the run left it (everything
+    before the block committed)."""
+    return block_forward(params, cfg, state.pool_k, state.pool_v, row,
+                         block_tok, start, dtype)
+
+
+def two_forward_dispatch(params, cfg, pool_k, pool_v, row, block_tok,
+                         block_open, start):
+    """The order a dispatch ran before the commit rode with the next
+    block: ``B`` denoising forwards of the block alone, one position
+    unmasked a step, then the commit forward over the final tokens.
+    -> (final tokens, the step each position was unmasked at, the pool
+    before the commit, the pool after it)."""
+    tok = np.where(block_open, MASK, block_tok)
+    still, at = np.array(block_open), np.full(B, -1)
+    for s in range(B):
+        logits, pool_k, pool_v = block_forward(params, cfg, pool_k, pool_v,
+                                               row, tok, start)
+        conf = np.asarray(jnp.exp(logits.max(axis=-1)
+                                  - jax.nn.logsumexp(logits, axis=-1)))
+        if still.any():
+            pick = int(np.argmax(np.where(still, conf, -1.0)))
+            tok[pick] = int(np.argmax(np.asarray(logits[pick])))
+            still[pick], at[pick] = False, s
+    _, ck, cv = block_forward(params, cfg, pool_k, pool_v, row, tok, start)
+    return tok, at, (pool_k, pool_v), (ck, cv)
+
+
+def pages_but_trash(pool):
+    return np.asarray(pool)[:, :, :-1]
 
 
 def reference_steps(file, req, pad_to=MAX_SEQ):
@@ -197,22 +231,118 @@ def test_the_cache_keeps_the_final_tokens_k_and_v(mesh, params):
     """The commit is not skipped: after a block, the pool holds at its
     positions the K and V of a forward over the block's FINAL tokens, not
     the last denoising step's (which saw the mask token at the position
-    it was about to unmask)."""
+    it was about to unmask). The commit of a request's LAST block would
+    ride in a dispatch that never comes: that block keeps the last step's
+    K and V, which nothing reads again."""
     cfg, _ = configs()
     eng = engine_of(mesh)
-    req, state, row = serve_one(eng, params, tokens(8, seed=1), 8)
-    for start in (8, 12):
+    req, state, row = serve_one(eng, params, tokens(8, seed=1), 12)
+    for start, committed in ((8, True), (12, True), (16, False)):
         at = np.arange(start, start + B)
         page, off = row[start // PAGE], start % PAGE
-        _, pk, pv = replay(params, cfg, state, row, req["tokens"][at], start)
-        for got, want in ((state.pool_k, pk), (state.pool_v, pv)):
+        final = replay(params, cfg, state, row, req["tokens"][at], start)
+        last = np.where(req["step"][at] == B - 1, MASK, req["tokens"][at])
+        step = replay(params, cfg, state, row, last, start)
+        kept, other = (final, step) if committed else (step, final)
+        for got, want in ((state.pool_k, kept[1]), (state.pool_v, kept[2])):
             assert np.abs(np.asarray(got[:, :, page, off:off + B])
                           - np.asarray(want[:, :, page, off:off + B])
                           ).max() < TOL
-        last = np.where(req["step"][at] == B - 1, MASK, req["tokens"][at])
-        _, pk, _ = replay(params, cfg, state, row, last, start)
         assert np.abs(np.asarray(state.pool_k[:, :, page, off:off + B])
-                      - np.asarray(pk[:, :, page, off:off + B])).max() > 1e-3
+                      - np.asarray(other[1][:, :, page, off:off + B])
+                      ).max() > 1e-3
+
+
+def admit(eng, params, state, alloc, slot, prompt, max_new):
+    padded = np.zeros(PAD, np.int32)
+    padded[:len(prompt)] = prompt
+    assert alloc.admit(slot, len(prompt))
+    return eng.prefill(params, state, padded, len(prompt), slot, max_new)[0]
+
+
+def dispatch(eng, params, state, alloc, starts):
+    """One dispatch of the slots ``starts`` names, each at its block's
+    first position. -> (state, tokens (B, slots), valid, stats)."""
+    for slot, start in starts.items():
+        assert alloc.ensure(slot, start + B - 1)
+    inside = np.zeros(eng.slots, bool)
+    inside[list(starts)] = True
+    state, toks, valid = eng.decode(params, state, dispatch_active=inside)
+    return state, np.asarray(toks), np.asarray(valid), eng.read_stats(state)
+
+
+def test_a_dispatch_leaves_the_pool_the_two_forward_order_leaves(mesh,
+                                                                  params):
+    """Slot 0's second block beside its first block's commit, slot 1's first
+    block (no commit) in the same dispatch: the tokens, the order of
+    unmasking and every page are what the order before the fusion gives,
+    a block's denoising forwards then its commit forward, block by block."""
+    cfg, _ = configs()
+    eng = engine_of(mesh)
+    state, alloc = eng.init_state(), eng.new_allocator()
+    state = admit(eng, params, state, alloc, 0, tokens(8, seed=4), 12)
+    state = admit(eng, params, state, alloc, 1, tokens(9, seed=6), 12)
+    pool = (np.asarray(state.pool_k), np.asarray(state.pool_v))
+    given = np.asarray(state.block_tok), np.asarray(state.block_open)
+    # slot 1 waits outside the first dispatch
+    state, toks, _, stats = dispatch(eng, params, state, alloc, {0: 8})
+    assert (stats["commits_fused"], stats["forwards_launched"]) == (0, B)
+    tok, at, _, pool = two_forward_dispatch(params, cfg, *pool, alloc.row(0),
+                                            given[0][0], given[1][0], 8)
+    np.testing.assert_array_equal(toks[:, 0], tok)
+    np.testing.assert_array_equal(eng.read_block(state)[:, 0], at)
+    assert list(np.asarray(state.commit_due)) == [True, False]
+
+    block = np.asarray(state.block_tok), np.asarray(state.block_open)
+    state, toks, _, stats = dispatch(eng, params, state, alloc,
+                                     {0: 12, 1: 8})
+    assert (stats["commits_fused"], stats["forwards_launched"]) == (1, B)
+    for slot, start in ((0, 12), (1, 8)):
+        tok, at, pool, _ = two_forward_dispatch(
+            params, cfg, *pool, alloc.row(slot), block[0][slot],
+            block[1][slot], start)
+        np.testing.assert_array_equal(toks[:, slot], tok)
+        np.testing.assert_array_equal(eng.read_block(state)[:, slot], at)
+    # the same float32 mathematics over rows of another batch shape: ~1e-6
+    # apart on entries of size ~4
+    for got, want in zip((state.pool_k, state.pool_v), pool):
+        assert np.abs(pages_but_trash(got) - pages_but_trash(want)).max() \
+            < TOL
+    assert list(np.asarray(state.commit_due)) == [True, True]
+
+
+@pytest.mark.parametrize("left", ["never_used", "finished", "evicted"])
+def test_a_slots_next_request_carries_no_commit(mesh, params, left):
+    """A slot in its first block carries no commit, whoever held it: a
+    request that finished dropped its commit in the program that finished
+    it; one the host evicted mid-request still had its commit due, and the
+    next admission's prefill clears it. The next request's first dispatch
+    writes nothing outside its own block, so no stale commit lands in pages
+    that request or any other now owns."""
+    eng = engine_of(mesh)
+    state, alloc = eng.init_state(), eng.new_allocator()
+    slot = 1
+    if left != "never_used":
+        state = admit(eng, params, state, alloc, slot, tokens(8, seed=7),
+                      4 if left == "finished" else 12)
+        state, _, _, _ = dispatch(eng, params, state, alloc, {slot: 8})
+        live = left == "evicted"
+        assert bool(np.asarray(state.active)[slot]) == live
+        assert bool(np.asarray(state.commit_due)[slot]) == live
+        alloc.free_slot(slot)
+    state = admit(eng, params, state, alloc, slot, tokens(10, seed=8), 6)
+    assert not np.asarray(state.commit_due)[slot]
+    before = (np.asarray(state.pool_k), np.asarray(state.pool_v))
+    state, _, valid, stats = dispatch(eng, params, state, alloc, {slot: 8})
+    assert stats["commits_fused"] == 0 and valid[:, slot].sum() == 2
+    page = alloc.row(slot)[8 // PAGE]
+    off = 8 % PAGE
+    for was, now in zip(before, (state.pool_k, state.pool_v)):
+        now = np.array(now)
+        # the block's own provisional k/v, and nothing else
+        now[:, :, page, off:off + B] = was[:, :, page, off:off + B]
+        np.testing.assert_array_equal(pages_but_trash(now),
+                                      pages_but_trash(was))
 
 
 def test_bfloat16_is_outside(mesh, params):
@@ -280,7 +410,13 @@ def test_run_serve_serves_every_budget_to_the_token(served):
         assert (r.prompt_len + r.max_new + len(res["surplus"])) % B == 0
     assert summary["generated_tokens"] == sum(r.max_new for r in reqs)
     assert summary["block_length"] == B
-    assert summary["forwards_per_token"] >= (B + 1) / B
+    # a request's blocks each take B forwards a slot, and each but its last
+    # one more: its commit, in the slot's next dispatch
+    blocks = [-(-(r.prompt_len % B + r.max_new) // B) for r in reqs]
+    assert summary["commits_fused"] == sum(n - 1 for n in blocks)
+    assert summary["forwards_launched"] == B * summary["dispatches"]
+    assert summary["forwards_per_token"] == round(
+        sum((B + 1) * n - 1 for n in blocks) / summary["generated_tokens"], 4)
     assert summary["prefill_compiles"] == summary["decode_compiles"] == 1
     # the time to first token is taken at the first block's return
     first = [e for e in logged if e.get("kind") == "serve_first_tokens"]
@@ -316,7 +452,11 @@ def test_run_serve_carries_the_span_arguments(served):
     assert steps
     for s in steps:
         a = s["args"]
-        assert a["forwards"] == (B + 1) * a["blocks"] == (B + 1) * a["active"]
+        # a slot's B denoising forwards, and its previous block's commit
+        # where one was due
+        assert a["forwards"] == B * a["blocks"] + a["commits_fused"]
+        assert a["blocks"] == a["active"] >= a["commits_fused"]
+        assert a["forwards_launched"] == B
         assert 0 < a["tokens_emitted"] <= B * a["blocks"]
         assert a["moe_pairs_per_expert"] > 0 and a["moe_experts_hit"] > 0
         # blocks of rows the expert routine ran, a layer a forward: one an
@@ -325,6 +465,11 @@ def test_run_serve_carries_the_span_arguments(served):
         assert a["kv_full_pages"] > 0
     assert sum(s["args"]["tokens_emitted"] for s in steps) \
         == sum(r.max_new for r in reqs)
+    # every block but a request's last was committed once, and a slot's
+    # first dispatch carried none
+    assert sum(s["args"]["commits_fused"] for s in steps) \
+        == sum(-(-(r.prompt_len % B + r.max_new) // B) - 1 for r in reqs)
+    assert steps[0]["args"]["commits_fused"] == 0
     pre = [s for s in spans if s["name"] == "prefill"]
     assert sorted(s["args"]["blocks_written"] for s in pre) \
         == sorted(r.prompt_len // B for r in reqs)

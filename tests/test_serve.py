@@ -672,6 +672,32 @@ def test_report_serving_prints_the_identity_experts_share():
     assert "identity experts" not in report_lib.to_markdown(plain)
 
 
+@pytest.mark.parametrize("fused", [True, False],
+                         ids=["with_counts", "record_before_the_counts"])
+def test_report_serving_prints_the_block_generation_counts(fused):
+    """A model that generates by blocks: forwards a token emitted, the
+    commits that rode in a next block's first step and the forwards
+    launched, on one line; a record from before the two counts prints the
+    line without them, a model that generates token by token no line."""
+    metrics = _serve_metrics()
+    metrics[-1].update(block_length=4, forwards_per_token=1.2512,
+                       tokens_per_dispatch=301.7)
+    if fused:
+        metrics[-1].update(commits_fused=35_012, forwards_launched=2_324)
+    rep = report_lib.build_report(metrics, {})
+    line = next(ln for ln in report_lib.to_markdown(rep).splitlines()
+                if "generation by blocks" in ln)
+    assert line.startswith("- generation by blocks of 4: 1.2512 forward(s) "
+                           "a token emitted")
+    assert line.endswith("301.7 token(s) a dispatch over all slots")
+    counts = ("35012 commit(s) fused into a next block's first step, "
+              "2324 forward(s) launched")
+    assert (counts in line) == fused
+    assert rep["serving"]["commits_fused"] == (35_012 if fused else None)
+    plain = report_lib.build_report(_serve_metrics(), {})
+    assert "generation by blocks" not in report_lib.to_markdown(plain)
+
+
 def test_report_serving_prints_what_the_engine_converted():
     """The ``weights_resident`` span (one a params tree the engine had to
     convert to its dtype) is the serving section's one line; a run whose

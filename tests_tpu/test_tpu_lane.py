@@ -431,6 +431,66 @@ def test_paged_attention_kernel_at_the_full_layers_geometry_on_chip():
     assert not out[7].any()         # nothing mapped: skipped
 
 
+def test_paged_attention_kernel_two_bounds_a_slot_on_chip():
+    """The `sdar` cell's first denoising forward, which carries the previous
+    block's commit, Mosaic-compiled: 128 slots, 32 query heads over 4 kv
+    heads x 128, 64-token pages, a window of 8 rows a slot under TWO bounds:
+    the previous block's final 4 rows read up to that block's end, the
+    current block's 4 up to their own (4 keys more). Blocks start on a page,
+    end one, sit at position 0 (both bounds the block's own) and at the
+    cache's last block; one slot freed. Against the XLA masked read. The
+    pool is 400 pages, not the cell's 3072 (the masked read scores every
+    slot against every page), so rows share pages, none twice in a row."""
+    from tpudist.models import transformer as T
+    from tpudist.ops.pallas import paged_attention as pa
+    slots, h, kv, hd, pages, pt, maxp, b = 128, 32, 4, 128, 400, 64, 48, 4
+    rng = np.random.default_rng(38)
+    kk, kq = jax.random.split(jax.random.PRNGKey(38))
+    shape = (1, kv, pages + 1, pt, hd)
+    pool_k = jax.random.normal(kk, shape, jnp.bfloat16)
+    pool_v = jax.random.normal(jax.random.fold_in(kk, 1), shape,
+                               jnp.bfloat16)
+    q = jax.random.normal(kq, (slots, 2 * b, h, hd), jnp.bfloat16)
+    starts = [0, 4, 60, 64, 68, maxp * pt - b] + [
+        int(n) * b for n in rng.integers(1, maxp * pt // b, slots - 6)]
+    starts[9] = None                # freed: its row cleared
+    table = np.full((slots, maxp), -1, np.int32)
+    see = np.full((slots, 2 * b), 900, np.int32)
+    for s, start in enumerate(starts):
+        if start is None:
+            continue
+        n = (start + b - 1) // pt + 1
+        table[s, :n] = rng.choice(pages, n, replace=False)
+        see[s, :b] = max(start - b, 0) + b - 1
+        see[s, b:] = start + b - 1
+    table, see = jnp.asarray(table), jnp.asarray(see)
+    assert T._use_paged_kernel(q.shape, pool_k.shape, pool_k.dtype, pt)
+    # eight rows a slot: the grid runs the slots in groups of 32
+    assert pa.slot_groups(slots, 2 * b * h, hd, hd, jnp.bfloat16) == 32
+
+    @jax.jit
+    def kernel(q, pk, pv, table, see):
+        return pa.paged_attention(q, pk, pv, 0,
+                                  pa.walk(table, see, pt, pk.shape[2]))
+
+    ref = jax.jit(T._masked_pool_read, static_argnums=(6,))(
+        q, pool_k, pool_v, jnp.int32(0), table, see, pt)
+    out = kernel(q, pool_k, pool_v, table, see)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    assert np.isfinite(out).all()
+    worst = {s: float(np.abs(out[s] - ref[s]).max())
+             for s, start in enumerate(starts) if start is not None}
+    assert max(worst.values()) < 4e-2, worst
+    assert not out[9].any()         # nothing mapped: skipped
+    # the commit rows' own bound is what was compared: up to the current
+    # block's end they would read other keys
+    one = jax.jit(T._masked_pool_read, static_argnums=(6,))(
+        q, pool_k, pool_v, jnp.int32(0), table,
+        jnp.broadcast_to(see[:, -1:], see.shape), pt)
+    assert np.abs(np.asarray(one, np.float32)[4, :b] - ref[4, :b]).max() \
+        > 4e-2
+
+
 @pytest.mark.parametrize("sub", [0, 7])
 def test_latent_paged_attention_kernel_matches_masked_read_on_chip(sub):
     """The kernel's LATENT call, Mosaic-compiled at the ``longcat`` cell's

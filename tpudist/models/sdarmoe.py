@@ -11,8 +11,8 @@ dropless expert routine and the scopes are theirs):
   ``mask_token_id``, and a query sees every key of its own block, the ones
   ahead of it too, and every key of the blocks before it (block-causal).
   The engine (``serve/engine.py``) runs a block through its denoising
-  forwards and one commit forward in a dispatch; this module is the
-  forward they call;
+  forwards in a dispatch, the first of them beside the commit of the
+  slot's previous block; this module is the forward they call;
 * a pre-norm SEQUENTIAL block: attention reads ``rmsnorm(x)`` and is added
   to ``x``, the expert mix reads the norm of THAT sum;
 * grouped-query attention with a per-head RMSNorm on q and on k (one gain

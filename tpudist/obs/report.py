@@ -734,6 +734,8 @@ def serving_section(metrics: List[Dict[str, Any]],
         # a model that generates by diffusion over blocks (None elsewhere)
         "block_length": s.get("block_length"),
         "forwards_per_token": s.get("forwards_per_token"),
+        "commits_fused": s.get("commits_fused"),
+        "forwards_launched": s.get("forwards_launched"),
         "tokens_per_dispatch": s.get("tokens_per_dispatch"),
         # one span a params tree the engine had to convert (none where
         # the tree rests in the engine's dtype already)
@@ -1251,12 +1253,17 @@ def to_markdown(report: Dict[str, Any]) -> str:
                       f"decode read copies these a layer a token step, "
                       f"the masked read the whole pool", ""]
         if sv.get("block_length"):
+            # a run record from before the fused commit has neither count
+            fused = (f"; {sv['commits_fused']} commit(s) fused into a next "
+                     f"block's first step, {sv['forwards_launched']} "
+                     f"forward(s) launched"
+                     if sv.get("forwards_launched") is not None else "")
             lines += [f"- generation by blocks of {sv['block_length']}: "
                       f"{sv['forwards_per_token']} forward(s) a token "
-                      f"emitted (a block takes its denoising steps and "
-                      f"one commit forward), "
-                      f"{sv['tokens_per_dispatch']} token(s) a dispatch "
-                      f"over all slots", ""]
+                      f"emitted (a block takes its denoising steps, and "
+                      f"its commit rides in the slot's next dispatch"
+                      f"{fused}), {sv['tokens_per_dispatch']} token(s) a "
+                      f"dispatch over all slots", ""]
         if sv.get("moe_pairs_per_expert_mean") is not None:
             lines += [f"- experts held here: "
                       f"{sv['moe_pairs_per_expert_mean']} pair(s) an "

@@ -84,8 +84,9 @@ BLOCK_SCOPES: Tuple[str, ...] = (
     "denoise",          # serve: the block-denoising program, outermost
     "unmask",           # inside it: the head, confidences, the choice of
                         # the position to unmask, the write into the block
-    "commit",           # inside it: the forward over the block's final
-                        # tokens that writes the K/V the cache keeps
+    "commit",           # inside it: the first denoising forward, which
+                        # carries the previous block's final tokens and
+                        # writes the K/V the cache keeps
 )
 
 # The scopes of latent attention and of identity experts (``longcatflash``),

@@ -114,14 +114,18 @@ class PagedServeState(NamedTuple):
     ring_v: tuple = ()
     stats: Optional[jax.Array] = None
     # a model that generates by diffusion over blocks only (else None):
-    # the slot's CURRENT block, (slots, block_length) each: its tokens
-    # (the prompt's remainder as given, the mask token elsewhere), which
-    # of its positions are still masked, and for the block the LAST
-    # dispatch finished the denoising step at which each position was
-    # unmasked (-1: it was given)
+    # the slot's CURRENT block, (slots, block_length) each: its given
+    # tokens (the prompt's remainder; at the positions still masked the
+    # mask token, or where a commit is due the final tokens of the block
+    # the last dispatch finished), which of its positions are still
+    # masked, and for the block the LAST dispatch finished the denoising
+    # step at which each position was unmasked (-1: it was given); and
+    # (slots,) whether that finished block's commit rides in the slot's
+    # next dispatch
     block_tok: Optional[jax.Array] = None
     block_open: Optional[jax.Array] = None
     block_step: Optional[jax.Array] = None
+    commit_due: Optional[jax.Array] = None
 
 
 @trace_lib.spanned("model_init", cat="init")
@@ -217,9 +221,9 @@ class PagedServeEngine:
         self.slots, self.max_seq = int(slots), int(max_seq)
         # a model that declares a block length generates by diffusion over
         # blocks: its dispatch is a program of its own below (a block
-        # through its denoising forwards and the commit forward), its
-        # prefill yields no token, and a dispatch's token steps are the
-        # block's positions
+        # through its denoising forwards, the first of which commits the
+        # slot's previous block), its prefill yields no token, and a
+        # dispatch's token steps are the block's positions
         self.block = int(model_cfg.block_length)
         if not self.block and getattr(self.model, "GENERATES_BY_BLOCKS",
                                       False):
@@ -227,8 +231,6 @@ class PagedServeEngine:
                 f"model {model_cfg.name!r} generates by diffusion over "
                 f"blocks and has no token-a-step decode: give its config "
                 f"a block_length")
-        # its denoising forwards (one position unmasked a step) + the commit
-        self.forwards_per_block = self.block + 1 if self.block else 0
         if self.block:
             self._check_block(model_cfg, speculate_k, adapt_ladder)
             decode_k = self.block
@@ -373,11 +375,13 @@ class PagedServeEngine:
             active=vec(jnp.zeros((s,), bool)),
             remaining=vec(jnp.zeros((s,), jnp.int32)),
             ring_k=rings["k"], ring_v=rings["v"],
-            stats=vec(jnp.zeros((1 + self.model.N_STATS,), jnp.int32))
+            stats=vec(jnp.zeros((1 + self.model.N_STATS
+                                 + (2 if self.block else 0),), jnp.int32))
             if self.counted else None,
             **({"block_tok": vec(jnp.zeros((s, self.block), jnp.int32)),
                 "block_open": vec(jnp.zeros((s, self.block), bool)),
-                "block_step": vec(jnp.full((s, self.block), -1, jnp.int32))}
+                "block_step": vec(jnp.full((s, self.block), -1, jnp.int32)),
+                "commit_due": vec(jnp.zeros((s,), bool))}
                if self.block else {}))
 
     # --------------------------------------------------------- prefill
@@ -555,7 +559,8 @@ class PagedServeEngine:
         slot's pages; no token comes of it. The prompt's remainder
         (``prompt_len mod block``) is not prefilled: it opens the slot's
         current block, already unmasked, beside mask tokens. Hands back the
-        number of blocks written, to fence on."""
+        number of blocks written, to fence on. The slot carries no commit:
+        what lies before its first block, the prefill wrote."""
         b = self.block
         whole = prompt_len // b * b
         _, ks, vs, counts = self.model.prefill_hidden_states(
@@ -579,7 +584,10 @@ class PagedServeEngine:
                 jnp.where(active, max_new, 0)),
             block_tok=row(state.block_tok, tok),
             block_open=row(state.block_open, at >= prompt_len),
-            stats=jnp.concatenate([jnp.ones((1,), jnp.int32), counts])), \
+            commit_due=state.commit_due.at[slot].set(False),
+            # one forward, no commit fused, one forward launched
+            stats=jnp.concatenate([jnp.ones((1,), jnp.int32), counts,
+                                   jnp.array([0, 1], jnp.int32)])), \
             whole // b
 
     def _note_program(self, name: str, jitted, args,
@@ -751,20 +759,33 @@ class PagedServeEngine:
                             ) -> Tuple[PagedServeState, jax.Array,
                                        jax.Array]:
         """One dispatch of a model that generates by blocks: every live
-        slot's current block through ``block`` denoising forwards and the
-        commit forward. A forward runs the block's tokens at their
-        positions: each layer writes their k and v into the slot's pages
-        (provisional until the commit) and reads the committed cache and
-        the block's own keys, all of them (``see``: the block's last
-        position). A denoising step takes, at each position still masked,
-        the argmax token and its probability (float32 softmax), and
-        unmasks the one of highest probability (ties: the lowest
-        position); a slot with nothing left masked (a request's first
-        block opens with the prompt's remainder) idles through the steps
-        that are left. The commit forward runs the block's final tokens
-        once more: what it writes is the K/V the cache keeps. A slot's
-        budget is honoured to the token: the block's positions past it
-        are computed and not emitted."""
+        slot's current block through ``block`` denoising forwards. A
+        forward runs the block's tokens at their positions: each layer
+        writes their k and v into the slot's pages (provisional until the
+        commit) and reads the committed cache and the block's own keys,
+        all of them (``see``: the block's last position). A denoising step
+        takes, at each position still masked, the argmax token and its
+        probability (float32 softmax), and unmasks the one of highest
+        probability (ties: the lowest position); a slot with nothing left
+        masked (a request's first block opens with the prompt's
+        remainder) idles through the steps that are left. A slot's budget
+        is honoured to the token: the block's positions past it are
+        computed and not emitted.
+
+        The commit: a finished block's final tokens run once more, and
+        what they write is the K/V the cache keeps. They ride as ``block``
+        more rows of the slot in the first forward of its NEXT dispatch
+        (``commit_due``), at their own positions and seeing up to their
+        own block's end; inside each layer the write comes before the
+        read, so the current block's rows read the committed K/V of this
+        same forward. A slot's first block has no commit before it (the
+        prefill wrote what lies there), and a request's last block none
+        after it: nothing reads it again, and its pages go back to the
+        allocator.
+
+        ``stats``: the passes of rows through the layer stack (``block``,
+        one more where any commit rode), the model's counts, the commits
+        that rode and the forwards launched."""
         self.decode_traces.append(self.block)   # trace-time compile marker
         b, cfg = self.block, self.model_cfg
         act = state.active & dispatch_active
@@ -774,16 +795,21 @@ class PagedServeEngine:
         see = jnp.broadcast_to(pos[:, -1:], pos.shape)
         write_ok = act[:, None] & (start[:, None] + offs < self.max_seq)
         open0 = state.block_open & act[:, None]
+        # the positions still masked hold the mask token; where a commit is
+        # due, ``block_tok`` holds the previous block's final tokens there
+        tok0 = jnp.where(state.block_open, jnp.int32(cfg.mask_token_id),
+                         state.block_tok)
+        due = act & state.commit_due
+        pos_c = jnp.maximum(start - b, 0)[:, None] + offs
+        rows = lambda c, x: jnp.concatenate([c, x], axis=1)
 
-        def forward(tok, pk, pv):
+        def forward(tok, pk, pv, pos, write_ok, see):
             return self.model.paged_hidden_states(
                 params, tok, cfg, dtype=self.dtype, pool_k=pk, pool_v=pv,
                 page_table=page_table, positions=pos, write_ok=write_ok,
                 see=see, page_tokens=self.spec.page_tokens)
 
-        def step(carry, s):
-            tok, still, at, pk, pv, counts = carry
-            h, pk, pv, st = forward(tok, pk, pv)
+        def unmask(h, tok, still, at, s):
             with scope("unmask"):
                 logits = self.model.head_logits(params, h, self.dtype)
                 best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -791,17 +817,28 @@ class PagedServeEngine:
                                - jax.nn.logsumexp(logits, axis=-1))
                 pick = jnp.argmax(jnp.where(still, conf, -1.0), axis=1)
                 hit = still & (offs == pick[:, None])
-                tok = jnp.where(hit, best, tok)
-                at = jnp.where(hit, s, at)
-            return (tok, still & ~hit, at, pk, pv, counts + st), None
+                return (jnp.where(hit, best, tok), still & ~hit,
+                        jnp.where(hit, s, at))
+
+        # step 0 beside the previous block's commit: 2 x block rows a slot
+        with scope("commit"):
+            h, pk, pv, counts = forward(
+                rows(state.block_tok, tok0), state.pool_k, state.pool_v,
+                rows(pos_c, pos),
+                rows(jnp.broadcast_to(due[:, None], pos.shape), write_ok),
+                rows(jnp.broadcast_to(pos_c[:, -1:], pos.shape), see))
+        tok, still, at = unmask(h[:, b:], tok0, open0,
+                                jnp.full_like(tok0, -1), 0)
+
+        def step(carry, s):
+            tok, still, at, pk, pv, counts = carry
+            h, pk, pv, st = forward(tok, pk, pv, pos, write_ok, see)
+            tok, still, at = unmask(h, tok, still, at, s)
+            return (tok, still, at, pk, pv, counts + st), None
 
         (tok, _, at, pk, pv, counts), _ = lax.scan(
-            step, (state.block_tok, open0, jnp.full_like(state.block_tok, -1),
-                   state.pool_k, state.pool_v,
-                   jnp.zeros((self.model.N_STATS,), jnp.int32)),
-            jnp.arange(b, dtype=jnp.int32))
-        with scope("commit"):
-            _, pk, pv, st = forward(tok, pk, pv)
+            step, (tok, still, at, pk, pv, counts),
+            jnp.arange(1, b, dtype=jnp.int32))
         # the new positions in order, as far as the budget goes
         emit = open0 & (jnp.cumsum(open0, axis=1) <= state.remaining[:, None])
         new_len = jnp.where(act, start + b, start)
@@ -814,13 +851,17 @@ class PagedServeEngine:
         new_state = state._replace(
             pool_k=pk, pool_v=pv, lengths=new_len, active=new_active,
             remaining=new_rem,
-            block_tok=jnp.where(fresh, jnp.int32(cfg.mask_token_id),
-                                state.block_tok),
+            # the final tokens wait for their commit; a slot that finished
+            # here drops it
+            block_tok=jnp.where(fresh, tok, state.block_tok),
             block_open=jnp.where(fresh, True, state.block_open),
             block_step=jnp.where(fresh, at, -1),
+            commit_due=jnp.where(dispatch_active, new_active,
+                                 state.commit_due),
             stats=jnp.concatenate([
-                jnp.full((1,), self.forwards_per_block, jnp.int32),
-                counts + st]))
+                (b + due.any()).astype(jnp.int32)[None], counts,
+                due.sum(dtype=jnp.int32)[None],
+                jnp.full((1,), b, jnp.int32)]))
         return new_state, jnp.where(fresh, tok, -1).T, emit.T
 
     def read_stats(self, state: PagedServeState) -> dict:
@@ -831,15 +872,22 @@ class PagedServeEngine:
             return {}
         steps, pairs, hit, blocks, *more = (int(v) for v in
                                             np.asarray(state.stats))
-        steps = max(steps, 1)       # token steps, or a block's forwards
+        steps = max(steps, 1)       # token steps, or passes of block rows
         cfg = self.model_cfg
         held = cfg.n_experts_held or cfg.n_experts
+        out, launched = {}, steps
+        if self.block:
+            # the block program's own two, after the model's counts: a
+            # forward that carries a commit passes two blocks' rows at once,
+            # so pairs go by passes and what a forward hits by forwards
+            *more, out["commits_fused"], launched = more
+            out["forwards_launched"] = launched
+        launched = max(launched, 1)
         # blocks over experts hit: the share of second trips to an expert
-        out = {"moe_pairs_local": pairs,
-               "moe_pairs_per_expert": pairs / (
-                   held * cfg.n_layers * steps),
-               "moe_experts_hit": hit / (cfg.n_layers * steps),
-               "moe_blocks": blocks / (cfg.n_layers * steps)}
+        out.update(moe_pairs_local=pairs,
+                   moe_pairs_per_expert=pairs / (held * cfg.n_layers * steps),
+                   moe_experts_hit=hit / (cfg.n_layers * launched),
+                   moe_blocks=blocks / (cfg.n_layers * launched))
         # what the model counts beyond the routine's three, under its own
         # names (``longcatflash``: pairs on identity experts, all pairs)
         for name, v in zip(getattr(self.model, "EXTRA_STATS", ()), more):

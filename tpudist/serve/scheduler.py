@@ -326,7 +326,9 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
     # and carried as arguments of the ``prefill`` and ``decode_step``
     # spans, with how full each kind of cache state is
     counts_on = engine.counted
-    forwards = 0                    # a block model's, summed over blocks
+    # a block model's, summed over dispatches: forwards a slot, commits
+    # fused into a next block's first step, forwards the programs launched
+    forwards = commits = launched = 0
     window_peak = 0
     moe_sum = {"moe_pairs_per_expert": 0.0, "moe_experts_hit": 0.0,
                "moe_blocks": 0.0}
@@ -657,8 +659,12 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                                     .window_tokens_used())
                 if block:
                     unmasked_at = engine.read_block(state)
-                    ran = engine.forwards_per_block * len(occupied)
+                    # the block's denoising forwards a slot, and one for
+                    # each commit of a previous block that rode with them
+                    ran = block * len(occupied) + counted["commits_fused"]
                     forwards += ran
+                    commits += counted["commits_fused"]
+                    launched += counted["forwards_launched"]
                     if tracer.enabled:
                         sp.note(forwards=ran, blocks=len(occupied),
                                 tokens_emitted=int(valid.sum()))
@@ -886,11 +892,15 @@ def run_serve(engine: PagedServeEngine, params, requests: List[Request], *,
                              if drafted else None),
         "speculate_k": spec_k,
         # a model that generates by blocks (None elsewhere): forwards run
-        # a token emitted (block + 1 over block on full blocks) and tokens
-        # a dispatch handed back, over all slots
+        # a token emitted (a slot's block, and its commit in the slot's
+        # next dispatch: about block + 1 over block on full blocks), the
+        # commits that rode so and the forwards the programs launched, and
+        # tokens a dispatch handed back, over all slots
         "block_length": block,
         "forwards_per_token": (round(forwards / generated, 4)
                                if block and generated else None),
+        "commits_fused": commits if block else None,
+        "forwards_launched": launched if block else None,
         "tokens_per_dispatch": (round(generated / dispatches, 2)
                                 if block and dispatches else None),
         "shared_prefix_len": prefix_len,
