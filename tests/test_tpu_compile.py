@@ -209,3 +209,51 @@ def test_kda_chunk_and_step_compile_at_the_cells_shapes(one_chip,
     state = S * H * D * D * 4
     assert mem.alias_size_in_bytes == state
     assert mem.temp_size_in_bytes < state // 64
+
+
+# the same prompt through the prefill's KDA kernel: a head's state in fast
+# memory across the chunks, q, k, v and g read where the projections leave
+# them; and the kernel inside the KDA sublayer, under its scope
+def test_kda_chunk_kernel_compiles_at_the_cells_shapes(one_chip,
+                                                      no_compile_cache,
+                                                      monkeypatch):
+    from tpudist.config import ModelConfig
+    from tpudist.models import kimilinear as K
+    from tpudist.ops.pallas import kda_chunk as kc
+    T, H, D, d = 4096, 32, 128, 2304
+    f32 = jnp.float32
+    sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt,
+                                                     sharding=one_chip)
+    assert kc.supports((1, T, H, D), (1, T, H, D), chunk=K.CHUNK)
+
+    def chunk(q, k, v, g, beta, n):
+        return kc.kda_chunk(q, k, v, g, beta, n, chunk=K.CHUNK, sub=K.SUB)
+    compiled = jax.jit(chunk).lower(
+        *(sds((1, T, H * D)) for _ in range(4)), sds((1, T, H)),
+        sds((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # within the fast memory it asks for, and no scratch in HBM beside the
+    # arguments: far under the GiB the XLA form may take
+    assert kc.vmem_bytes(D, D, H, K.CHUNK) <= kc._VMEM_BYTES
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    # the KDA sublayer as the prefill lowers it on one TPU chip: the kernel
+    # carries the scope ``kda/chunk`` its metrics are read under
+    cfg = ModelConfig(
+        name="kimilinear", vocab_size=20480, n_layers=2, d_model=d,
+        n_heads=32, d_ff=1024, d_ff_dense=9216, n_experts=256,
+        n_experts_held=32, expert_first=0, expert_top_k=8,
+        n_shared_experts=1, routed_scaling=2.446, norm_eps=1e-5,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, kda_heads=H, kda_head_dim=D, kda_conv=4,
+        first_dense=1, full_attn_every=4)
+    shapes = jax.eval_shape(lambda: K.init(jax.random.PRNGKey(0), cfg))
+    sp = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                      shapes["layers"][1]["mix"])
+    monkeypatch.setattr(K, "one_tpu_program", lambda: True)
+    text = jax.jit(lambda x, sp, n: K._kda_prefill(x, sp, cfg, n)).lower(
+        sds((1, T, d), jnp.bfloat16), sp, sds((), jnp.int32)).compile(
+        ).as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l
+             and "kda_chunk" in l]
+    assert len(calls) == 1
+    assert "kda/chunk/" in calls[0]

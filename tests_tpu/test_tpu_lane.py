@@ -694,3 +694,58 @@ def test_engine_serves_the_same_tokens_grouped_and_looped_on_chip(
     assert [len(outs["grouped"][i][0]) for i in range(6)] \
         == [33, 64, 21, 40, 64, 12]
     assert outs["grouped"] == outs["loop"]
+
+
+def _kda_inputs(seed, n, T=4096, H=32, K=128):
+    """The prefill's KDA operands at the `kimilinear` cell's widths, with
+    gates drawn as the model draws them (A in U(1, 16), dt log-uniform in
+    [1e-3, 0.1]); padding from ``n`` on, beta = 0 and g = 0."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    l2 = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                     + 1e-6)
+    q = l2(jax.random.normal(ks[0], (1, T, H, K))) / np.sqrt(K)
+    k = l2(jax.random.normal(ks[1], (1, T, H, K)))
+    v = jax.random.normal(ks[2], (1, T, H, K))
+    a = jax.random.uniform(ks[3], (H, 1), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(ks[4], (H, K), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    g = -a * jax.nn.softplus(0.5 * jax.random.normal(ks[5], (1, T, H, K))
+                             + jnp.log(jnp.expm1(dt)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[0], (1, T, H)))
+    real = (jnp.arange(T) < n)[None, :, None]
+    return (q, k, v, jnp.where(real[..., None], g, 0.0),
+            jnp.where(real, beta, 0.0))
+
+
+def _kda_gaps(seed, n):
+    """The kernel's largest gaps to ``chunk_kda`` at the real positions
+    and in the state, each over the reference's largest magnitude."""
+    from tpudist.models import kimilinear as M
+    from tpudist.ops.pallas import kda_chunk as kc
+    q, k, v, g, beta = _kda_inputs(seed, n)
+    b, T, H, K = q.shape
+    flat = lambda x: x.reshape(b, T, -1)
+    # traced afresh: the kernel's own jit would keep its first trace
+    o, S = jax.jit(lambda *x: kc.kda_chunk.__wrapped__(
+        *map(flat, x[:4]), x[4], x[5], chunk=M.CHUNK,
+        sub=M.SUB))(q, k, v, g, beta, jnp.int32(n))
+    o_ref, S_ref = jax.jit(M.chunk_kda)(q, k, v, g, beta)
+    o, S, o_ref, S_ref = map(np.asarray, (o.reshape(b, T, H, K), S,
+                                          o_ref, S_ref))
+    rel = lambda x, y: float(np.abs(x - y).max() / np.abs(y).max())
+    assert np.isfinite(o).all() and not o[:, n:].any()
+    return rel(o[:, :n], o_ref[:, :n]), rel(S, S_ref)
+
+
+def test_kda_chunk_kernel_matches_chunk_kda_on_chip(monkeypatch):
+    """One prompt of 2,300 tokens padded to 4,096 at the cell's widths,
+    through the Mosaic-compiled kernel and through ``chunk_kda``: both
+    float32 at HIGHEST, so the gap is rounding's. The control, the
+    kernel's products at DEFAULT (one bfloat16 pass), misses the same
+    bound: a lost HIGHEST would show."""
+    from tpudist.ops.pallas import kda_chunk as kc
+    o_gap, s_gap = _kda_gaps(4100000411, 2300)
+    assert o_gap <= 1e-4 and s_gap <= 1e-4
+    monkeypatch.setattr(kc, "_HI", jax.lax.Precision.DEFAULT)
+    o_gap, s_gap = _kda_gaps(4100000411, 2300)
+    assert max(o_gap, s_gap) > 1e-4

@@ -29,7 +29,9 @@ gain at ``norm_eps`` and u = norm(x), a layer is::
   then o_h <- RMSNorm_dk(o_h) * sigmoid((u Wg_a) Wg_b)_h (one gain shared
   by the heads) and x + concat_h(o_h) Wo. The PREFILL runs the CHUNKED
   form over chunks of 64 (:func:`chunk_kda`: within a chunk the WY form
-  through one triangular solve, across chunks the state through a scan);
+  through one triangular solve, across chunks the state through a scan;
+  on one TPU chip the same algebra as ONE Pallas kernel,
+  ``ops/pallas/kda_chunk.py``, which skips the chunks of padding);
   padding takes beta = 0 and g = 0, so the state it hands the slot is the
   real tokens'. The DECODE runs the one-token recurrence for every slot
   (:func:`kda_step`), its state read and written in place.
@@ -71,6 +73,8 @@ from tpudist.models import dropless
 from tpudist.models import longcatflash as lc
 from tpudist.models import transformer as T
 from tpudist.models.cohere2moe import _maker, held
+from tpudist.ops.pallas import kda_chunk as kc
+from tpudist.parallel.mesh import one_tpu_program
 from tpudist.scopes import cast, scope
 
 Params = Dict
@@ -394,11 +398,19 @@ def kda_step(S, q, k, v, g, beta, live):
     return jnp.where(live[:, None, None, None], new, S), o
 
 
+def _use_chunk_kernel(k_shape, v_shape) -> bool:
+    """Run the prefill's chunked KDA as the one Pallas kernel? By what the
+    code can observe, the twin of ``dropless._use_grouped_kernel``: on one
+    TPU chip (``one_tpu_program``: elsewhere :func:`chunk_kda` stays the
+    path and the kernel's reference) at shapes the kernel supports."""
+    return one_tpu_program() and kc.supports(k_shape, v_shape, chunk=CHUNK)
+
+
 def _kda_prefill(x, sp: Params, cfg: ModelConfig, prompt_len):
     """The KDA sublayer over a whole prompt, chunked. x: (b, s, d);
     ``prompt_len`` (traced): positions from there on are padding, with
-    beta = 0 and g = 0. -> (x + KDA, final state (b, H, K, V), conv tail
-    (b, taps - 1, 3 H K))."""
+    beta = 0 and g = 0 (the kernel's output there is 0). -> (x + KDA,
+    final state (b, H, K, V), conv tail (b, taps - 1, 3 H K))."""
     b, s, _ = x.shape
     taps = cfg.kda_conv
     u = T.rmsnorm(x, sp["in_norm"], cfg.norm_eps)
@@ -414,7 +426,13 @@ def _kda_prefill(x, sp: Params, cfg: ModelConfig, prompt_len):
         g = jnp.where(real[..., None], g, 0.0)
         beta = jnp.where(real, beta, 0.0)
     with scope("kda/chunk"):
-        o, S = chunk_kda(q, k, v, g, beta)
+        if _use_chunk_kernel(k.shape, v.shape):
+            flat = lambda a: a.reshape(b, s, -1)
+            o, S = kc.kda_chunk(*map(flat, (q, k, v, g)), beta, prompt_len,
+                                chunk=CHUNK, sub=SUB)
+            o = o.reshape(v.shape)
+        else:
+            o, S = chunk_kda(q, k, v, g, beta)
     return _kda_out(x, o, u, sp, cfg), S, tail
 
 

@@ -114,7 +114,8 @@ LINEAR_SCOPES: Tuple[str, ...] = (
     "kda/conv",         # the short causal conv, SiLU, the q/k L2 norms and
                         # the conv tail a slot keeps
     "kda/gate",         # the decay a channel and beta a head
-    "kda/chunk",        # prefill: the chunked recurrence (WY form + scan)
+    "kda/chunk",        # prefill: the chunked recurrence (the kernel
+                        # kda_chunk, or the WY form + scan off the TPU)
     "kda/state",        # decode: one token of the recurrence, the slots'
                         # states read and written
     "kda/out",          # the gated output norm and projection + residual
